@@ -181,9 +181,17 @@ class Agent:
 
     def _setup_server(self) -> None:
         from nomad_tpu.server.server import Server, ServerConfig
+        from nomad_tpu.server.worker import Worker
 
         cfg = ServerConfig(
             num_workers=self.config.num_schedulers,
+            # a worker takes up to one wave of ready evals per dequeue,
+            # so a backlog is scheduled as joint device waves
+            # (parallel/coalesce.launch_wave); with a width of 1 an
+            # agent would dispatch every eval on its own and never run
+            # the wave programs at all. A lone eval still takes the
+            # single-eval path.
+            worker_batch_size=Worker.MAX_WAVE,
             region=self.config.region,
             datacenter=self.config.datacenter,
             name=self.config.name,

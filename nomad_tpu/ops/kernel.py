@@ -30,6 +30,7 @@ placement axis padded to step buckets (``pad_steps``).
 
 from __future__ import annotations
 
+import os
 from typing import NamedTuple, Optional
 
 import jax
@@ -44,76 +45,27 @@ from nomad_tpu.tensors.schema import (
     EvalTensors,
 )
 
-def _machine_cache_tag() -> str:
-    """A fingerprint of what makes an XLA:CPU AOT artifact loadable on
-    THIS host: the CPU feature set (plus arch and jax version, which
-    change the serialized format).
-
-    The persistent compilation cache stores machine-code artifacts;
-    XLA's ``cpu_aot_loader`` loads them back with only a LOG-AND-FALL-
-    BACK check against the host's features, so a cache dir carried
-    across machines (a baked container image, a shared home volume, a
-    migrated VM) floods stderr with "Target machine feature
-    +prefer-no-gather is not supported" walls on every variant load —
-    hundreds of them per warmup pass. Namespacing the cache dir by
-    this tag makes a foreign machine's artifacts simply invisible:
-    stale caches degrade to a clean recompile (into the new
-    namespace), never a spew."""
-    import hashlib
-    import platform
-
-    bits = [platform.machine(), jax.__version__]
-    try:
-        with open("/proc/cpuinfo") as f:
-            for line in f:
-                # x86 "flags", arm64 "Features" — the first CPU's line
-                # is the loadability contract cpu_aot_loader checks
-                if line.startswith(("flags", "Features")):
-                    bits.append(" ".join(sorted(
-                        line.split(":", 1)[1].split())))
-                    break
-    except OSError:
-        # no /proc (macOS, containers without procfs): arch + version
-        # still split caches across the incompatibility classes that
-        # have bitten (different container hosts)
-        pass
-    return hashlib.sha256("|".join(bits).encode()).hexdigest()[:16]
+#: the checkout this package runs from (nomad_tpu/ops/kernel.py -> repo)
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
 
 
 def _enable_compile_cache() -> None:
-    """Persistent XLA compilation cache (set up when the kernel module
-    loads, i.e. only for consumers that actually touch the device path).
+    """Persistent XLA compilation cache, set up when the kernel module
+    loads (i.e. only for consumers that touch the device path).
 
     The scheduler compiles one kernel variant per (wave size, step
-    bucket, feature set); on TPU a cold compile is tens of seconds.
-    The persistent cache makes every variant a one-time cost per
-    machine instead of per process — without it, a fresh server paying
-    full compiles mid-scheduling can outlive the eval broker's nack
-    timeout and thrash redeliveries. Respects an existing user-set
-    cache dir; disable with NOMAD_TPU_COMPILE_CACHE=0.
-
-    The cache lives in a per-machine-fingerprint subdirectory
-    (``_machine_cache_tag``): AOT artifacts are machine code, and a
-    cache dir that outlives its machine (image bake, shared volume)
-    otherwise floods stderr through XLA's cpu_aot_loader on every
-    load attempt before falling back.
-    """
-    import os
-
-    try:
-        if jax.config.jax_compilation_cache_dir:
-            return
-        cache_dir = os.environ.get(
-            "NOMAD_TPU_COMPILE_CACHE",
-            os.path.join(os.path.expanduser("~"), ".cache", "nomad_tpu_xla"),
-        )
-        if cache_dir and cache_dir != "0":
-            cache_dir = os.path.join(cache_dir, _machine_cache_tag())
-            os.makedirs(cache_dir, exist_ok=True)
-            jax.config.update("jax_compilation_cache_dir", cache_dir)
-            jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-    except Exception:  # noqa: BLE001 - cache is an optimization only
-        pass
+    bucket, feature set), and a cold TPU compile is tens of seconds:
+    without the cache a fresh server paying full compiles
+    mid-scheduling can outlive the eval broker's nack timeout. Where
+    ``JAX_COMPILATION_CACHE_DIR`` is set the cache is placed from
+    outside: jax reads the variable itself and no directory is set in
+    code. Otherwise the cache lives at the fixed path
+    ``<checkout>/.jax_cache`` (git-ignored) -- the path is part of the
+    cache key, so a directory that moves never hits."""
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir",
+                          os.path.join(_CHECKOUT, ".jax_cache"))
 
 
 _enable_compile_cache()
@@ -363,7 +315,7 @@ class KernelIn(NamedTuple):
 #: snapshot utilization) or stacked with a leading member axis; a leaf
 #: whose rank equals the entry here +1 is batched. Shipping shared
 #: planes once instead of B times is what keeps wave upload bytes flat
-#: in wave size on a remote-device transport.
+#: in wave size.
 KIN_UNBATCHED_RANKS = KernelIn(
     cap_cpu=1, cap_mem=1, cap_disk=1, free_cores=1, shares_per_core=1,
     free_dyn=1, base_mask=1, used_cpu=1, used_mem=1, used_disk=1,
@@ -1096,7 +1048,7 @@ def place_taskgroups_joint(
     def _bat(x, rank):
         """Ensure a leading member axis (carried leaves need one even
         when the wave shipped the leaf shared/unbatched — the broadcast
-        happens ON DEVICE, costing HBM, not transport)."""
+        happens ON DEVICE, costing HBM, not host-to-device bytes)."""
         if jnp.ndim(x) == rank + 1:
             return x
         return jnp.broadcast_to(x, (b,) + jnp.shape(x))
@@ -1288,8 +1240,9 @@ place_taskgroups_joint_jit = jax.jit(
 # per launch: the joint program execution, then an eager per-field
 # fetch of eleven separate output buffers. The fused variant runs the
 # same scan as a single Pallas program (ops/pallas_kernel.fused_wave
-# _place — interpret mode off-TPU so CPU tier-1 exercises the exact
-# program) and PACKS everything the launcher fetches eagerly into one
+# _place — interpreted off-TPU; it does not lower through Mosaic, so
+# on TPU the launcher never routes here, see that module) and PACKS
+# everything the launcher fetches eagerly into one
 # flat f32 buffer, so steady state is one dispatch and one readback
 # that rides the dispatch's own synchronization. The top-k planes stay
 # separate device outputs — they are lazy (_WaveTopK) and drain in the
@@ -1476,8 +1429,7 @@ def build_kernel_in(
 
     # leaves stay NUMPY: jit uploads each argument once at call time.
     # Building device arrays here would mean one host->device transfer
-    # per field per evaluation (and per wave member when coalescing) —
-    # on a remote-device transport every transfer is a round trip.
+    # per field per evaluation (and per wave member when coalescing).
     return KernelIn(
         cap_cpu=np.asarray(cluster.cap_cpu, np.float32),
         cap_mem=np.asarray(cluster.cap_mem, np.float32),

@@ -537,21 +537,38 @@ def make_schedule_apply_step_pallas(k_steps: int, interpret: bool = False):
 # ---------------------------------------------------------------------------
 # Fused wave mega-kernel (ISSUE 19): the whole joint wave — feasibility
 # masking, binpack/spread scoring, the per-step capacity-carry scan,
-# and top-k selection — as ONE pallas program whose intermediate planes
-# (masked scores, penalty unions, candidate sets) never leave
-# VMEM/registers between stages. The body runs the SAME scan core as
-# the XLA composite (ops/kernel.place_taskgroups_joint) over values
-# read from the kernel refs, so bit-identity with the composite holds
-# by construction across the whole supported feature lattice; what
-# fusion adds is the program boundary: one dispatch, one packed
-# readback (ops/kernel.FusedWaveOut), zero HBM round-trips between the
-# former composite stages. Interpret mode off-TPU keeps CPU tier-1
-# running the exact fused program the TPU path dispatches.
+# and top-k selection — as ONE pallas program with one packed readback
+# (ops/kernel.FusedWaveOut). The body runs the SAME scan core as the
+# XLA composite (ops/kernel.place_taskgroups_joint) over values read
+# from the kernel refs, so bit-identity with the composite holds by
+# construction across the whole supported feature lattice.
+#
+# Mosaic's verdict (PR 21, jax 0.9.0 / libtpu 0.0.34, TPU v5e): the
+# body does NOT lower for TPU. jax's Pallas->Mosaic lowering stops at
+# the wave scan (`_scan_lowering_rule`: NotImplementedError, a scan
+# with stacked per-step inputs/outputs) before Mosaic sees anything,
+# and the step body behind it uses `dynamic_slice`, `scatter`,
+# `scatter-add` and `top_k`, none of which has a Pallas-TPU lowering
+# rule, plus a full-width 1D permutation gather. That is a rewrite of
+# the placement core, not a repair, so the program exists only where
+# Pallas interprets it (traced into ordinary XLA ops: the CPU tier-1
+# path); on TPU the launcher routes every wave to the composite
+# statically (parallel/coalesce.fused_wave_enabled), never by trying
+# this one and catching the error. PERF.md "Bring-up on v5e" has the
+# evidence; ROADMAP A3/C2 own the delete-or-rewrite decision.
 # ---------------------------------------------------------------------------
 
 
+def pallas_interpret() -> bool:
+    """Whether a ``pallas_call`` dispatched to the default device runs
+    interpreted: Mosaic compiles for TPU only. The ONE place that
+    choice is made; every entry point below takes ``interpret``
+    explicitly."""
+    return jax.devices()[0].platform != "tpu"
+
+
 def fused_wave_place(kin, step_member, step_local, t_steps: int,
-                     features, interpret: bool = True):
+                     features, interpret: bool):
     """One-dispatch fused wave: (stacked KernelIn, step maps) ->
     ops/kernel.FusedWaveOut. Mirrors place_taskgroups_joint + the
     launcher's eager-fetch packing in a single pallas program."""
@@ -605,18 +622,14 @@ def fused_wave_place(kin, step_member, step_local, t_steps: int,
 
 def _fused_wave_run(kin, step_member, step_local, t_steps: int,
                     features):
-    # interpret everywhere but real TPU: tier-1 CPU runs the exact
-    # fused program; on-chip the same body compiles through Mosaic
     return fused_wave_place(kin, step_member, step_local, t_steps,
-                            features,
-                            interpret=jax.default_backend() != "tpu")
+                            features, interpret=pallas_interpret())
 
 
 fused_wave_place_jit = jax.jit(_fused_wave_run, static_argnums=(3, 4))
 
 
-def make_fused_wave_apply(t_steps: int, features,
-                          interpret: bool = True):
+def make_fused_wave_apply(t_steps: int, features, interpret: bool):
     """Fused wave + carry commit with owned-buffer donation (the
     PR 10/18 discipline): ``fn(kin, used_cpu, used_mem, step_member,
     step_local) -> (FusedWaveOut, used_cpu', used_mem')`` where the

@@ -597,14 +597,17 @@ def _warm_single(e: Dict) -> bool:
 
 def warmup_entries(entries: List[Dict], mesh=None,
                    mesh_only: bool = False) -> Tuple[int, int]:
-    """Compile every manifest entry; returns (compiled, failed).
-    Failures are logged and skipped — warmup is an optimization, never
-    a liveness dependency.
+    """Compile every manifest entry; returns (compiled, failed). An
+    entry that fails to compile is an ERROR, logged with its traceback
+    and counted — the same program would fail the same way under a
+    live wave — but the pass goes on to the entries that remain.
 
     ``mesh``: ALSO warm the sharded joint signatures for this mesh
     (the default dispatch on a >=2-device server). ``mesh_only`` skips
-    the single-device programs — the pass a server runs when its mesh
-    probe adopts a mesh AFTER the main warmup already covered them."""
+    the single-device programs."""
+    from nomad_tpu.parallel.coalesce import fused_wave_routes
+    from nomad_tpu.tensors.device_state import default_device_state
+
     compiled = failed = 0
     node_sizes = set()
     for e in _dedupe(entries):
@@ -612,24 +615,18 @@ def warmup_entries(entries: List[Dict], mesh=None,
             did = False
             if e.get("kernel") == "joint":
                 # warm the one program the launcher will route this
-                # entry's envelope to: the FUSED mega-kernel when the
-                # envelope supports it (and the knob is on), the
-                # composite otherwise — warming both would double
-                # compile time on a program that never dispatches.
-                # The composite still compiles lazily on the rare
-                # fused-exception fallback; that path is off the
-                # steady state by construction.
-                from nomad_tpu.parallel.coalesce import (
-                    fused_wave_enabled,
-                )
-
-                fused_on = fused_wave_enabled()
+                # entry's envelope to: the FUSED program where the
+                # launcher has a fused route and the envelope supports
+                # it, the composite otherwise — warming both would
+                # double compile time on a program that never
+                # dispatches
                 if not mesh_only:
-                    did = fused_on and _warm_fused(e)
+                    did = fused_wave_routes(False) and _warm_fused(e)
                     if not did:
                         did = _warm_joint(e)
                 if mesh is not None:
-                    d2 = fused_on and _warm_fused_sharded(e, mesh)
+                    d2 = fused_wave_routes(True) \
+                        and _warm_fused_sharded(e, mesh)
                     if not d2:
                         # a mesh too narrow for the fused local
                         # top-k merge launches composite-sharded
@@ -643,41 +640,33 @@ def warmup_entries(entries: List[Dict], mesh=None,
             if did:
                 compiled += 1
                 node_sizes.add(int(e["nodes"]))
-        except Exception as err:                # noqa: BLE001
+        except Exception:       # noqa: BLE001 - counted and reported
             failed += 1
-            LOG.warning("kernel warmup entry failed (%s): %s", e, err)
+            LOG.exception("kernel warmup entry failed to compile: %s", e)
     # the device-resident state's dirty-row scatter rides the same
     # node shapes: precompile its (row-bucket, dtype) programs so the
     # first burst whose dirty set crosses a fresh bucket doesn't pay a
     # cold compile inside an eval's snapshot phase
     for n in sorted(node_sizes):
-        try:
-            from nomad_tpu.tensors.device_state import default_device_state
-
-            default_device_state.warm_scatter(n)
-        except Exception as err:                # noqa: BLE001
-            LOG.warning("scatter warmup failed (n=%d): %s", n, err)
+        default_device_state.warm_scatter(n)
     return compiled, failed
 
 
 def warmup_from_manifest(path: str, expand: bool = True,
                          max_wave: Optional[int] = None,
-                         mesh=None,
-                         mesh_only: bool = False) -> Tuple[int, int]:
+                         mesh=None) -> Tuple[int, int]:
     """Load ``path`` and precompile its lattice (expanded across the
     wave-bucket axis unless ``expand=False``; see ``expand_lattice``
-    for ``max_wave``, ``warmup_entries`` for ``mesh``/``mesh_only``).
-    Missing/corrupt manifests are a no-op."""
+    for ``max_wave``, ``warmup_entries`` for ``mesh``). A missing
+    manifest is a no-op (a first start has none yet); an unreadable
+    one raises."""
     try:
         entries = load_manifest(path)
     except FileNotFoundError:
         return (0, 0)
-    except Exception as err:                    # noqa: BLE001
-        LOG.warning("warmup manifest %s unreadable: %s", path, err)
-        return (0, 0)
     if expand:
         entries = expand_lattice(entries, max_wave=max_wave)
-    return warmup_entries(entries, mesh=mesh, mesh_only=mesh_only)
+    return warmup_entries(entries, mesh=mesh)
 
 
 def start_background_warmup(path: str, expand: bool = True,
@@ -687,18 +676,19 @@ def start_background_warmup(path: str, expand: bool = True,
     """Server-start entry point: warm the manifest on a daemon thread
     (compiles hold the XLA compile lock, not the GIL, so the server
     keeps serving; waves that race warmup simply compile first and the
-    warmup call becomes a cache hit)."""
+    warmup call becomes a cache hit). Entries that failed to compile
+    are reported as an error; anything else that goes wrong ends the
+    thread with its traceback."""
     def run() -> None:
-        try:
-            compiled, failed = warmup_from_manifest(
-                path, expand=expand, max_wave=max_wave, mesh=mesh)
-            if compiled or failed:
-                LOG.info("kernel warmup: %d compiled, %d failed (%s)",
-                         compiled, failed, path)
-            if on_done is not None:
-                on_done(compiled, failed)
-        except Exception as err:                # noqa: BLE001
-            LOG.warning("kernel warmup failed: %s", err)
+        compiled, failed = warmup_from_manifest(
+            path, expand=expand, max_wave=max_wave, mesh=mesh)
+        if failed:
+            LOG.error("kernel warmup: %d of %d entries of %s failed "
+                      "to compile", failed, compiled + failed, path)
+        elif compiled:
+            LOG.info("kernel warmup: %d compiled (%s)", compiled, path)
+        if on_done is not None:
+            on_done(compiled, failed)
 
     t = threading.Thread(target=run, daemon=True, name="kernel-warmup")
     t.start()

@@ -126,9 +126,9 @@ def make_schedule_apply_loop(k_steps: int,
 
     ``lax.scan`` over the batch axis keeps the utilization planes in
     the carry, so a whole measurement burst (or a steady-state window
-    of the live system) is a single dispatch — on a remote-device
-    transport, per-dispatch round trips otherwise dominate and measure
-    the link instead of the scheduler (the round-1 grid pathology).
+    of the live system) is a single dispatch: per-dispatch host
+    overhead would otherwise dominate and measure the launch path
+    instead of the scheduler.
 
     ``backend``: "xla" uses the vmapped XLA kernels (full-width, or
     candidate-set when ``topk``); "pallas_topk" uses the fused pallas

@@ -125,7 +125,8 @@ sharded_wave_stats = _ShardedWaveStats()
 
 #: Fused-wave dispatch knob (ISSUE 19). Default ON: waves whose
 #: feature union fits the fused envelope
-#: (ops/kernel.fused_wave_supported) run the one-dispatch mega-kernel;
+#: (ops/kernel.fused_wave_supported) run the one-dispatch fused
+#: program where one exists for the dispatch (``fused_wave_routes``);
 #: the rest take the composite path, counted as fallbacks below.
 _FUSED_WAVE = True
 
@@ -141,18 +142,39 @@ def fused_wave_enabled() -> bool:
     return _FUSED_WAVE
 
 
+def fused_wave_routes(sharded: bool) -> bool:
+    """Whether waves of this dispatch kind route fused-first: the knob,
+    and a fused program that EXISTS for the dispatch. The sharded
+    program is XLA under ``shard_map`` and runs on any backend. The
+    single-device one is a Pallas program whose body does not lower
+    through Mosaic (ops/pallas_kernel.py has the verdict), so it exists
+    only where Pallas interprets it: on TPU every single-device wave
+    takes the composite — decided here, from the platform, never by
+    trying the fused program and catching what it raises. The launcher
+    and the AOT warmup both ask this one function."""
+    if not _FUSED_WAVE:
+        return False
+    if sharded:
+        return True
+    from nomad_tpu.ops.pallas_kernel import pallas_interpret
+
+    return pallas_interpret()
+
+
 class _FusedWaveStats:
     """Fused-dispatch accounting (exported as the
     ``nomad_tpu_wave_fused_*`` Prometheus series; reset with
     telemetry.reset()).
 
     ``launches`` counts waves that ran the fused mega-kernel;
-    ``fallbacks`` counts waves that wanted fusion (knob on) but ran
-    the composite anyway — an unsupported feature union
-    (spreads/devices/cores/network), a node shard too narrow for the
-    local top-k merge, or a fused dispatch error. Steady live traffic
-    fits the envelope, so the steady-burst gate holds fallbacks at
-    ZERO."""
+    ``fallbacks`` counts waves that had a fused route
+    (``fused_wave_routes``) but ran the composite anyway — an
+    unsupported feature union (spreads/devices/cores/network) or a
+    node shard too narrow for the local top-k merge. Both are routing
+    decisions made from the wave itself BEFORE dispatch: a program the
+    router chose that then raises is an error, never a fallback.
+    Steady lean traffic fits the envelope, so the steady-burst gate
+    holds fallbacks at ZERO."""
 
     def __init__(self) -> None:
         self._lock = witness_lock("FusedWaveStats._lock")
@@ -601,8 +623,7 @@ def launch_wave(kins: List[KernelIn], k_steps: List[int],
         wave_sharded = mesh_size >= 2 and n_nodes % mesh_size == 0
         # stack on HOST (numpy): the jit call below uploads each stacked
         # leaf once; stacking device arrays would dispatch per leaf per
-        # member — thousands of round trips on a remote-device
-        # transport. The big node planes (cluster capacity + the wave
+        # member. The big node planes (cluster capacity + the wave
         # snapshot's utilization) are usually IDENTICAL across members;
         # when every one of _SHAREABLE_FIELDS is identity-shared, they
         # ship UNBATCHED (the joint kernel broadcasts on device) so wave
@@ -679,16 +700,19 @@ def launch_wave(kins: List[KernelIn], k_steps: List[int],
     # fused dispatch (ISSUE 19): one mega-kernel program instead of
     # program + eager multi-buffer fetch. Sharded fusion additionally
     # needs each node shard wide enough for the local TOPK merge.
-    fused_ok = (_FUSED_WAVE and fused_wave_supported(feats)
+    fused_route = fused_wave_routes(wave_sharded)
+    fused_ok = (fused_route and fused_wave_supported(feats)
                 and (not wave_sharded
                      or n_nodes // mesh_size >= TOPK))
-    host = None
-    wave_topk = None
     t_launch = time.perf_counter()
     token = object()
     with _INFLIGHT_LOCK:
         _INFLIGHT_STARTS[token] = t_launch
     try:
+        # an exception from the program the router chose PROPAGATES
+        # (to every member of the wave, through the coalescer): a
+        # compile or device error must read as an error, not as a
+        # slower wave
         if wave_sharded:
             from nomad_tpu.parallel.sharded import (
                 fused_sharded_entry,
@@ -702,44 +726,26 @@ def launch_wave(kins: List[KernelIn], k_steps: List[int],
             # (the profiler's explicit upload would otherwise commit
             # them to one device and the call would pay a reshard);
             # step planes ship replicated, raw numpy on purpose
-            if fused_ok:
-                try:
-                    fn, kin_shardings, repl = fused_sharded_entry(
-                        mesh, shareable, neutral_shareable,
-                        job_shareable)
-                    fout = profiler.call(
-                        "fused_wave_sharded", fn,
-                        (stacked, step_member, step_local),
-                        (t_pad, feats),
-                        wave_key + (tuple(mesh.devices.flat),),
-                        jit_fn=fn,
-                        shardings=(kin_shardings, repl, repl),
-                    )
-                    host, wave_topk = _fused_fetch(fout, t_pad, b_pad)
-                except Exception:       # noqa: BLE001 - counted, composite covers
-                    host = wave_topk = None
-            if host is None:
-                fn, kin_shardings, repl = joint_sharded_entry(
-                    mesh, shareable, neutral_shareable, job_shareable)
-                out = profiler.call(
-                    "joint_sharded", fn,
-                    (stacked, step_member, step_local),
-                    (t_pad, feats),
-                    wave_key + (tuple(mesh.devices.flat),), jit_fn=fn,
-                    shardings=(kin_shardings, repl, repl),
-                )
+            kernel, entry = (
+                ("fused_wave_sharded", fused_sharded_entry) if fused_ok
+                else ("joint_sharded", joint_sharded_entry))
+            fn, kin_shardings, repl = entry(
+                mesh, shareable, neutral_shareable, job_shareable)
+            out = profiler.call(
+                kernel, fn,
+                (stacked, step_member, step_local),
+                (t_pad, feats),
+                wave_key + (tuple(mesh.devices.flat),), jit_fn=fn,
+                shardings=(kin_shardings, repl, repl),
+            )
         else:
             if mesh is not None:
                 sharded_wave_stats.note_fallback(mesh_size)
             if fused_ok:
-                try:
-                    fout = fused_wave_launch(
-                        stacked, step_member, step_local, t_pad,
-                        feats, wave_key)
-                    host, wave_topk = _fused_fetch(fout, t_pad, b_pad)
-                except Exception:       # noqa: BLE001 - counted, composite covers
-                    host = wave_topk = None
-            if host is None:
+                out = fused_wave_launch(
+                    stacked, step_member, step_local, t_pad,
+                    feats, wave_key)
+            else:
                 out = profiler.call(
                     "joint", place_taskgroups_joint_jit,
                     (stacked, jnp.asarray(step_member),
@@ -747,12 +753,13 @@ def launch_wave(kins: List[KernelIn], k_steps: List[int],
                     (t_pad, feats),
                     wave_key, jit_fn=place_taskgroups_joint_jit,
                 )
-        if host is not None:
+        if fused_ok:
+            host, wave_topk = _fused_fetch(out, t_pad, b_pad)
             fused_wave_stats.note_launch()
         else:
-            if _FUSED_WAVE:
-                # wanted fusion, ran the composite (unsupported
-                # feature union, narrow shard, or a fused error)
+            if fused_route:
+                # had a fused route, ran the composite (unsupported
+                # feature union or narrow shard)
                 fused_wave_stats.note_fallback()
             with tracer.span("kernel.d2h"):
                 # fetch ONLY the planes members consume immediately:
@@ -1060,10 +1067,7 @@ class ClusterCache:
             # flight waves keep their own generation's arrays). The
             # wave launcher then finds every shared leaf resident and
             # uploads nothing for it.
-            try:
-                default_device_state.ensure(built, u)
-            except Exception:                   # noqa: BLE001
-                pass        # residency is an optimization, never a dep
+            default_device_state.ensure(built, u)
             return built
         key = id(state)
         with self._lock:

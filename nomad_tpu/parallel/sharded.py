@@ -514,8 +514,6 @@ def fused_sharded_entry(mesh: Mesh, shared: bool = False,
     twins flow in without resharding."""
     import functools
 
-    from jax.experimental.shard_map import shard_map
-
     from nomad_tpu.ops.kernel import FusedWaveOut
 
     layouts = _fused_sharded_cache.get(mesh)
@@ -535,8 +533,8 @@ def fused_sharded_entry(mesh: Mesh, shared: bool = False,
         body = functools.partial(
             _fused_sharded_core, t_steps=t_steps, features=features,
             n_shards=n_shards)
-        res = shard_map(body, mesh=mesh, in_specs=in_specs,
-                        out_specs=out_specs, check_rep=False)(
+        res = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                            out_specs=out_specs, check_vma=False)(
             kin, step_member, step_local)
         return FusedWaveOut(*res)
 

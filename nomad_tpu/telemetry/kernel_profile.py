@@ -69,6 +69,10 @@ class KernelProfiler:
         self.dispatches: Dict[str, int] = {}
         #: cross-check: observed jit cache growth (when introspectable)
         self.cache_growth = 0
+        #: kernel -> devices its outputs were found on after execute:
+        #: what says a program really ran on the chip, and across how
+        #: many of them (chip_smoke.py asserts on it)
+        self.output_devices: Dict[str, set] = {}
 
     # --- control --------------------------------------------------------
 
@@ -92,6 +96,7 @@ class KernelProfiler:
                 self.transfer_bytes[k] = 0
             self.dispatches.clear()
             self.cache_growth = 0
+            self.output_devices.clear()
 
     # --- accounting -----------------------------------------------------
 
@@ -114,6 +119,9 @@ class KernelProfiler:
                                  for k, v in self.stage_s.items()},
                 "TransferBytes": dict(self.transfer_bytes),
                 "Dispatches": dict(self.dispatches),
+                "OutputDevices": {
+                    k: sorted(str(d) for d in devs)
+                    for k, devs in self.output_devices.items()},
                 "PerKey": per_key,
             }
 
@@ -174,14 +182,11 @@ class KernelProfiler:
 
         import jax
 
+        # a jit function's own cache size; a plain callable (tests
+        # profile fakes) has none and is classified by the seen set
         probe = jit_fn if jit_fn is not None else fn
         size_fn = getattr(probe, "_cache_size", None)
-        size0 = None
-        if callable(size_fn):
-            try:
-                size0 = size_fn()
-            except Exception:           # noqa: BLE001 - introspection only
-                size0 = None
+        size0 = size_fn() if size_fn is not None else None
 
         # explicit upload: jit would upload the host numpy leaves
         # transparently inside the call; splitting it out is what makes
@@ -227,17 +232,12 @@ class KernelProfiler:
         out = fn(*dev_args, *static_args)
         call_s = time.perf_counter() - t0
 
-        grew = 0
-        if size0 is not None:
-            try:
-                grew = max(size_fn() - size0, 0)
-            except Exception:           # noqa: BLE001
-                grew = 0
-        # a miss is OBSERVED cache growth when the runtime exposes it
-        # (survives profiler resets against a warm jit cache); the seen
-        # set is the fallback. A key we bucketed as "seen" that grows
-        # the cache anyway is the exact bug class this counter exists
-        # to expose (two shapes under one bucket key).
+        # a miss is OBSERVED growth of the jit function's cache
+        # (survives profiler resets against a warm jit cache). A key
+        # we bucketed as seen that grows the cache anyway is the exact
+        # bug class this counter exists to expose (two shapes under
+        # one bucket key).
+        grew = 0 if size0 is None else max(size_fn() - size0, 0)
         miss = bool(grew) if size0 is not None else not seen
         stage = "compile" if miss else "dispatch"
         tracer.record(f"kernel.{stage}", call_s)
@@ -251,6 +251,10 @@ class KernelProfiler:
             t0 = time.perf_counter()
             jax.block_until_ready(out)
             self._bump_stage("execute", time.perf_counter() - t0)
+        devs = {d for x in jax.tree_util.tree_leaves(out)
+                if isinstance(x, jax.Array) for d in x.devices()}
+        with self._lock:
+            self.output_devices.setdefault(kernel, set()).update(devs)
         return out
 
     def _bump_stage(self, stage: str, dur_s: float) -> None:

@@ -243,6 +243,16 @@ class DeviceClusterState:
                                  if self._mesh is not None else 0),
             }
 
+    def newest_planes(self) -> Dict[str, object]:
+        """field -> device array of the most recently used resident
+        generation ({} when nothing is resident): what a diagnostic
+        reads to see WHERE the wave-shared planes live, and how they
+        are split (chip_smoke.py)."""
+        with self._lock:
+            if not self._gens:
+                return {}
+            return dict(next(reversed(self._gens.values())).planes)
+
     # --- registry -------------------------------------------------------
 
     def lookup(self, arr, frozen_ok: bool = True, spec=None,
