@@ -81,6 +81,9 @@ _ATTRIBUTED = {
     "kernel.dispatch": ("dispatch", "wall"),
     "kernel.execute": ("execute", "wall"),
     "kernel.d2h": ("d2h", "wall"),
+    # the wave's top-k planes, copied in the plan window (under
+    # plan.deferred): a device copy all the same
+    "kernel.d2h.topk": ("d2h", "wall"),
     "plan.evaluate": ("plan-apply", "cpu"),
     "plan.commit": ("plan-apply", "cpu"),
     # the group-commit pass (ISSUE 6): one planes snapshot + vectorized
@@ -93,6 +96,8 @@ _ATTRIBUTED = {
     # of the wave-critical sched-host sum
     "plan.deferred": ("plan-post", "cpu"),
     "fsm.apply": ("fsm", "cpu"),
+    # the store's write transaction, a child of fsm.apply: same stage
+    "store.txn": ("fsm", "cpu"),
 }
 
 #: waits that overlap attributed work; reported, never summed

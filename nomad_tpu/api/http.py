@@ -29,6 +29,7 @@ from nomad_tpu.server import endpoints
 from nomad_tpu.server.readplane import ReadPlaneError
 from nomad_tpu.structs import consts
 from nomad_tpu.structs.job import Job
+from nomad_tpu.telemetry.trace import tracer
 
 
 class HTTPError(Exception):
@@ -270,30 +271,50 @@ class HTTPAgent:
                 if v is not None
             }
             req = Request(method, path, params, query, body, token, handler)
-            try:
-                result = fn(req)
-            except HTTPError as e:
-                self._send(handler, e.status, {"error": e.message})
-            except ReadPlaneError as e:
-                # the read plane refused (no leader / over max_stale):
-                # loud 503 + the leader hint so callers can re-aim
-                if e.known_leader:
-                    handler._read_leader_hint = e.known_leader
-                self._send(handler, 503, {"error": str(e)})
-            except PermissionError as e:
-                self._send(handler, 403, {"error": str(e)})
-            except KeyError as e:
-                self._send(handler, 404, {"error": str(e)})
-            except (ValueError, TypeError) as e:
-                self._send(handler, 400, {"error": str(e)})
-            except Exception as e:  # wrap(): 500 + message
-                self._send(handler, 500, {"error": f"{type(e).__name__}: {e}"})
+            # one span per request, named by its route handler; none for
+            # a request that is held open (an endless stream, a blocking
+            # query, a follow-mode log tail, a websocket): how long
+            # those last is the client's patience
+            held = (path in self._STREAMING_PATHS or "index" in query
+                    or self._wants_stream(parsed) or "upgrade" in
+                    handler.headers.get("Connection", "").lower())
+            if held:
+                self._run_route(handler, fn, req)
             else:
-                if result is not StreamedResponse:
-                    status, payload = result if isinstance(result, tuple) else (200, result)
-                    self._send(handler, status, payload)
+                with tracer.span(f"http.{fn.__name__}") as span:
+                    span.set(status=self._run_route(handler, fn, req))
             return
         self._send(handler, 404, {"error": f"no handler for {method} {path}"})
+
+    def _run_route(self, handler, fn: Callable, req: Request) -> int:
+        """Call the route handler and send what it returns or raises
+        (http.go wrap()). Returns the status sent; 0 where the handler
+        wrote its own response."""
+        try:
+            result = fn(req)
+        except HTTPError as e:
+            status, payload = e.status, {"error": e.message}
+        except ReadPlaneError as e:
+            # the read plane refused (no leader / over max_stale):
+            # loud 503 + the leader hint so callers can re-aim
+            if e.known_leader:
+                handler._read_leader_hint = e.known_leader
+            status, payload = 503, {"error": str(e)}
+        except PermissionError as e:
+            status, payload = 403, {"error": str(e)}
+        except KeyError as e:
+            status, payload = 404, {"error": str(e)}
+        except (ValueError, TypeError) as e:
+            status, payload = 400, {"error": str(e)}
+        except Exception as e:  # wrap(): 500 + message
+            status, payload = 500, {"error": f"{type(e).__name__}: {e}"}
+        else:
+            if result is StreamedResponse:
+                return 0
+            status, payload = result if isinstance(result, tuple) \
+                else (200, result)
+        self._send(handler, status, payload)
+        return status
 
     # endpoints whose responses never end; forwarding must relay
     # them incrementally rather than buffer the body

@@ -26,6 +26,7 @@ from __future__ import annotations
 
 import functools
 import logging
+import os
 import socket
 import subprocess
 import threading
@@ -44,14 +45,18 @@ def _run(argv: List[str], timeout: float = 15.0) -> subprocess.CompletedProcess:
 
 @functools.lru_cache(maxsize=1)
 def bridge_supported() -> bool:
-    """Can this host create netns + veth? (probe once)"""
-    ns = "nomadtpu-probe"
+    """Can this host create netns + veth? (probe once per process;
+    the probe's names carry the pid, so that processes probing at the
+    same moment, as a test run's workers do, cannot refuse each other)"""
+    pid = os.getpid()
+    ns = f"nomadtpu-probe-{pid}"
+    veth = f"ntp{pid}a"         # an interface name holds 15 characters
     try:
         if _run(["ip", "netns", "add", ns]).returncode != 0:
             return False
-        ok = _run(["ip", "link", "add", "nomadtpu-pr0", "type", "veth",
-                   "peer", "name", "nomadtpu-pr1"]).returncode == 0
-        _run(["ip", "link", "del", "nomadtpu-pr0"])
+        ok = _run(["ip", "link", "add", veth, "type", "veth",
+                   "peer", "name", f"ntp{pid}b"]).returncode == 0
+        _run(["ip", "link", "del", veth])
         return ok
     except (OSError, subprocess.TimeoutExpired):
         return False

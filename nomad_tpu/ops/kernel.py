@@ -348,6 +348,52 @@ class KernelOut(NamedTuple):
     exhausted_cores: jnp.ndarray
 
 
+class LaunchOrigin(NamedTuple):
+    """Who asks for a launch: what the scheduler hands the launcher
+    beside the tensors, so that the launch record (the ``wave.launch``
+    span, ``wave_stats``) can say whom the device placed, against which
+    state. Never read by a program."""
+
+    eval_id: str
+    state_index: int     # index of the snapshot the tensors were built from
+    steps: int           # real placement steps (``k_steps`` is their bucket)
+    relaunch: bool       # the scheduler is placing this evaluation again
+
+
+def features_key(f: KernelFeatures) -> str:
+    """``n_spreads=1,with_topk,with_shuffle``: the set features, for a
+    launch record."""
+    return ",".join(
+        name if value is True else f"{name}={value}"
+        for name, value in zip(f._fields, f) if value)
+
+
+def real_steps(k_steps: list, origins: list) -> list:
+    """Each member's real placement steps: what its origin says, or its
+    step bucket where the caller gave none."""
+    return [o.steps if o is not None else k
+            for o, k in zip(origins, k_steps)]
+
+
+def launch_attrs(seq: int, k_steps: list, origins: Optional[list],
+                 deadline_fired: bool = False) -> dict:
+    """A launch record's attributes that are known before the launch is
+    assembled (docs/TELEMETRY.md): the members in the order the device
+    program places them. The launcher adds ``program``, ``slots``,
+    ``padded_steps`` and ``features`` once it has routed the launch."""
+    origins = origins or [None] * len(k_steps)
+    return {
+        "seq": seq,
+        "members": len(k_steps),
+        "evals": [o.eval_id if o is not None else "" for o in origins],
+        "steps": real_steps(k_steps, origins),
+        "state_index": [o.state_index if o is not None else -1
+                        for o in origins],
+        "relaunch": [o is not None and o.relaunch for o in origins],
+        "deadline": deadline_fired,
+    }
+
+
 def _feasible(kin: KernelIn, st, f: KernelFeatures) -> tuple:
     """Resource-fit mask planes for the current carry state."""
     true_plane = jnp.ones_like(kin.base_mask)
@@ -960,30 +1006,62 @@ def _resident_kin(kin: KernelIn) -> KernelIn:
 
 
 def default_kernel_launch(kin: KernelIn, k_steps: int,
-                          features: KernelFeatures) -> KernelOut:
+                          features: KernelFeatures,
+                          origin: Optional[LaunchOrigin] = None) -> KernelOut:
     """The stack's direct (non-coalesced) dispatch: candidate-set fast
     path when its preconditions hold, full-width kernel otherwise or on
-    a bound breach.
-
-    Profiled like coalesced waves (telemetry/kernel_profile.py): the
-    single-eval path compiles its own (node-pad, step-bucket, features)
-    variants, and an un-instrumented fallback here would let recompiles
-    hide outside the wave accounting."""
-    from nomad_tpu.telemetry.kernel_profile import profiler
-
+    a bound breach. Each is a device launch of its own, with its own
+    launch record."""
     features = canonical_features(features)
     n_pad = int(np.asarray(kin.cap_cpu).shape[0])
     kin = _resident_kin(kin)
     key = (n_pad, k_steps, features)
     if features.n_spreads == 0 and not bool(kin.algorithm_spread):
-        out, ok = profiler.call(
-            "single_topk", place_taskgroup_topk_jit, (kin,),
-            (k_steps, features), key, jit_fn=place_taskgroup_topk_jit)
-        if bool(ok):
+        out = _lone_launch("single_topk", place_taskgroup_topk_jit, kin,
+                           k_steps, features, key, origin)
+        if out is not None:
             return out
-    return profiler.call(
-        "single_full", place_taskgroup_jit, (kin,),
-        (k_steps, features), key, jit_fn=place_taskgroup_jit)
+    return _lone_launch("single_full", place_taskgroup_jit, kin, k_steps,
+                        features, key, origin)
+
+
+def _lone_launch(program: str, jit_fn, kin: KernelIn, k_steps: int,
+                 features: KernelFeatures, key: tuple,
+                 origin: Optional[LaunchOrigin]) -> Optional[KernelOut]:
+    """One evaluation's launch outside any wave, recorded like a wave's
+    (parallel/coalesce.launch_wave): ``wave.launch`` over
+    ``kernel.dispatch`` / ``kernel.execute`` (telemetry/kernel_profile.py:
+    the single-eval path compiles its own (node-pad, step-bucket,
+    features) variants, and recompiles must not hide outside the wave
+    accounting) and ``kernel.d2h``. The planes the scheduler walks next
+    come back as numpy; the top-k planes stay on the device until the
+    plan window's score_meta drain. None where the candidate-set
+    program says its bound did not hold."""
+    from nomad_tpu.parallel.coalesce import wave_stats
+    from nomad_tpu.telemetry.kernel_profile import launch_seq, profiler
+    from nomad_tpu.telemetry.trace import tracer
+
+    steps = origin.steps if origin is not None else int(kin.n_steps)
+    wave_stats.observe_lone(
+        steps, k_steps, origin is not None and origin.relaunch)
+    seq = next(launch_seq)
+    attrs = None
+    if tracer.enabled:
+        attrs = dict(
+            launch_attrs(seq, [steps], [origin]), program=program,
+            slots=1, padded_steps=k_steps, features=features_key(features))
+    with tracer.span("wave.launch", attrs=attrs):
+        out = profiler.call(program, jit_fn, (kin,), (k_steps, features),
+                            key, jit_fn=jit_fn)
+        with tracer.span("kernel.d2h") as sp:
+            if program == "single_topk":
+                out, ok = out
+                if not bool(ok):
+                    return None
+            host = {f: np.asarray(x) for f, x in zip(out._fields, out)
+                    if f not in ("topk_idx", "topk_scores")}
+            sp.set(bytes=sum(a.nbytes for a in host.values()))
+    return out._replace(**host)
 
 
 class JointOut(NamedTuple):

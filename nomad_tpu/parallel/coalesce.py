@@ -35,15 +35,19 @@ from nomad_tpu.ops.kernel import (
     KernelFeatures,
     KernelIn,
     KernelOut,
+    LaunchOrigin,
     canonical_features,
+    features_key,
     fused_wave_launch,
     fused_wave_supported,
+    launch_attrs,
     pad_steps,
     place_taskgroups_joint_jit,
+    real_steps,
     unpack_fused_wave,
 )
 from nomad_tpu.telemetry.histogram import histograms, percentile
-from nomad_tpu.telemetry.kernel_profile import profiler
+from nomad_tpu.telemetry.kernel_profile import launch_seq, profiler
 from nomad_tpu.telemetry.trace import tracer
 from nomad_tpu.tensors.device_state import default_device_state
 from nomad_tpu.utils.faultpoints import fault
@@ -268,8 +272,12 @@ class _WaveTopK:
             # while-loop above) so one injected error never wedges the
             # whole wave's score_meta drain
             fault("wave.d2h.drain")
-            idx = np.asarray(self._idx)
-            scores = np.asarray(self._scores)
+            # its own name: this copy runs later, in the plan window,
+            # and is far larger than the wave-critical kernel.d2h
+            with tracer.span("kernel.d2h.topk") as sp:
+                idx = np.asarray(self._idx)
+                scores = np.asarray(self._scores)
+                sp.set(bytes=idx.nbytes + scores.nbytes)
             profiler.add_bytes("d2h", idx.nbytes + scores.nbytes)
             # counted in the dispatch series but EXCLUDED from the
             # steady dispatches_per_wave key: the drain runs in the
@@ -458,9 +466,17 @@ class WaveStats:
         self.deadline_launches = 0
         self.members_sum = 0
         self.slots_sum = 0
+        # placement steps handed to the device, real and padded, of
+        # EVERY launch (a lone one too), and the members the scheduler
+        # was placing again (a retry against a refreshed state)
+        self.steps_sum = 0
+        self.padded_steps_sum = 0
+        self.relaunched_members_sum = 0
         self._park_s: deque = deque(maxlen=4096)
 
-    def observe_wave(self, members: int, deadline_fired: bool) -> None:
+    def observe_wave(self, members: int, deadline_fired: bool,
+                     steps: int = 0, padded_steps: int = 0,
+                     relaunched: int = 0) -> None:
         with self._lock:
             self.launches += 1
             self.members_sum += members
@@ -469,6 +485,18 @@ class WaveStats:
                 self.deadline_launches += 1
             else:
                 self.full_launches += 1
+            self.steps_sum += steps
+            self.padded_steps_sum += padded_steps
+            self.relaunched_members_sum += relaunched
+
+    def observe_lone(self, steps: int, padded_steps: int,
+                     relaunched: bool) -> None:
+        """A launch outside any wave (ops/kernel.default_kernel_launch):
+        its steps count, it is no wave and fills no slots."""
+        with self._lock:
+            self.steps_sum += steps
+            self.padded_steps_sum += padded_steps
+            self.relaunched_members_sum += int(relaunched)
 
     def observe_park(self, seconds: float) -> None:
         with self._lock:
@@ -486,6 +514,9 @@ class WaveStats:
             self.deadline_launches = 0
             self.members_sum = 0
             self.slots_sum = 0
+            self.steps_sum = 0
+            self.padded_steps_sum = 0
+            self.relaunched_members_sum = 0
             self._park_s.clear()
 
     def snapshot(self) -> dict:
@@ -567,8 +598,9 @@ def _fused_fetch(fout, t_pad: int, b_pad: int):
     "wave_fetch" dispatch count: profiler.call already blocked on the
     fused program's outputs, so the copy rides the dispatch's own
     synchronization instead of being another device interaction."""
-    with tracer.span("kernel.d2h"):
+    with tracer.span("kernel.d2h") as sp:
         packed = np.asarray(fout.packed)
+        sp.set(bytes=packed.nbytes)
     profiler.add_bytes("d2h", packed.nbytes)
     host = unpack_fused_wave(packed, t_pad, b_pad)
     return host, _WaveTopK(fout.topk_idx, fout.topk_scores)
@@ -582,10 +614,21 @@ def _oldest_inflight_age_s() -> float:
     return time.perf_counter() - oldest
 
 
+def wave_step_pad(members: int, k_max: int) -> int:
+    """Padded step count of a wave's device program: sized from the
+    PADDED wave so the compiled shape depends only on (wave bucket,
+    step bucket, features)."""
+    return pad_steps(pad_wave(members) * k_max)
+
+
 def launch_wave(kins: List[KernelIn], k_steps: List[int],
                 features: List[KernelFeatures],
-                mesh=_USE_GLOBAL) -> List[KernelOut]:
+                mesh=_USE_GLOBAL,
+                origins: Optional[List[Optional[LaunchOrigin]]] = None,
+                deadline_fired: bool = False) -> List[KernelOut]:
     """Fire B launch requests as ONE joint device call; split results.
+    One ``wave.launch`` span, the launch record, covers it: assembly,
+    the call, the wait and the copies are its children.
 
     The wave runs the joint kernel (ops/kernel.place_taskgroups_joint):
     members' placement steps execute in arrival order over a shared
@@ -596,8 +639,19 @@ def launch_wave(kins: List[KernelIn], k_steps: List[int],
     passes its server's choice explicitly — including None for "this
     server opted out" — so co-resident servers never fight over the
     module global; only DIRECT calls (no mesh argument) fall back to
-    ``configure_wave_mesh``'s global.
+    ``configure_wave_mesh``'s global. ``origins`` (who asked, per
+    member) and ``deadline_fired`` only feed the launch record.
     """
+    seq = next(launch_seq)
+    attrs = (launch_attrs(seq, k_steps, origins, deadline_fired)
+             if tracer.enabled else None)
+    with tracer.span("wave.launch", attrs=attrs) as record:
+        return _launch_wave(kins, k_steps, features, mesh, record)
+
+
+def _launch_wave(kins: List[KernelIn], k_steps: List[int],
+                 features: List[KernelFeatures], mesh,
+                 record) -> List[KernelOut]:
     if mesh is _USE_GLOBAL:
         mesh = _WAVE_MESH
     # wave-launch seam (chaos plane): an injected failure lands on
@@ -681,7 +735,7 @@ def launch_wave(kins: List[KernelIn], k_steps: List[int],
         # features) — retry waves of any real size reuse it; inert
         # steps are microseconds of device time. Built vectorized:
         # the per-member python loop showed up at bench wave sizes.
-        t_pad = pad_steps(b_pad * k_max)
+        t_pad = wave_step_pad(len(kins), k_max)
         ks = np.asarray(k_steps, np.int64)
         starts = np.concatenate(([0], np.cumsum(ks)[:-1]))
         offsets = starts.tolist()
@@ -704,6 +758,12 @@ def launch_wave(kins: List[KernelIn], k_steps: List[int],
     fused_ok = (fused_route and fused_wave_supported(feats)
                 and (not wave_sharded
                      or n_nodes // mesh_size >= TOPK))
+    if wave_sharded:
+        program = "fused_wave_sharded" if fused_ok else "joint_sharded"
+    else:
+        program = "fused_wave" if fused_ok else "joint"
+    record.set(program=program, slots=b_pad, padded_steps=t_pad,
+               features=features_key(feats))
     t_launch = time.perf_counter()
     token = object()
     with _INFLIGHT_LOCK:
@@ -726,13 +786,11 @@ def launch_wave(kins: List[KernelIn], k_steps: List[int],
             # (the profiler's explicit upload would otherwise commit
             # them to one device and the call would pay a reshard);
             # step planes ship replicated, raw numpy on purpose
-            kernel, entry = (
-                ("fused_wave_sharded", fused_sharded_entry) if fused_ok
-                else ("joint_sharded", joint_sharded_entry))
+            entry = fused_sharded_entry if fused_ok else joint_sharded_entry
             fn, kin_shardings, repl = entry(
                 mesh, shareable, neutral_shareable, job_shareable)
             out = profiler.call(
-                kernel, fn,
+                program, fn,
                 (stacked, step_member, step_local),
                 (t_pad, feats),
                 wave_key + (tuple(mesh.devices.flat),), jit_fn=fn,
@@ -761,7 +819,7 @@ def launch_wave(kins: List[KernelIn], k_steps: List[int],
                 # had a fused route, ran the composite (unsupported
                 # feature union or narrow shard)
                 fused_wave_stats.note_fallback()
-            with tracer.span("kernel.d2h"):
+            with tracer.span("kernel.d2h") as sp:
                 # fetch ONLY the planes members consume immediately:
                 # the per-step placements and the per-member metric
                 # scalars. The joint kernel's final capacity carry
@@ -774,11 +832,12 @@ def launch_wave(kins: List[KernelIn], k_steps: List[int],
                     f: np.asarray(getattr(out, f))
                     for f in _JOINT_FETCH_FIELDS
                 }
+                d2h_bytes = sum(a.nbytes for a in host.values())
+                sp.set(bytes=d2h_bytes)
             # the composite's wave-critical result drain is its own
             # device interaction on top of the program dispatch
             profiler.count_dispatch("wave_fetch")
-            profiler.add_bytes(
-                "d2h", sum(a.nbytes for a in host.values()))
+            profiler.add_bytes("d2h", d2h_bytes)
             wave_topk = _WaveTopK(out.topk_idx, out.topk_scores)
     finally:
         with _INFLIGHT_LOCK:
@@ -806,12 +865,15 @@ def launch_wave(kins: List[KernelIn], k_steps: List[int],
 
 
 class _Request:
-    __slots__ = ("kin", "k_steps", "features", "out", "error", "event")
+    __slots__ = ("kin", "k_steps", "features", "origin", "out", "error",
+                 "event")
 
-    def __init__(self, kin, k_steps, features):
+    def __init__(self, kin, k_steps, features, origin=None):
         self.kin = kin
         self.k_steps = k_steps
         self.features = features
+        #: who asked (LaunchOrigin), for the launch record only
+        self.origin = origin
         self.out: Optional[KernelOut] = None
         self.error: Optional[BaseException] = None
         self.event = threading.Event()
@@ -907,8 +969,9 @@ class LaunchCoalescer:
         return min(max(target, self.window_min_s), self.window_max_s)
 
     def launch(self, kin: KernelIn, k_steps: int,
-               features: KernelFeatures) -> KernelOut:
-        req = _Request(kin, k_steps, features)
+               features: KernelFeatures,
+               origin: Optional[LaunchOrigin] = None) -> KernelOut:
+        req = _Request(kin, k_steps, features, origin)
         wave: Optional[List[_Request]] = None
         with self._cv:
             self.requests += 1
@@ -1009,15 +1072,21 @@ class LaunchCoalescer:
         for grp in groups.values():
             self.launches += 1
             self.max_wave = max(self.max_wave, len(grp))
-            wave_stats.observe_wave(len(grp), deadline_fired)
+            k_steps = [r.k_steps for r in grp]
+            origins = [r.origin for r in grp]
+            wave_stats.observe_wave(
+                len(grp), deadline_fired,
+                steps=sum(real_steps(k_steps, origins)),
+                padded_steps=wave_step_pad(len(grp), max(k_steps)),
+                relaunched=sum(1 for o in origins
+                               if o is not None and o.relaunch))
             try:
-                with tracer.span("wave.launch"):
-                    outs = launch_wave(
-                        [r.kin for r in grp],
-                        [r.k_steps for r in grp],
-                        [r.features for r in grp],
-                        mesh=self.mesh,
-                    )
+                outs = launch_wave(
+                    [r.kin for r in grp], k_steps,
+                    [r.features for r in grp],
+                    mesh=self.mesh, origins=origins,
+                    deadline_fired=deadline_fired,
+                )
                 for r, out in zip(grp, outs):
                     r.out = out
                 # wave-boundary plan batching: the members are about

@@ -112,6 +112,10 @@ class EvalContext:
         # per-eval decorrelation seed for stochastic dynamic-port
         # assignment (network.go:598); None = precise selection
         self.port_seed: Optional[int] = None
+        # which attempt of the evaluation this context serves (0 = the
+        # first; the scheduler's retry loop counts up): past the first
+        # the scheduler is placing again, and says so on its launches
+        self.attempt = 0
         # the placement-kernel dispatch point: defaults to the direct
         # candidate-set/full dispatcher; a batching worker injects a
         # LaunchCoalescer so concurrent evals share one joint launch

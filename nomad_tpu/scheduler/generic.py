@@ -81,6 +81,7 @@ class GenericScheduler(Scheduler):
         self.queued_allocs: Dict[str, int] = {}
         self.followup_evals: List[Evaluation] = []
         self._cluster: Optional[ClusterTensors] = None
+        self._attempts = 0
 
     # -- entry (generic_sched.go:144 Process) ----------------------------
 
@@ -132,6 +133,8 @@ class GenericScheduler(Scheduler):
         self.failed_tg_allocs = {}
         self.ctx = EvalContext(self.state, self.plan, events_cb=self.events_cb,
                                kernel_launch=self.kernel_launch)
+        self.ctx.attempt = self._attempts
+        self._attempts += 1
         self._cluster = self._build_cluster()
         self.stack = XLAGenericStack(self.batch, self.ctx, self._cluster)
         # decorrelate concurrent evals' tie-breaking (shuffleNodes

@@ -25,6 +25,7 @@ from nomad_tpu.ops.kernel import (
     MAX_PENALTY_NODES,
     NEG_INF,
     KernelOut,
+    LaunchOrigin,
     build_kernel_in,
     infer_features,
     neutral_planes,
@@ -184,7 +185,13 @@ class XLAGenericStack:
                 any_preferred=any(requests[ri].preferred_node for ri in pending),
                 with_shuffle=node_perm is not None,
             )
-            out = self.ctx.kernel_launch(kin, k_pad, features)
+            out = self.ctx.kernel_launch(
+                kin, k_pad, features,
+                origin=LaunchOrigin(
+                    eval_id=self.ctx.plan.eval_id,
+                    state_index=snapshot.latest_index(),
+                    steps=len(pending),
+                    relaunch=self.ctx.attempt > 0 or _attempt > 0))
             # selective host fetch: the planes the walk reads NOW come
             # to host (tiny [K] vectors — one transfer each); the
             # top-k score planes stay as the launcher handed them

@@ -66,6 +66,7 @@ class SystemScheduler(Scheduler):
         self.sysbatch = sysbatch
         self.events_cb = events_cb
         self.kernel_launch = kernel_launch
+        self._attempts = 0
         self.cluster_provider = cluster_provider
         self.eval: Optional[Evaluation] = None
         self.job = None
@@ -106,6 +107,8 @@ class SystemScheduler(Scheduler):
         self.queued_allocs = {}
         self.ctx = EvalContext(self.state, self.plan, events_cb=self.events_cb,
                                kernel_launch=self.kernel_launch)
+        self.ctx.attempt = self._attempts
+        self._attempts += 1
         # decorrelate concurrent evals' dynamic-port picks, like the
         # generic scheduler (network.go:598 stochastic selection)
         import zlib

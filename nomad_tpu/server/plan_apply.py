@@ -39,6 +39,7 @@ mixes).
 
 from __future__ import annotations
 
+import itertools
 import threading
 import time
 from concurrent.futures import ThreadPoolExecutor
@@ -50,6 +51,7 @@ from nomad_tpu.structs.eval_plan import Plan, PlanResult
 from nomad_tpu.structs.resources import allocs_fit
 from nomad_tpu.server.plan_queue import PendingPlan, PlanQueue
 from nomad_tpu.telemetry.histogram import histograms
+from nomad_tpu.telemetry.kernel_profile import profiler
 from nomad_tpu.telemetry.trace import tracer
 from nomad_tpu.utils.faultpoints import fault
 from nomad_tpu.utils.witness import witness_lock
@@ -706,6 +708,21 @@ class _GroupFitChecker:
         return cpu <= cap[0] and mem <= cap[1] and disk <= cap[2]
 
 
+def _pass_attrs(pass_no: int, plans: List[Plan],
+                results: Optional[List["PlanResult"]] = None) -> Dict:
+    """What one applier pass was about, for its spans: the plans it
+    took, whose evaluations they are, and the allocations asked for
+    (or, given ``results``, committed)."""
+    placed = results if results is not None else plans
+    return {
+        "pass": pass_no,
+        "evals": [p.eval_id for p in plans],
+        "plans": len(plans),
+        "allocs": sum(len(a) for x in placed
+                      for a in x.node_allocation.values()),
+    }
+
+
 class Planner:
     """The plan-apply loop (plan_apply.go:71 planApply)."""
 
@@ -748,6 +765,10 @@ class Planner:
         self.plans_duplicate_slot = 0
         self.stage_s = {"queue_wait": 0.0, "evaluate": 0.0, "commit": 0.0,
                         "commit_wait": 0.0}
+        # applier passes so far: the spans of one pass (plan.evaluate,
+        # plan.group_commit, plan.commit) say which it was, so that the
+        # commit thread's span pairs with its evaluation by number
+        self._passes = itertools.count(1)
         # persistent re-check pool (plan_apply_pool.go:18 EvaluatePool)
         self._pool = (
             ThreadPoolExecutor(
@@ -818,8 +839,12 @@ class Planner:
             t_eval = time.perf_counter()
             evaluated: List[Tuple[PendingPlan, PlanResult, int]] = []
             snapshot = _LiveView(self.state, overlay)
-            with tracer.span("plan.evaluate"), \
-                    tracer.span("plan.group_commit"):
+            pass_no = next(self._passes)
+            eval_id = batch[0].plan.eval_id
+            attrs = (_pass_attrs(pass_no, [p.plan for p in batch])
+                     if tracer.enabled else None)
+            with tracer.span("plan.evaluate", eval_id, attrs), \
+                    tracer.span("plan.group_commit", eval_id, attrs):
                 # ONE planes snapshot + overlay fold re-validates the
                 # whole wave; per-node exact walks survive only as the
                 # unprovable-case fallback (counted, CI-gated to 0 on
@@ -863,7 +888,7 @@ class Planner:
                 self.stage_s["commit_wait"] += time.perf_counter() - t_wait
             in_flight = threading.Thread(
                 target=self._apply_batch_async,
-                args=(evaluated, overlay),
+                args=(evaluated, overlay, pass_no),
                 daemon=True, name="plan-commit",
             )
             in_flight.start()
@@ -874,10 +899,15 @@ class Planner:
         self,
         evaluated: List[Tuple[PendingPlan, PlanResult, int]],
         overlay: _PlanOverlay,
+        pass_no: int,
     ) -> None:
         try:
             t0 = time.perf_counter()
-            with tracer.span("plan.commit"):
+            attrs = (_pass_attrs(pass_no, [p.plan for p, _r, _t in evaluated],
+                                 [r for _p, r, _t in evaluated])
+                     if tracer.enabled else None)
+            with tracer.span("plan.commit", evaluated[0][0].plan.eval_id,
+                             attrs):
                 index = self._commit_batch(
                     [(p.plan, r) for p, r, _ in evaluated])
             commit_dur = time.perf_counter() - t0
@@ -916,7 +946,10 @@ class Planner:
         snapshot = _LiveView(self.state, overlay)
         checker = _GroupFitChecker(self.state, overlay)
         results: List[PlanResult] = []
-        with tracer.span("plan.group_commit"):
+        attrs = (_pass_attrs(next(self._passes), plans)
+                 if tracer.enabled and plans else None)
+        with tracer.span("plan.group_commit",
+                         plans[0].eval_id if plans else "", attrs):
             for plan in plans:
                 result = self.evaluate_plan_group(checker, snapshot, plan)
                 overlay.add(result)
@@ -948,10 +981,12 @@ class Planner:
         ]
         req = {"alloc_index": self.state.latest_index(), "plans": reqs}
         n_bytes = 0
-        if tracer.enabled:
+        if profiler.enabled:
             # the wire weight of the batched raft entry (its alloc
-            # payload — what a real log would ship); measured only with
-            # telemetry on, off the wave-critical path (commit thread)
+            # payload — what a real log would ship). Serializing 300
+            # allocations takes some 10 ms of this thread, so it hangs
+            # on the profiler's switch (telemetry.enable()), never on
+            # the tracer's: the tracer only records
             try:
                 import pickle
 

@@ -29,6 +29,7 @@ from nomad_tpu.server.worker import Worker
 from nomad_tpu.state.store import StateStore
 from nomad_tpu.structs import consts
 from nomad_tpu.structs.eval_plan import Evaluation, Plan, PlanResult
+from nomad_tpu.telemetry.trace import tracer
 from nomad_tpu.utils.faultpoints import fault
 
 LOG = logging.getLogger(__name__)
@@ -519,7 +520,12 @@ class Server:
                         break
                     if self._shutdown.wait(0.5):
                         return
-                gc.collect()
+                # a full pass holds the interpreter lock for its whole
+                # length (seconds at 10,000 nodes): every thread of
+                # the process stops, so the span says where the time
+                # of whatever was open then went
+                with tracer.span("gc.collect"):
+                    gc.collect()
 
         threading.Thread(target=maintain, daemon=True,
                          name="interpreter-gc").start()
@@ -773,8 +779,6 @@ class Server:
                     term=self.raft.current_term)
 
     def _leader_loop(self, fn, interval: float, gen: int) -> None:
-        from nomad_tpu.telemetry.trace import tracer
-
         span_name = "bg." + fn.__name__
         while (
             self._leader
@@ -1513,8 +1517,6 @@ class Server:
 
     def submit_plan(self, plan: Plan) -> PlanResult:
         import time as _time
-
-        from nomad_tpu.telemetry.trace import tracer
 
         err = self._validate_plan_token(plan)
         if err:

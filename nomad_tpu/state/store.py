@@ -49,6 +49,7 @@ from nomad_tpu.state.pmap import EMPTY, PMap, TOMBSTONE, pmap_diff
 from nomad_tpu.structs import consts
 from nomad_tpu.structs.alloc import Allocation
 from nomad_tpu.structs.eval_plan import Deployment, Evaluation, Plan, PlanResult
+from nomad_tpu.telemetry.trace import tracer
 from nomad_tpu.utils.witness import witness_lock
 
 
@@ -954,17 +955,22 @@ class StateStore:
     def _commit(self, txn: _WriteTxn) -> None:
         """Fold one txn's overlays into a new root and publish it.
         Caller holds the write lock."""
-        self._publish_root(
-            txn.base, txn.overlays,
-            {t: txn.index for t in txn.notify}, txn.index,
-            txn.scheduler_config, txn.autopilot_config)
+        with tracer.span("store.txn", attrs=_txn_attrs(txn.overlays)
+                         if tracer.enabled else None):
+            self._publish_root(
+                txn.base, txn.overlays,
+                {t: txn.index for t in txn.notify}, txn.index,
+                txn.scheduler_config, txn.autopilot_config)
 
     def _commit_batch(self, batch: _BatchTxn) -> None:
         """Fold the whole accumulator into ONE new root. Caller holds
         the write lock."""
-        self._publish_root(
-            batch.base, batch.overlays, batch.notify_indexes,
-            batch.index, batch.scheduler_config, batch.autopilot_config)
+        with tracer.span("store.txn", attrs=_txn_attrs(batch.overlays)
+                         if tracer.enabled else None):
+            self._publish_root(
+                batch.base, batch.overlays, batch.notify_indexes,
+                batch.index, batch.scheduler_config,
+                batch.autopilot_config)
 
     def _publish_root(self, base: StoreRoot, overlays: Dict[str, Dict],
                       notify_indexes: Dict[str, int], index: int,
@@ -2076,6 +2082,12 @@ class StateStore:
             txn.notify = (["allocs", "deployment"] if dep_touched
                           else ["allocs"])
         return txn.index
+
+
+def _txn_attrs(overlays: Dict[str, Dict]) -> Dict:
+    """What a ``store.txn`` span wrote: the tables and the rows."""
+    return {"tables": sorted(overlays),
+            "rows": sum(len(o) for o in overlays.values())}
 
 
 def _record_write_txn(dt: float) -> None:

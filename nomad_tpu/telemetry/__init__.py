@@ -1,16 +1,23 @@
 """Eval-lifecycle tracing + TPU kernel profiling.
 
-The subsystem the BENCH_r05 gap analysis was missing: spans across the
-full eval hot path (broker dequeue -> worker batch -> snapshot -> wave
-assembly -> kernel launch -> plan submit -> plan apply -> FSM), a
-JAX-level wave profiler (h2d / compile / dispatch / execute / d2h, jit
-cache-miss accounting per bucket shape), and exposition through
-``/v1/metrics`` + ``/v1/operator/traces``.
+Spans across the full eval hot path (HTTP handler -> broker dequeue ->
+worker batch -> snapshot -> wave assembly -> device launch -> plan
+submit -> plan apply -> FSM -> store), each with optional attributes;
+one launch record (``wave.launch``) per device launch; exposition
+through ``/v1/metrics`` + ``/v1/operator/traces``.
 
-Disabled by default; ``telemetry.enable()`` (or
-``NOMAD_TPU_TRACE=1`` in the environment) turns both the tracer and
-the kernel profiler on. Disabled-mode cost on the hot path is one
-attribute check per span site.
+Two switches. The *tracer* records and does nothing else: no wait, no
+upload, no branch of the program hangs on it, so a traced run is the
+same program. It alone gives the launch its ``kernel.compile`` /
+``kernel.dispatch`` / ``kernel.execute`` / ``kernel.d2h`` spans. The
+kernel *profiler* adds what costs something: an explicit upload of the
+host leaves before each launch (``kernel.h2d``) and launch / jit
+cache-miss counts per bucket shape, cross-checked against the jit
+cache's growth. ``telemetry.enable()``, ``NOMAD_TPU_TRACE=1`` and
+``PUT /v1/operator/traces`` turn both on; the benchmark turns on the
+tracer alone (``tracer.enable()``) and keeps the profiler off.
+Disabled-mode cost on the hot path is one attribute check per span
+site.
 """
 
 from __future__ import annotations

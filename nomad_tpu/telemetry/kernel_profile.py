@@ -1,41 +1,56 @@
-"""JAX-level instrumentation of placement kernel waves.
+"""JAX-level instrumentation of placement kernel launches.
 
-Decomposes every coalesced wave launch into the stages that actually
-cost wall time on an accelerator backend:
+Every device launch (``joint``, ``joint_sharded`` / ``fused_wave_sharded``,
+``fused_wave``, ``single_full``, ``single_topk``) goes through
+``KernelProfiler.call``. With the *tracer* on, profiler on or off, the
+call records under the caller's ``wave.launch``:
 
-- ``kernel.h2d``     host->device upload of the stacked wave planes
-- ``kernel.compile`` jit trace + XLA compile (first call per
-                     (kernel, bucket-shape) key — a cold TPU compile is
-                     tens of seconds and MUST be visible, not smeared)
-- ``kernel.dispatch``the async dispatch of an already-compiled program
-- ``kernel.execute`` device execution (``block_until_ready``)
+- ``kernel.compile`` jit trace + XLA compile: the call into the jitted
+                     function when its jit cache grew (a cold TPU
+                     compile is tens of seconds and MUST be visible,
+                     not smeared)
+- ``kernel.dispatch``the same call when the program was compiled
+                     already: the async dispatch
+- ``kernel.execute`` ``block_until_ready`` on the outputs. Not the
+                     tracer's doing: the call always waits there,
+                     because its callers read the outputs next, and
+                     the program must do the same thing traced and not
 
-(``kernel.d2h`` — the device->host fetch of the wave result — is
-recorded by the caller around its result unpacking.)
+(``kernel.d2h``, the copies of the results to the host, is recorded by
+the caller around its fetch.)
 
-The profiler also counts jit cache misses per (kernel, key): the live
-path is bucketed precisely so that repeated waves REUSE compiled
-programs, and a miss counter per bucket shape is the direct test of
-that claim (BENCH_r05's open question: is the TPU live-path gap
-recompilation?). A miss is classified first by the profiler's own seen
-set and cross-checked against the jit function's cache size when the
-runtime exposes it (``_cache_size``), so bucket-key bugs (two keys
-mapping to one program, or one key recompiling) show up as
-``misses != cache_growth``.
+The *profiler* (off in the benchmark: it changes the launch path) adds
+two things over the tracer:
 
-When disabled, ``profiled_call`` runs the plain call — same arguments,
-same upload behavior (jit uploads host numpy leaves once at call time),
-zero added device synchronization.
+- ``kernel.h2d``: it uploads the host leaves itself and waits for them
+  before the call, so "is it transfer?" is answerable; without it jit
+  uploads them inside the dispatch.
+- jit cache misses per (kernel, key): the live path is bucketed
+  precisely so that repeated waves REUSE compiled programs, and a miss
+  counter per bucket shape is the direct test of that claim. A miss is
+  classified first by the profiler's own seen set and cross-checked
+  against the jit function's cache size when the runtime exposes it
+  (``_cache_size``), so bucket-key bugs (two keys mapping to one
+  program, or one key recompiling) show up as
+  ``misses != cache_growth``.
 """
 
 from __future__ import annotations
 
+import itertools
 import threading
+import time
 from typing import Callable, Dict, Optional, Tuple
 
 from nomad_tpu.telemetry.trace import tracer
 
-__all__ = ["KernelProfiler", "profiler", "profiled_call"]
+__all__ = ["KernelProfiler", "profiler", "profiled_call", "launch_seq"]
+
+#: process-wide sequence number of device launches, taken by whoever
+#: opens a ``wave.launch`` (the wave launcher, the lone dispatch): the
+#: order in which this process handed programs to the device, which is
+#: the order its launches appear in on a device trace
+launch_seq = itertools.count(1)
 
 
 class KernelProfiler:
@@ -165,38 +180,93 @@ class KernelProfiler:
     def call(self, kernel: str, fn: Callable, dev_args: tuple,
              static_args: tuple, key: tuple, jit_fn=None,
              shardings=None):
-        """Run ``fn(*dev_args, *static_args)`` decomposed into h2d /
-        compile-or-dispatch / execute stages. ``dev_args`` is the array
-        pytree uploaded to the device; ``static_args`` (jit static
-        argnums — step bucket, feature set) pass through untouched.
+        """Run ``fn(*dev_args, *static_args)`` and wait for its outputs:
+        the one seam every device launch shares. ``dev_args`` is the
+        array pytree the program reads; ``static_args`` (jit static
+        argnums: step bucket, feature set) pass through untouched.
+
+        The tracer alone times the call (``kernel.compile`` when the
+        jit cache grew, else ``kernel.dispatch``) and the wait
+        (``kernel.execute``); it changes nothing about either. The
+        profiler adds the explicit upload (``kernel.h2d``: host leaves
+        put on the device and waited for BEFORE the call) and the
+        per-key launch and miss counts; the benchmark keeps it off.
+
         ``key`` is the bucket-shape identity the compile cache SHOULD
         be keyed by; ``jit_fn`` (when it differs from ``fn``, e.g. a
         sharded wrapper) is the object whose ``_cache_size`` is
-        consulted for the cross-check. ``shardings`` (a pytree
-        matching ``dev_args``) places host leaves at upload time — a
-        sharded wave's explicit h2d must land each leaf with the jit's
-        in_shardings, or the call would pay a hidden reshard."""
-        if not self._enabled:
-            return fn(*dev_args, *static_args)
-        import time
-
+        consulted. ``shardings`` (a pytree matching ``dev_args``)
+        places host leaves at upload time: a sharded wave's explicit
+        h2d must land each leaf with the jit's in_shardings, or the
+        call would pay a hidden reshard."""
         import jax
 
-        # a jit function's own cache size; a plain callable (tests
-        # profile fakes) has none and is classified by the seen set
-        probe = jit_fn if jit_fn is not None else fn
-        size_fn = getattr(probe, "_cache_size", None)
-        size0 = size_fn() if size_fn is not None else None
+        profiling = self._enabled
+        if not profiling and not tracer.enabled:
+            out = fn(*dev_args, *static_args)
+        else:
+            if profiling:
+                dev_args = self._upload(dev_args, shardings)
+            # a jit function's own cache size; a plain callable (tests
+            # profile fakes) has none and is classified by the
+            # profiler's seen set
+            probe = jit_fn if jit_fn is not None else fn
+            size_fn = getattr(probe, "_cache_size", None)
+            size0 = size_fn() if size_fn is not None else None
+            full_key = (kernel, key)
+            seen = True
+            if profiling:
+                with self._lock:
+                    seen = full_key in self._launches
+                    self._launches[full_key] = \
+                        self._launches.get(full_key, 0) + 1
+                    self.dispatches[kernel] = \
+                        self.dispatches.get(kernel, 0) + 1
+            t0 = time.perf_counter()
+            out = fn(*dev_args, *static_args)
+            call_s = time.perf_counter() - t0
+            # a miss is OBSERVED growth of the jit function's cache
+            # (survives profiler resets against a warm jit cache). A
+            # key we bucketed as seen that grows the cache anyway is
+            # the exact bug class this counter exists to expose (two
+            # shapes under one bucket key).
+            grew = 0 if size0 is None else max(size_fn() - size0, 0)
+            miss = bool(grew) if size0 is not None else not seen
+            stage = "compile" if miss else "dispatch"
+            tracer.record(f"kernel.{stage}", call_s)
+            if profiling:
+                self._bump_stage(stage, call_s)
+                with self._lock:
+                    if miss:
+                        self._misses[full_key] = \
+                            self._misses.get(full_key, 0) + 1
+                    self.cache_growth += grew
+        # the wait, traced or not: every caller reads the outputs next
+        # (np.asarray, bool), which would block here anyway
+        with tracer.span("kernel.execute"):
+            t0 = time.perf_counter()
+            jax.block_until_ready(out)
+            wait_s = time.perf_counter() - t0
+        if profiling:
+            self._bump_stage("execute", wait_s)
+            devs = {d for x in jax.tree_util.tree_leaves(out)
+                    if isinstance(x, jax.Array) for d in x.devices()}
+            with self._lock:
+                self.output_devices.setdefault(kernel, set()).update(devs)
+        return out
 
-        # explicit upload: jit would upload the host numpy leaves
-        # transparently inside the call; splitting it out is what makes
-        # "is it transfer?" answerable. Leaves that are already device
-        # arrays (the resident cluster state) skip device_put entirely
-        # — only host leaves pay PCIe, so only they are uploaded,
-        # blocked on, and byte-metered. One flatten + ONE batched
-        # device_put: on a firing thread racing B eval threads for the
-        # GIL, every extra per-leaf python round trip is a potential
-        # 5ms switch-interval stall inside this span.
+    def _upload(self, dev_args: tuple, shardings):
+        """The profiler's explicit upload: jit would upload the host
+        numpy leaves transparently inside the call; splitting it out
+        is what makes "is it transfer?" answerable. Leaves that are
+        already device arrays (the resident cluster state) skip
+        device_put entirely: only host leaves pay PCIe, so only they
+        are uploaded, blocked on, and byte-metered. One flatten + ONE
+        batched device_put: on a firing thread racing B eval threads
+        for the GIL, every extra per-leaf python round trip is a
+        potential 5ms switch-interval stall inside this span."""
+        import jax
+
         leaves, treedef = jax.tree_util.tree_flatten(dev_args)
         host_idx = [i for i, x in enumerate(leaves)
                     if not isinstance(x, jax.Array)]
@@ -220,42 +290,8 @@ class KernelProfiler:
                 for i, v in zip(host_idx, put):
                     leaves[i] = v
             self._bump_stage("h2d", time.perf_counter() - t0)
-        dev_args = jax.tree_util.tree_unflatten(treedef, leaves)
         self.add_bytes("h2d", up_bytes)
-
-        full_key = (kernel, key)
-        with self._lock:
-            seen = full_key in self._launches
-            self._launches[full_key] = self._launches.get(full_key, 0) + 1
-            self.dispatches[kernel] = self.dispatches.get(kernel, 0) + 1
-        t0 = time.perf_counter()
-        out = fn(*dev_args, *static_args)
-        call_s = time.perf_counter() - t0
-
-        # a miss is OBSERVED growth of the jit function's cache
-        # (survives profiler resets against a warm jit cache). A key
-        # we bucketed as seen that grows the cache anyway is the exact
-        # bug class this counter exists to expose (two shapes under
-        # one bucket key).
-        grew = 0 if size0 is None else max(size_fn() - size0, 0)
-        miss = bool(grew) if size0 is not None else not seen
-        stage = "compile" if miss else "dispatch"
-        tracer.record(f"kernel.{stage}", call_s)
-        self._bump_stage(stage, call_s)
-        with self._lock:
-            if miss:
-                self._misses[full_key] = self._misses.get(full_key, 0) + 1
-            self.cache_growth += grew
-
-        with tracer.span("kernel.execute"):
-            t0 = time.perf_counter()
-            jax.block_until_ready(out)
-            self._bump_stage("execute", time.perf_counter() - t0)
-        devs = {d for x in jax.tree_util.tree_leaves(out)
-                if isinstance(x, jax.Array) for d in x.devices()}
-        with self._lock:
-            self.output_devices.setdefault(kernel, set()).update(devs)
-        return out
+        return jax.tree_util.tree_unflatten(treedef, leaves)
 
     def _bump_stage(self, stage: str, dur_s: float) -> None:
         with self._lock:

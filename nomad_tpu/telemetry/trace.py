@@ -59,12 +59,13 @@ class Span:
     device-blocking stages, so neither is double counted."""
 
     __slots__ = ("name", "trace_id", "span_id", "parent_id", "start_s",
-                 "dur_s", "child_s", "cpu_s", "child_cpu_s", "thread")
+                 "dur_s", "child_s", "cpu_s", "child_cpu_s", "thread",
+                 "attrs")
 
     def __init__(self, name: str, trace_id: str, span_id: int,
                  parent_id: int, start_s: float, dur_s: float,
                  child_s: float, cpu_s: float, child_cpu_s: float,
-                 thread: str) -> None:
+                 thread: str, attrs: Optional[Dict] = None) -> None:
         self.name = name
         self.trace_id = trace_id
         self.span_id = span_id
@@ -75,6 +76,10 @@ class Span:
         self.cpu_s = cpu_s
         self.child_cpu_s = child_cpu_s
         self.thread = thread
+        #: what the span was about (a launch's members, a commit's
+        #: plans): small JSON-able values, or None. The LAST positional
+        #: field, so ten-field rows from before it still rebuild.
+        self.attrs = attrs
 
     @property
     def exclusive_s(self) -> float:
@@ -86,7 +91,7 @@ class Span:
 
     def to_api(self) -> Dict:
         """The wire shape /v1/operator/traces serves."""
-        return {
+        out = {
             "Name": self.name,
             "TraceID": self.trace_id,
             "SpanID": self.span_id,
@@ -98,6 +103,9 @@ class Span:
             "ExclusiveCpuMs": round(self.exclusive_cpu_s * 1e3, 4),
             "Thread": self.thread,
         }
+        if self.attrs:
+            out["Attrs"] = self.attrs
+        return out
 
 
 class _NoopSpan:
@@ -111,6 +119,9 @@ class _NoopSpan:
     def __exit__(self, *exc) -> None:
         return None
 
+    def set(self, **attrs) -> None:
+        return None
+
 
 _NOOP = _NoopSpan()
 
@@ -119,15 +130,17 @@ class _LiveSpan:
     """An open span on one thread's stack."""
 
     __slots__ = ("tracer", "name", "trace_id", "span_id", "parent_id",
-                 "t0", "c0", "child_s", "child_cpu_s", "sampled")
+                 "t0", "c0", "child_s", "child_cpu_s", "sampled", "attrs")
 
     def __init__(self, tracer: "Tracer", name: str, trace_id: str,
-                 parent_id: int, sampled: bool) -> None:
+                 parent_id: int, sampled: bool,
+                 attrs: Optional[Dict] = None) -> None:
         self.tracer = tracer
         self.name = name
         self.trace_id = trace_id
         self.span_id = next(_ids)
         self.parent_id = parent_id
+        self.attrs = attrs
         self.child_s = 0.0
         self.child_cpu_s = 0.0
         self.t0 = 0.0
@@ -143,6 +156,15 @@ class _LiveSpan:
         # Tracer._calibrate
         self.c0 = time.thread_time() if self.sampled else 0.0
         return self
+
+    def set(self, **attrs) -> None:
+        """Add attributes to the open span: what is known only once
+        the work is under way (which program ran, how many bytes
+        came back)."""
+        if self.attrs is None:
+            self.attrs = attrs
+        else:
+            self.attrs.update(attrs)
 
     def __exit__(self, *exc) -> None:
         # clock geometry on a sampled span: t0 is captured BEFORE the
@@ -270,9 +292,13 @@ class Tracer:
             self._tls.stack = stack
         return stack
 
-    def span(self, name: str, trace_id: str = ""):
+    def span(self, name: str, trace_id: str = "",
+             attrs: Optional[Dict] = None):
         """Open a span. The ONLY hot-path entry point: when disabled it
-        returns a shared no-op without reading the clock."""
+        returns a shared no-op without reading the clock. ``attrs``
+        (and ``set`` on the open span) say what the span was about; a
+        site whose attributes cost something to build builds them only
+        ``if tracer.enabled``, so the disabled path stays one check."""
         if not self._enabled:
             return _NOOP
         stack = self._tls_stack()
@@ -282,16 +308,17 @@ class Tracer:
             # within one tree
             parent = stack[-1]
             return _LiveSpan(self, name, trace_id or parent.trace_id,
-                             parent.span_id, parent.sampled)
+                             parent.span_id, parent.sampled, attrs)
         sampled = self.cpu_sample_every == 1 or (
             next(self._root_seq) % self.cpu_sample_every == 0)
         inherit = getattr(self._tls, "inherit", None)
         if inherit is not None:
             return _LiveSpan(self, name, trace_id or inherit[0],
-                             inherit[1], sampled)
-        return _LiveSpan(self, name, trace_id, 0, sampled)
+                             inherit[1], sampled, attrs)
+        return _LiveSpan(self, name, trace_id, 0, sampled, attrs)
 
-    def record(self, name: str, dur_s: float, trace_id: str = "") -> None:
+    def record(self, name: str, dur_s: float, trace_id: str = "",
+               attrs: Optional[Dict] = None) -> None:
         """Record an already-measured interval as a leaf span (for
         sites that must decide retroactively, e.g. a blocking dequeue
         that only counts when it returned work)."""
@@ -306,13 +333,13 @@ class Tracer:
         # blocking waits); cpu_s=0 keeps them out of CPU attributions
         sp = Span(name, trace_id, next(_ids), parent_id,
                   time.monotonic() - dur_s, dur_s, 0.0, 0.0, 0.0,
-                  threading.current_thread().name)
+                  threading.current_thread().name, attrs)
         self._append(sp, 0)
 
     def _record(self, live: _LiveSpan, dur_s: float, cpu_s: float) -> None:
         sp = Span(live.name, live.trace_id, live.span_id, live.parent_id,
                   live.t0, dur_s, live.child_s, cpu_s, live.child_cpu_s,
-                  threading.current_thread().name)
+                  threading.current_thread().name, live.attrs)
         self._append(sp, self.cpu_sample_every if live.sampled else 0)
 
     def _append(self, sp: Span, cpu_scale: int = 1) -> None:
@@ -362,7 +389,8 @@ class Tracer:
         with self._lock:
             rows = [(s.name, s.trace_id, s.span_id, s.parent_id,
                      s.start_s, s.dur_s, s.child_s, s.cpu_s,
-                     s.child_cpu_s, s.thread) for s in self._ring]
+                     s.child_cpu_s, s.thread, s.attrs)
+                    for s in self._ring]
             self._ring.clear()
         return rows
 

@@ -386,16 +386,18 @@ class DeviceClusterState:
         # own span name: this upload runs on an EVAL thread at
         # snapshot time, overlapping the in-flight wave — the trace
         # decomposition must not sum it into the wave-critical-path
-        # kernel.h2d wall stage
-        with tracer.span("state.h2d"):
+        # kernel.h2d wall stage. It times the ENQUEUE: the tracer never
+        # waits for the device (a wait here made traced runs another
+        # program); the transfer itself is on the device trace
+        with tracer.span("state.h2d") as sp:
             if sharding is None:
                 dev = {f: jax.device_put(a)
                        for f, a in host_planes.items()}
             else:
                 dev = {f: jax.device_put(a, sharding)
                        for f, a in host_planes.items()}
-            if tracer.enabled:
-                jax.block_until_ready(list(dev.values()))
+            sp.set(bytes=n_bytes, rows=max(
+                (a.shape[0] for a in host_planes.values()), default=0))
         profiler.add_bytes("h2d", n_bytes)
         self.bytes_uploaded += n_bytes
         return dev
@@ -425,7 +427,7 @@ class DeviceClusterState:
         rows_p = np.full(rb, n_pad, np.int32)
         rows_p[:len(rows)] = rows
         n_bytes = rows_p.nbytes
-        with tracer.span("state.h2d"):
+        with tracer.span("state.h2d") as sp:
             rows_dev = jax.device_put(rows_p) if repl is None \
                 else jax.device_put(rows_p, repl)
             out = dict(planes)
@@ -436,8 +438,7 @@ class DeviceClusterState:
                 vals_dev = jax.device_put(vals) if repl is None \
                     else jax.device_put(vals, repl)
                 out[f] = scatter(planes[f], rows_dev, vals_dev)
-            if tracer.enabled:
-                jax.block_until_ready(list(out.values()))
+            sp.set(bytes=n_bytes, rows=int(len(rows)))
         profiler.add_bytes("h2d", n_bytes)
         self.bytes_uploaded += n_bytes
         self.rows_uploaded += int(len(rows)) * len(host_planes)

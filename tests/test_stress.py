@@ -139,7 +139,7 @@ class TestCoalescerContention:
         wave fires from whichever thread completes the rendezvous)."""
         from nomad_tpu.parallel import coalesce
 
-        def stub_launch_wave(kins, k_steps, features, mesh=None):
+        def stub_launch_wave(kins, k_steps, features, mesh=None, **_record):
             time.sleep(0.001)
             return [object() for _ in kins]
 

@@ -283,9 +283,17 @@ class TestHTTPEndpoints:
     @pytest.fixture()
     def agent(self):
         from nomad_tpu.api.agent import Agent, AgentConfig
+        from nomad_tpu.server.core_sched import ALL_CORE_JOBS
 
         a = Agent(AgentConfig(serf_enabled=False))
         a.start()
+        # a new leader enqueues its core GC evals at once; let them
+        # finish, so that none records its e2e sample after
+        # clean_telemetry's reset (the histogram counts are exact)
+        deadline = time.time() + 30
+        while sum(w.processed for w in a.server.workers) \
+                < len(ALL_CORE_JOBS) and time.time() < deadline:
+            time.sleep(0.01)
         try:
             yield a
         finally:

@@ -282,7 +282,7 @@ class TestAdaptiveCoalescer:
 
         fired = []
 
-        def stub_launch_wave(kins, k_steps, features, mesh=None):
+        def stub_launch_wave(kins, k_steps, features, mesh=None, **_record):
             fired.append(len(kins))
             return [object() for _ in kins]
 
@@ -328,7 +328,7 @@ class TestAdaptiveCoalescer:
 
         fired = []
 
-        def stub_launch_wave(kins, k_steps, features, mesh=None):
+        def stub_launch_wave(kins, k_steps, features, mesh=None, **_record):
             fired.append(len(kins))
             return [object() for _ in kins]
 
@@ -365,7 +365,7 @@ class TestAdaptiveCoalescer:
     def test_full_wave_still_fires_immediately(self, monkeypatch):
         from nomad_tpu.parallel import coalesce
 
-        def stub_launch_wave(kins, k_steps, features, mesh=None):
+        def stub_launch_wave(kins, k_steps, features, mesh=None, **_record):
             return [object() for _ in kins]
 
         monkeypatch.setattr(coalesce, "launch_wave", stub_launch_wave)
@@ -400,7 +400,7 @@ class TestAdaptiveCoalescer:
         (suspend) must not hold up the remaining members' wave."""
         from nomad_tpu.parallel import coalesce
 
-        def stub_launch_wave(kins, k_steps, features, mesh=None):
+        def stub_launch_wave(kins, k_steps, features, mesh=None, **_record):
             return [object() for _ in kins]
 
         monkeypatch.setattr(coalesce, "launch_wave", stub_launch_wave)
@@ -435,7 +435,7 @@ class TestAdaptiveCoalescer:
         from nomad_tpu.parallel import coalesce
         from nomad_tpu.telemetry.exporter import prometheus_text
 
-        def stub_launch_wave(kins, k_steps, features, mesh=None):
+        def stub_launch_wave(kins, k_steps, features, mesh=None, **_record):
             return [object() for _ in kins]
 
         monkeypatch.setattr(coalesce, "launch_wave", stub_launch_wave)

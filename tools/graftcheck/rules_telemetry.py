@@ -6,9 +6,9 @@ extends it to the whole observable surface:
 - **spans**: every literal ``tracer.span("...")`` /
   ``tracer.record("...")`` name must appear in TELEMETRY.md's
   "## Instrumented spans" fenced table, and every documented span must
-  still be emitted. ``bg.*`` loop spans are dynamic-by-design and
-  covered as a prefix; any other f-string site must be registered in
-  ``DYNAMIC`` with its expansions.
+  still be emitted. ``bg.*`` loop spans and ``http.*`` route-handler
+  spans are dynamic-by-design and covered as a prefix; any other
+  f-string site must be registered in ``DYNAMIC`` with its expansions.
 - **Prometheus series**: every ``nomad_tpu_*`` series literal in the
   code must appear in the "## Prometheus series" fenced list, and vice
   versa (a scraper alerting on a renamed series is an outage, not a
@@ -34,6 +34,10 @@ RULE = "R5"
 
 DOC_REL = "docs/TELEMETRY.md"
 BENCH_REL = "bench.py"
+
+#: span families named from what runs (a background loop, a route
+#: handler): covered as a prefix, never enumerated
+DYNAMIC_PREFIXES = ("bg.", "http.")
 
 #: registered dynamic span-name sites (template with {} placeholders
 #: -> concrete expansions). A new f-string span site must be added
@@ -123,13 +127,13 @@ class TelemetryDriftRule:
                 if isinstance(arg, ast.Constant) and isinstance(arg.value,
                                                                 str):
                     name = arg.value
-                    if not name.startswith("bg."):
+                    if not name.startswith(DYNAMIC_PREFIXES):
                         emitted.setdefault(name, (src.rel, node.lineno))
                 elif isinstance(arg, ast.JoinedStr):
                     template = "".join(
                         v.value if isinstance(v, ast.Constant) else "{}"
                         for v in arg.values)
-                    if template.startswith("bg."):
+                    if template.startswith(DYNAMIC_PREFIXES):
                         continue
                     if template not in DYNAMIC:
                         bad.append(Finding(
