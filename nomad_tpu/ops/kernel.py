@@ -86,9 +86,10 @@ def pad_steps(k: int) -> int:
 #: live-path floor for the placement-axis bucket: a follow-up eval
 #: placing 1-2 leftover allocs used to compile its own tiny step
 #: variant per (wave, k) pair — padding every live launch to at least
-#: 8 steps collapses those onto the primary evals' programs (inactive
-#: steps are a few microseconds of device scan; a cold compile is tens
-#: of seconds)
+#: 8 steps collapses those onto the primary evals' programs. An
+#: inactive step costs what a real one does (every step scores every
+#: node and masks the result afterwards; PERF.md section 5); a cold
+#: compile is tens of seconds
 MIN_STEP_BUCKET = 8
 
 
@@ -222,8 +223,8 @@ def canonical_features(f: KernelFeatures) -> KernelFeatures:
     preferred node. Canonicalization rounds UP onto a coarser lattice:
 
     - ``n_spreads`` is 0 or MAX_SPREADS (inactive stanzas are no-ops
-      by kernel definition, so extra spread slots only cost device
-      time on a tiny [S] axis);
+      by kernel definition; each extra slot costs a few elementwise
+      operations over [N] a step, ``_spread_score``);
     - ``with_step_penalties``/``with_preferred`` travel together (both
       read tiny per-step planes whose neutral rows -1 are no-ops).
 
@@ -455,7 +456,7 @@ def _feasible(kin: KernelIn, st, f: KernelFeatures) -> tuple:
 
 
 def _score(kin: KernelIn, st, ask_cpu_total, penalty,
-           f: KernelFeatures, spread_onehot=None) -> tuple:
+           f: KernelFeatures, spread_des_n=None) -> tuple:
     """Score planes + appended-mask normalization (rank.go semantics)."""
     util_cpu = st["used_cpu"] + ask_cpu_total
     util_mem = st["used_mem"] + kin.ask_mem
@@ -499,7 +500,7 @@ def _score(kin: KernelIn, st, ask_cpu_total, penalty,
 
     # spread (spread.go:116-245)
     if f.n_spreads > 0:
-        spread_total = _spread_score(kin, st, spread_onehot, f.n_spreads)
+        spread_total = _spread_score(kin, st, spread_des_n, f.n_spreads)
         spread_on = spread_total != 0.0
         score_sum = score_sum + jnp.where(spread_on, spread_total, 0.0)
         nplanes = nplanes + spread_on.astype(jnp.float32)
@@ -507,25 +508,53 @@ def _score(kin: KernelIn, st, ask_cpu_total, penalty,
     return score_sum / nplanes
 
 
-def _spread_score(kin: KernelIn, st, spread_onehot,
-                  n_spreads: int) -> jnp.ndarray:
+def _spread_node_planes(kin: KernelIn, n_spreads: int) -> tuple:
+    """The two bucket tables seen from the node axis, derived once per
+    launch: ``cnt_n[s, n] = spread_counts[s, spread_bucket[s, n]]`` and
+    ``des_n`` likewise from ``spread_desired`` (both f32[S, N]).
+
+    One elementwise pass over [S, N] per bucket: a wave of B members
+    holds B x S x N values at any time, never a bucket axis beside the
+    node axis. A bucket-less node (``spread_bucket < 0``) keeps 0 and
+    -1, which nothing uses: it scores the missing penalty and no
+    placement bumps it."""
+    sb = kin.spread_bucket[:n_spreads]
+    counts = kin.spread_counts[:n_spreads]
+    desired = kin.spread_desired[:n_spreads]
+
+    def bucket_pass(k, planes):
+        cnt_n, des_n = planes
+        hit = sb == k
+        return (jnp.where(hit, counts[:, k, None], cnt_n),
+                jnp.where(hit, desired[:, k, None], des_n))
+
+    blank = jnp.zeros(sb.shape, jnp.float32)
+    return jax.lax.fori_loop(0, SPREAD_BUCKETS, bucket_pass,
+                             (blank, blank - 1.0))
+
+
+def _spread_score(kin: KernelIn, st, des_n, n_spreads: int) -> jnp.ndarray:
     """Sum of per-stanza spread boosts for every node.
 
-    TPU formulation: boosts are a function of the node's BUCKET, so
-    compute them over the tiny bucket axis (B=SPREAD_BUCKETS) and
-    scatter to nodes with one one-hot matmul per stanza — the MXU
-    replaces a 10k-wide gather (2x faster measured, and the
-    bucket-axis math is ~100x narrower than node-axis math)."""
+    A node's boost is a function of the count of its OWN bucket and of
+    a few per-stanza scalars, so the scan carries that count on the
+    node axis (``st["spread_cnt_n"]``, f32[S, N], beside the bucket
+    table ``st["spread_counts"]`` that yields the scalars) and the
+    score is elementwise over [N]: nothing in a step has both a node
+    axis and a bucket axis. ``des_n`` is the desired count of each
+    node's bucket (``_spread_node_planes``)."""
     n = kin.cap_cpu.shape[0]
     total = jnp.zeros(n, jnp.float32)
-    counts = st["spread_counts"]  # [S, B]
+    counts = st["spread_counts"]   # [S, B]
+    cnt_n = st["spread_cnt_n"]     # [S, N]
     for s in range(n_spreads):     # static unroll, S is tiny
         counts_b = counts[s]                     # f32[B]
+        cnt = cnt_n[s]                           # f32[N]
         # -- desired-count path (spread.go:158-183): usedCount+1 --
-        des_b = kin.spread_desired[s]            # f32[B], -1 = even mode
-        desired_b = jnp.where(
-            des_b > 0.0,
-            ((des_b - (counts_b + 1.0)) / des_b) * kin.spread_weight[s],
+        des = des_n[s]                           # f32[N], -1 = even mode
+        desired = jnp.where(
+            des > 0.0,
+            ((des - (cnt + 1.0)) / des) * kin.spread_weight[s],
             -1.0,
         )
         # -- even-spread path (spread.go evenSpreadScoreBoost :193) --
@@ -533,11 +562,11 @@ def _spread_score(kin: KernelIn, st, spread_onehot,
         any_alloc = jnp.any(present)
         minc = jnp.min(jnp.where(present, counts_b, jnp.inf))
         maxc = jnp.max(jnp.where(present, counts_b, -jnp.inf))
-        delta_b = jnp.where(
-            minc > 0, (minc - counts_b) / jnp.maximum(minc, 1.0), -1.0)
-        even_b = jnp.where(
-            counts_b != minc,
-            delta_b,
+        delta = jnp.where(
+            minc > 0, (minc - cnt) / jnp.maximum(minc, 1.0), -1.0)
+        even = jnp.where(
+            cnt != minc,
+            delta,
             jnp.where(
                 minc == maxc,
                 -1.0,
@@ -545,17 +574,10 @@ def _spread_score(kin: KernelIn, st, spread_onehot,
                           (maxc - minc) / jnp.maximum(minc, 1.0)),
             ),
         )
-        even_b = jnp.where(any_alloc, even_b, 0.0)
-        stanza_b = jnp.where(kin.spread_even[s], even_b, desired_b)
-        # bucket -> node: one-hot matmul (zero rows for bucket-less
-        # nodes, which score the missing penalty instead). HIGHEST
-        # precision: default TPU matmul rounds f32 through bf16 on the
-        # MXU, which would break Go-score parity on close boosts
-        node_boost = jnp.matmul(
-            spread_onehot[s], stanza_b,
-            precision=jax.lax.Precision.HIGHEST)            # f32[N]
-        missing = kin.spread_bucket[s] < 0
-        stanza = jnp.where(missing, -1.0, node_boost)
+        even = jnp.where(any_alloc, even, 0.0)
+        stanza = jnp.where(kin.spread_even[s], even, desired)
+        # bucket-less nodes score the missing penalty instead
+        stanza = jnp.where(kin.spread_bucket[s] < 0, -1.0, stanza)
         total = total + jnp.where(kin.spread_active[s], stanza, 0.0)
     return total
 
@@ -589,19 +611,11 @@ def place_taskgroup(
         init["dev_free"] = kin.dev_free
     if f.with_distinct:
         init["job_any_count"] = kin.job_any_count
+    spread_des_n = None
     if f.n_spreads > 0:
         init["spread_counts"] = kin.spread_counts
-    # node->bucket one-hot derived on device once per launch (XLA
-    # keeps it live across the scan); 0/1 rows, zero for bucket-less
-    # nodes, so the MXU projections are exact where they must be
-    spread_onehot = None
-    if f.n_spreads > 0:
-        sb = kin.spread_bucket[:f.n_spreads]
-        spread_onehot = (
-            jax.nn.one_hot(jnp.clip(sb, 0, SPREAD_BUCKETS - 1),
-                           SPREAD_BUCKETS, dtype=jnp.float32)
-            * (sb >= 0)[..., None]
-        )
+        init["spread_cnt_n"], spread_des_n = _spread_node_planes(
+            kin, f.n_spreads)
 
     # metrics from the initial state (one extra mask pass, outside scan)
     feas0, _, dims0 = _feasible(kin, init, f)
@@ -618,7 +632,7 @@ def place_taskgroup(
             pen_ids = kin.step_penalty[i]                   # i32[P]
             step_pen = jnp.any(iota[:, None] == pen_ids[None, :], axis=1)
             penalty = penalty | step_pen
-        final = _score(kin, st, ask_cpu_total, penalty, f, spread_onehot)
+        final = _score(kin, st, ask_cpu_total, penalty, f, spread_des_n)
         active = i < kin.n_steps
         masked = jnp.where(feasible & active, final, NEG_INF)
         if f.with_shuffle:
@@ -669,9 +683,9 @@ def place_taskgroup(
         if f.with_distinct:
             st2["job_any_count"] = st["job_any_count"] + onei
         if f.n_spreads > 0:
-            st2["spread_counts"] = _bump_spread(
-                kin, st["spread_counts"], one, spread_onehot, f.n_spreads
-            )
+            st2["spread_counts"], st2["spread_cnt_n"] = _bump_spread(
+                kin, st["spread_counts"], st["spread_cnt_n"], idx,
+                found & active, f.n_spreads)
         out = (
             jnp.where(found, idx, -1).astype(jnp.int32),
             jnp.where(found, masked[idx], 0.0),
@@ -702,20 +716,24 @@ def place_taskgroup(
     )
 
 
-def _bump_spread(kin: KernelIn, counts, one, spread_onehot,
-                 n_spreads: int = MAX_SPREADS):
-    """counts[s, bucket_of_chosen] += 1 for active stanzas.
-
-    ``one`` is the chosen node's one-hot plane (f32[N], zeros when
-    nothing placed); projecting it through the node->bucket one-hot
-    gives the chosen bucket row without a dynamic gather (zero row
-    when the chosen node has no bucket value)."""
-    bump = jnp.zeros_like(counts)
-    for s in range(n_spreads):
-        row = one @ spread_onehot[s]              # f32[B]
-        bump = bump.at[s].add(
-            jnp.where(kin.spread_active[s], row, 0.0))
-    return counts + bump
+def _bump_spread(kin: KernelIn, counts, cnt_n, idx, placed,
+                 n_spreads: int) -> tuple:
+    """A placement on node ``idx``: under every active stanza the
+    chosen node's bucket ``b*`` gains one, in the bucket table
+    (``counts[s, b*]``, a one-hot over the buckets alone) and on the
+    node plane (``cnt_n[s, n]`` for every node of bucket ``b*``).
+    Nothing moves when nothing was placed (``placed`` false) or the
+    chosen node has no bucket value."""
+    sb = kin.spread_bucket[:n_spreads]            # i32[S, N]
+    # one scalar read per stanza: the column ``sb[:, idx]`` makes the
+    # TPU compiler lay every [B, S, N] plane out with S minor-most, 30
+    # us a step of copies at 16,384 nodes (PERF.md, PR 27)
+    b_star = jnp.stack([sb[s, idx] for s in range(n_spreads)])  # i32[S]
+    on = kin.spread_active[:n_spreads] & (b_star >= 0) & placed
+    row = jax.nn.one_hot(b_star, SPREAD_BUCKETS, dtype=jnp.float32)
+    counts = counts.at[:n_spreads].add(jnp.where(on[:, None], row, 0.0))
+    same = (sb == b_star[:, None]) & on[:, None]
+    return counts, cnt_n + same.astype(jnp.float32)
 
 
 place_taskgroup_jit = jax.jit(place_taskgroup, static_argnums=(1, 2))
@@ -1148,8 +1166,18 @@ def place_taskgroups_joint(
         init["a_dev"] = jnp.zeros((n, kin.dev_free.shape[-1]), jnp.float32)
     if f.with_distinct:
         init["job_any_count"] = _bat(kin.job_any_count, 1)   # [B, N]
+    # which leaves carry a member axis (for vmap over the members)
+    in_axes = KernelIn(*[
+        0 if jnp.ndim(x) == r + 1 else None
+        for x, r in zip(kin, KIN_UNBATCHED_RANKS)
+    ])
+    spread_des_n = None
     if f.n_spreads > 0:
         init["spread_counts"] = _bat(kin.spread_counts, 2)   # [B, S, Bk]
+        # per member, once per launch                         [B, S, N]
+        init["spread_cnt_n"], spread_des_n = jax.vmap(
+            lambda kin_m: _spread_node_planes(kin_m, f.n_spreads),
+            in_axes=(in_axes,))(kin)
 
     iota = jnp.arange(n, dtype=jnp.int32)
 
@@ -1180,6 +1208,7 @@ def place_taskgroups_joint(
             st_m["job_any_count"] = st["job_any_count"][m]
         if f.n_spreads > 0:
             st_m["spread_counts"] = st["spread_counts"][m]
+            st_m["spread_cnt_n"] = st["spread_cnt_n"][m]
         return kin_m, st_m
 
     def step(st, t):
@@ -1195,15 +1224,8 @@ def place_taskgroups_joint(
             pen_ids = kin_m.step_penalty[j]
             step_pen = jnp.any(iota[:, None] == pen_ids[None, :], axis=1)
             penalty = penalty | step_pen
-        spread_onehot = None
-        if f.n_spreads > 0:
-            sb = kin_m.spread_bucket[:f.n_spreads]
-            spread_onehot = (
-                jax.nn.one_hot(jnp.clip(sb, 0, SPREAD_BUCKETS - 1),
-                               SPREAD_BUCKETS, dtype=jnp.float32)
-                * (sb >= 0)[..., None]
-            )
-        final = _score(kin_m, st_m, ask_cpu_total, penalty, f, spread_onehot)
+        final = _score(kin_m, st_m, ask_cpu_total, penalty, f,
+                       spread_des_n[m] if f.n_spreads > 0 else None)
         active = active_step & (j < kin_m.n_steps)
         masked = jnp.where(feasible & active, final, NEG_INF)
         if f.with_shuffle:
@@ -1249,10 +1271,11 @@ def place_taskgroups_joint(
         if f.with_distinct:
             st2["job_any_count"] = st["job_any_count"].at[m].add(onei)
         if f.n_spreads > 0:
-            st2["spread_counts"] = st["spread_counts"].at[m].set(
-                _bump_spread(kin_m, st["spread_counts"][m], one,
-                             spread_onehot, f.n_spreads)
-            )
+            counts_m, cnt_n_m = _bump_spread(
+                kin_m, st_m["spread_counts"], st_m["spread_cnt_n"], idx,
+                found & active, f.n_spreads)
+            st2["spread_counts"] = st["spread_counts"].at[m].set(counts_m)
+            st2["spread_cnt_n"] = st["spread_cnt_n"].at[m].set(cnt_n_m)
         out = (
             jnp.where(found, idx, -1).astype(jnp.int32),
             jnp.where(found, masked[idx], 0.0),
@@ -1287,10 +1310,6 @@ def place_taskgroups_joint(
             ex(dims0["fit_ports"]), ex(dims0["fit_dev"]), ex(dims0["fit_cores"]),
         )
 
-    in_axes = KernelIn(*[
-        0 if jnp.ndim(x) == r + 1 else None
-        for x, r in zip(kin, KIN_UNBATCHED_RANKS)
-    ])
     (m_eval, m_feas, m_cpu, m_mem, m_disk, m_ports, m_dev, m_cores) = jax.vmap(
         member_metrics, in_axes=(in_axes,))(kin)
 

@@ -732,8 +732,9 @@ def _launch_wave(kins: List[KernelIn], k_steps: List[int],
         # applier's serialization order = plan arrival order). The step
         # axis is sized from the PADDED wave (b_pad * k_max) so the
         # compiled shape depends only on (wave bucket, step bucket,
-        # features) — retry waves of any real size reuse it; inert
-        # steps are microseconds of device time. Built vectorized:
+        # features) — retry waves of any real size reuse it. An inert
+        # step costs the device what a real one does (PERF.md section
+        # 5): the padding is paid for in launch time. Built vectorized:
         # the per-member python loop showed up at bench wave sizes.
         t_pad = wave_step_pad(len(kins), k_max)
         ks = np.asarray(k_steps, np.int64)
@@ -907,9 +908,10 @@ class LaunchCoalescer:
     is parked in ``launch`` — OR when a parked request's adaptive
     deadline expires, in which case whatever is pending fires as a
     partial wave and later arrivals form the next one. The deadline is
-    a fraction of the EWMA wave latency clamped to
-    ``[window_min_s, window_max_s]``: parking is only worth paying
-    while it stays small against the device call it amortizes. The
+    a fraction of the EWMA wave latency, at least ``window_min_s``,
+    and armed only while that fraction fits under ``window_max_s``
+    (``_window_s``): parking is only worth cutting short while the
+    device call it amortizes is itself short. The
     observer that completes the rendezvous (a parking launcher, a
     finishing participant, or the deadline owner itself) executes the
     device call — there is no dispatcher thread.
@@ -937,29 +939,30 @@ class LaunchCoalescer:
         self.max_wave = 0
         self.deadline_launches = 0
 
-    #: deadlines disarm while EWMA x fraction exceeds this multiple of
-    #: window_max: the device is grossly slower than the cap (cold
-    #: compiles in flight), and firing partial waves then SPRAYS more
-    #: cold compiles across fresh wave buckets instead of amortizing
-    #: one full-wave compile
-    TRANSIENT_FACTOR = 4.0
-
     def _window_s(self) -> Optional[float]:
         """Deadline for a parked request, or None to park until the
-        rendezvous completes (no latency sample yet, or the compile
-        transient is still running — both cases where fragmenting
-        waves costs far more than parking)."""
+        rendezvous completes: no latency sample yet, or a device call
+        worth more than the cap can buy.
+
+        Deadlines arm only while the window the wave latency asks for
+        (EWMA x fraction) fits under ``window_max_s``. Past that a
+        deadline AT the cap cuts waves whose members are still being
+        prepared (100 ms apiece on the host at 10,000 nodes, against
+        a 50 ms cap): the pieces launch apart against one snapshot
+        with no shared capacity carry, the applier refuses what
+        collides, the leftovers place again alone and each leftover
+        count compiles its own step bucket (PERF.md finding 27-2).
+        Cold compiles in flight fall under the same rule."""
         ewma = wave_latency_ewma.value
         if ewma is None:
             return None
         target = ewma * self.WINDOW_FRACTION
-        if target > self.window_max_s * self.TRANSIENT_FACTOR:
+        if target > self.window_max_s:
             return None
-        # an in-flight launch already running far past the cap is a
-        # cold compile the EWMA hasn't learned about yet — disarm
-        # before firing more partial waves into it
-        if _oldest_inflight_age_s() > \
-                self.window_max_s * self.TRANSIENT_FACTOR:
+        # a launch in flight past the cap keeps the device busy (or is
+        # a cold compile the EWMA hasn't learned about yet): a partial
+        # wave fired now would only queue behind it
+        if _oldest_inflight_age_s() > self.window_max_s:
             return None
         # fragmentation feedback: widen (up to 4x, still capped) while
         # recent launches keep firing by deadline instead of by full

@@ -121,7 +121,8 @@ class ServerConfig:
         # adaptive wave-coalescer window bounds (seconds derive from
         # ms knobs; parallel/coalesce.LaunchCoalescer): the rendezvous
         # fires a partial wave once a parked eval has waited
-        # clamp(EWMA_wave_latency/2, min, max)
+        # max(EWMA_wave_latency/2, min), and never by deadline once
+        # EWMA_wave_latency/2 exceeds max
         self.coalesce_window_min_ms = coalesce_window_min_ms
         self.coalesce_window_max_ms = coalesce_window_max_ms
         self.coalesce_adaptive = coalesce_adaptive

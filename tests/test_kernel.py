@@ -7,6 +7,7 @@ float32 tolerance. This is the port of the reference's scheduler unit
 tests' role (rank_test.go, spread_test.go) onto the batched formulation.
 """
 
+import functools
 import math
 
 import numpy as np
@@ -329,19 +330,25 @@ class TestPenaltyAndAffinity:
         assert out.chosen[0] == 1
 
 
+def spread_tensor(n_pad, buckets, counts=(), desired=None, weight=1.0,
+                  even=False):
+    """A stanza over the first len(buckets) nodes; -1 marks a node that
+    lacks the attribute, and every node past them."""
+    b = np.full(n_pad, -1, np.int32)
+    b[: len(buckets)] = buckets
+    c = np.zeros(SPREAD_BUCKETS, np.float32)
+    c[: len(counts)] = counts
+    d = np.full(SPREAD_BUCKETS, -1.0, np.float32)
+    if desired is not None:
+        d[: len(desired)] = desired
+    return SpreadTensor(bucket_id=b, counts=c, desired=d,
+                        weight_frac=weight, even=even)
+
+
 class TestSpreadStanza:
     def _spread(self, cluster, buckets, counts, desired, weight=1.0, even=False):
-        b = np.full(cluster.n_pad, -1, np.int32)
-        b[: len(buckets)] = buckets
-        c = np.zeros(SPREAD_BUCKETS, np.float32)
-        c[: len(counts)] = counts
-        d = np.full(SPREAD_BUCKETS, -1.0, np.float32)
-        if desired is not None:
-            d[: len(desired)] = desired
-        return SpreadTensor(
-            bucket_id=b, counts=c, desired=d if desired is not None else np.full(SPREAD_BUCKETS, -1.0, np.float32),
-            weight_frac=weight, even=even,
-        )
+        return spread_tensor(cluster.n_pad, buckets, counts, desired,
+                             weight, even)
 
     def test_desired_count_spread(self):
         # 4 nodes: dc0,dc0,dc1,dc1; desire 3 in dc0, 1 in dc1 (count 4)
@@ -610,3 +617,273 @@ class TestCandidateKernel:
         else:
             # fallback path: full kernel remains the source of truth
             assert np.asarray(full.found).sum() >= np.asarray(topk.found).sum()
+
+
+# ---------------------------------------------------------------------------
+# Spread scoring on the node axis: parity with a plain evaluation by bucket
+# table, and the rule that a scan step holds no [node, bucket] value
+# ---------------------------------------------------------------------------
+
+SPREAD_N_NODES = 20        # real nodes of the parity cluster (n_pad 64)
+SPREAD_STEPS = 48          # steps per member: the carry runs well past 40
+
+
+def _spread_stanzas(name, rng):
+    """(stanzas, spread_active or None) of one wave member for the named
+    case, over the parity cluster. ``spread_active`` overrides
+    build_kernel_in's "the first len(spreads) stanzas are on"."""
+    n = SPREAD_N_NODES
+    stanza = functools.partial(spread_tensor, pad_bucket(n))
+    racks = np.arange(n) % 5
+    dcs = np.arange(n) % 2
+    if name == "even":
+        return [stanza(racks, even=True)], None
+    if name == "desired_implicit_remainder":
+        # 70% of 48 wanted in rack 0; the remainder, 14.4, is the implicit
+        # target of every other value of the table (stack.py, spread.go:258)
+        return [stanza(racks, desired=[33.6, 14.4, 14.4, 14.4, 14.4])], None
+    if name == "bucketless_nodes":
+        lacking = racks.copy()
+        lacking[rng.choice(n, 6, replace=False)] = -1
+        return [stanza(lacking, even=True),
+                stanza(dcs, desired=[30.0, 18.0], weight=0.5)], None
+    if name == "seeded_counts":
+        # counts from the job's live allocations, one in a bucket (5) that
+        # no node of this cluster has: it still sets minc and maxc
+        return [stanza(racks, counts=[4, 0, 2, 7, 1, 3], even=True),
+                stanza(dcs, counts=[5, 1], desired=[24.0, 24.0],
+                       weight=0.5)], None
+    if name == "inactive_between_active":
+        return [stanza(racks, even=True),
+                stanza(np.arange(n) % 3, counts=[9, 0, 4], even=True),
+                stanza(dcs, desired=[36.0, 12.0], weight=0.5)], np.array(
+                    [True, False, True, False])
+    if name == "places_nothing":
+        return [stanza(racks, counts=[1, 0, 0, 2, 0], even=True)], None
+    raise AssertionError(name)
+
+
+SPREAD_CASES = ["even", "desired_implicit_remainder", "bucketless_nodes",
+                "seeded_counts", "inactive_between_active", "places_nothing"]
+
+
+def _spread_by_bucket_table(stanzas, active, counts):
+    """float64 spread plane over the real nodes, every boost computed over
+    the BUCKET table and then looked up by each node's bucket."""
+    total = np.zeros(SPREAD_N_NODES)
+    for s, sp in enumerate(stanzas):
+        if not active[s]:
+            continue
+        cnt = counts[s]
+        if sp.even:
+            present = cnt > 0
+            if not present.any():
+                table = np.zeros(SPREAD_BUCKETS)
+            else:
+                minc, maxc = cnt[present].min(), cnt[present].max()
+                at_min = (-1.0 if minc == maxc
+                          else 1.0 if minc == 0 else (maxc - minc) / minc)
+                table = np.where(cnt != minc, (minc - cnt) / minc, at_min)
+        else:
+            des = sp.desired.astype(np.float64)
+            safe = np.where(des > 0, des, 1.0)
+            table = np.where(
+                des > 0, ((des - (cnt + 1)) / safe) * sp.weight_frac, -1.0)
+        bucket = sp.bucket_id[:SPREAD_N_NODES]
+        total += np.where(bucket >= 0, table[np.clip(bucket, 0, None)], -1.0)
+    return total
+
+
+def _spread_reference(cluster, members, order):
+    """Place ``order``'s steps (member, local index) one after the other
+    over a shared capacity carry, as the wave program does: float64,
+    first-best node, spreads by bucket table. ``members`` is a list of
+    (ev, active, n_steps)."""
+    n = SPREAD_N_NODES
+    cap_c = cluster.cap_cpu[:n].astype(np.float64)
+    cap_m = cluster.cap_mem[:n].astype(np.float64)
+    used_c, used_m = np.zeros(n), np.zeros(n)
+    job_cnt = [np.zeros(n) for _ in members]
+    counts = [[sp.counts.astype(np.float64).copy() for sp in ev.spreads]
+              for ev, _, _ in members]
+    out = []
+    for m, local in order:
+        ev, active, n_steps = members[m]
+        ask = ev.ask
+        fits = (cap_c - used_c >= ask.cpu) & (cap_m - used_m >= ask.mem)
+        if local >= n_steps or not fits.any():
+            out.append((-1, 0.0))
+            continue
+        total = (10.0 ** (1 - (used_c + ask.cpu) / cap_c)
+                 + 10.0 ** (1 - (used_m + ask.mem) / cap_m))
+        planes = [np.clip(20.0 - total, 0.0, 18.0) / 18.0]
+        on = [np.ones(n, bool)]
+        planes.append(-(job_cnt[m] + 1) / max(ev.desired_count, 1))
+        on.append(job_cnt[m] > 0)
+        spread = _spread_by_bucket_table(ev.spreads, active, counts[m])
+        planes.append(spread)
+        on.append(spread != 0.0)
+        score = (sum(np.where(o, p, 0.0) for p, o in zip(planes, on))
+                 / sum(o.astype(np.float64) for o in on))
+        best = int(np.argmax(np.where(fits, score, -np.inf)))
+        out.append((best, float(score[best])))
+        used_c[best] += ask.cpu
+        used_m[best] += ask.mem
+        job_cnt[m][best] += 1
+        for s, sp in enumerate(ev.spreads):
+            if active[s] and sp.bucket_id[best] >= 0:
+                counts[m][s][sp.bucket_id[best]] += 1
+    return out
+
+
+def _spread_problem(name, seed, n_members):
+    """The parity cluster, the reference's view of its members and their
+    KernelIn. Member 0 has the named case's stanzas, a second member
+    another case's. The nodes hold every step's task, but in
+    ``places_nothing``: there each member asks for 44 of its 48 steps
+    and the nodes hold 40 a member, so four steps find no node and four
+    are past ``n_steps``."""
+    rng = np.random.default_rng(seed)
+    n_steps, per_node = SPREAD_STEPS, 3
+    if name == "places_nothing":
+        n_steps, per_node = SPREAD_STEPS - 4, 2
+    # three node sizes, so that binpack tells nodes apart
+    room = 1000 * per_node * n_members + rng.choice([0, 300, 700],
+                                                    SPREAD_N_NODES)
+    cluster = make_cluster([(r, r) for r in room])
+    members, kins = [], []
+    for m in range(n_members):
+        case = SPREAD_CASES[(SPREAD_CASES.index(name) + 2 * m)
+                            % len(SPREAD_CASES)]
+        stanzas, active = _spread_stanzas(case, rng)
+        ev = make_eval(cluster, ask=simple_ask(cpu=1000, mem=1000),
+                       spreads=stanzas, desired_count=SPREAD_STEPS)
+        kin = build_kernel_in(cluster, ev, n_steps)
+        if active is None:
+            active = np.asarray(kin.spread_active)
+        kins.append(kin._replace(spread_active=active))
+        members.append((ev, active, n_steps))
+    return cluster, members, kins
+
+
+def _assert_spread_parity(got_chosen, got_scores, want):
+    for step, (node, score) in enumerate(want):
+        assert got_chosen[step] == node, (
+            f"step {step}: program chose {got_chosen[step]}, the bucket "
+            f"table {node} (scores {got_scores[step]}, {score})")
+        if node >= 0:
+            assert got_scores[step] == pytest.approx(score, abs=1e-6), step
+
+
+class TestSpreadNodeAxis:
+    @pytest.mark.parametrize("name", SPREAD_CASES)
+    def test_single_matches_bucket_table(self, name):
+        cluster, members, kins = _spread_problem(name, seed=11, n_members=1)
+        out = place_taskgroup_jit(kins[0], SPREAD_STEPS)
+        want = _spread_reference(
+            cluster, members, [(0, i) for i in range(SPREAD_STEPS)])
+        assert sum(node < 0 for node, _ in want) == (
+            8 if name == "places_nothing" else 0)
+        _assert_spread_parity(np.asarray(out.chosen), np.asarray(out.scores),
+                              want)
+
+    @pytest.mark.parametrize("name", SPREAD_CASES)
+    def test_joint_matches_bucket_table(self, name):
+        """Two members over the same nodes, their steps interleaved: the
+        capacity carry is shared, the two spread carries are per member."""
+        import jax.numpy as jnp
+
+        from nomad_tpu.ops.kernel import KernelIn, place_taskgroups_joint_jit
+
+        cluster, members, kins = _spread_problem(name, seed=12, n_members=2)
+        order = [(t % 2, t // 2) for t in range(2 * SPREAD_STEPS)]
+        stacked = KernelIn(*[np.stack([np.asarray(getattr(k, f)) for k in kins])
+                             for f in KernelIn._fields])
+        out = place_taskgroups_joint_jit(
+            stacked, jnp.asarray([m for m, _ in order], jnp.int32),
+            jnp.asarray([i for _, i in order], jnp.int32), len(order))
+        want = _spread_reference(cluster, members, order)
+        assert sum(node < 0 for node, _ in want) == (
+            16 if name == "places_nothing" else 0)
+        _assert_spread_parity(np.asarray(out.chosen), np.asarray(out.scores),
+                              want)
+
+
+def _node_and_bucket_values(jaxpr, n_pad, inside_scan=False):
+    """(scan bodies met, offending values): every value produced or read
+    inside a scan body of ``jaxpr``, however deeply nested, whose shape
+    has both an ``n_pad``-sized and a SPREAD_BUCKETS-sized axis."""
+    scans, bad = 0, []
+    for eqn in jaxpr.eqns:
+        if inside_scan:
+            for v in list(eqn.invars) + list(eqn.outvars):
+                shape = getattr(v.aval, "shape", ())
+                if n_pad in shape and SPREAD_BUCKETS in shape:
+                    bad.append((eqn.primitive.name, shape))
+        is_scan = eqn.primitive.name == "scan"
+        scans += is_scan
+        for param in eqn.params.values():
+            for sub in (param if isinstance(param, (list, tuple)) else [param]):
+                sub = getattr(sub, "jaxpr", sub)      # ClosedJaxpr or Jaxpr
+                if hasattr(sub, "eqns"):
+                    s, b = _node_and_bucket_values(
+                        sub, n_pad, inside_scan or is_scan)
+                    scans += s
+                    bad += b
+    return scans, bad
+
+
+class TestNoNodeByBucketValueInAStep:
+    """ISSUE 27: spread scoring works on the node axis and on the bucket
+    axis separately; a placement step never builds or reads the
+    [S, n_pad, SPREAD_BUCKETS] one-hot (33.5 MB a step at the benchmark
+    cell's shape)."""
+
+    N_PAD = 256            # not SPREAD_BUCKETS: the two axes stay apart
+
+    def _kin(self):
+        from nomad_tpu.parallel.synthetic import synthetic_cluster, synthetic_eval
+
+        cluster = synthetic_cluster(self.N_PAD - 20, seed=5)
+        assert cluster.n_pad == self.N_PAD != SPREAD_BUCKETS
+        ev = synthetic_eval(cluster, with_spread=True, used_frac=0.3, seed=5)
+        return build_kernel_in(cluster, ev, 8)
+
+    def test_the_walker_sees_a_one_hot_in_a_scan(self):
+        import jax
+        import jax.numpy as jnp
+
+        def old_way(bucket):
+            def step(carry, _):
+                onehot = jax.nn.one_hot(bucket, SPREAD_BUCKETS)
+                return carry + onehot.sum(), None
+            return jax.lax.scan(step, 0.0, jnp.arange(3))[0]
+
+        scans, bad = _node_and_bucket_values(
+            jax.make_jaxpr(old_way)(jnp.zeros(self.N_PAD, jnp.int32)).jaxpr,
+            self.N_PAD)
+        assert scans == 1 and bad
+
+    @pytest.mark.parametrize("program", ["place_taskgroup",
+                                         "place_taskgroups_joint"])
+    def test_scan_body(self, program):
+        import jax
+
+        from nomad_tpu.ops import kernel as K
+        from nomad_tpu.tensors.schema import MAX_SPREADS
+
+        assert K.FULL_FEATURES.n_spreads == MAX_SPREADS
+        kin = self._kin()
+        if program == "place_taskgroup":
+            jaxpr = jax.make_jaxpr(
+                lambda k: K.place_taskgroup(k, 8, K.FULL_FEATURES))(kin)
+        else:
+            stacked = K.KernelIn(*[np.stack([np.asarray(x)] * 2) for x in kin])
+            member = np.repeat(np.arange(2, dtype=np.int32), 8)
+            local = np.tile(np.arange(8, dtype=np.int32), 2)
+            jaxpr = jax.make_jaxpr(
+                lambda k, m, j: K.place_taskgroups_joint(
+                    k, m, j, 16, K.FULL_FEATURES))(stacked, member, local)
+        scans, bad = _node_and_bucket_values(jaxpr.jaxpr, self.N_PAD)
+        assert scans >= 1, "no scan found: the guard has nothing to walk"
+        assert not bad, bad[:5]

@@ -72,6 +72,45 @@ class TestShardedWaveParity:
                                        rtol=1e-6, atol=1e-7)
         assert any(np.asarray(s.found).any() for s in single)
 
+    def test_spread_wave_identical_to_single_device(self, wave_mesh):
+        """``joint_sharded`` with spread stanzas: the per-node count
+        plane is node-sharded like every [N] carry, and each placement
+        reads the chosen node's bucket from a node-sharded plane."""
+        from nomad_tpu.ops.kernel import build_kernel_in, infer_features
+        from nomad_tpu.parallel.synthetic import (
+            synthetic_cluster,
+            synthetic_eval,
+        )
+
+        cluster = synthetic_cluster(200, cpu=2000.0, mem=4096.0,
+                                    disk=50000.0, seed=5)
+        kins, steps, feats = [], [], []
+        for i in range(3):
+            ev = synthetic_eval(cluster, desired_count=12, with_spread=True,
+                                used_frac=0.3, seed=i)
+            kins.append(build_kernel_in(cluster, ev, 12))
+            steps.append(12)
+            feats.append(infer_features(ev))
+        assert all(f.n_spreads == 1 for f in feats)
+
+        coalesce.configure_wave_mesh(None)
+        single = coalesce.launch_wave(kins, steps, feats)
+        before = coalesce.sharded_wave_launches
+        coalesce.configure_wave_mesh(wave_mesh)
+        try:
+            sharded = coalesce.launch_wave(kins, steps, feats)
+        finally:
+            coalesce.configure_wave_mesh(None)
+        assert coalesce.sharded_wave_launches == before + 1
+
+        for s, m in zip(single, sharded):
+            assert np.asarray(s.found).all()
+            np.testing.assert_array_equal(np.asarray(s.chosen),
+                                          np.asarray(m.chosen))
+            np.testing.assert_allclose(np.asarray(s.scores),
+                                       np.asarray(m.scores),
+                                       rtol=1e-6, atol=1e-7)
+
 
 def _shared_layout_wave(n_nodes=200, members=4, k=3, seed=5):
     """B kins whose three sharing groups are ALL identity-shared (the

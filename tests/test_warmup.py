@@ -320,10 +320,19 @@ class TestAdaptiveCoalescer:
         for _ in range(4):
             c.done()
 
-    def test_cold_start_parks_for_full_waves(self, monkeypatch):
-        """Without a wave-latency sample (cold process, first compiles
-        in flight) deadlines stay disarmed: firing partial waves then
-        would spray cold compiles across fresh wave buckets."""
+    @pytest.mark.parametrize("latency_s, window_max_s", [
+        (None, 0.001),       # cold process: no wave-latency sample yet
+        (0.36, 0.050),       # the window it asks for, 180 ms, outgrows the cap
+    ])
+    def test_parks_for_full_waves(self, monkeypatch, latency_s,
+                                  window_max_s):
+        """Deadlines stay disarmed without a wave-latency sample (cold
+        process, first compiles in flight), and once half the wave
+        latency no longer fits under the cap: a deadline at the cap
+        would cut a wave whose members are still being prepared, the
+        pieces would collide in the applier and each leftover would
+        compile a fresh bucket (a 360 ms wave armed a 50 ms deadline
+        until PR 27)."""
         from nomad_tpu.parallel import coalesce
 
         fired = []
@@ -334,7 +343,9 @@ class TestAdaptiveCoalescer:
 
         monkeypatch.setattr(coalesce, "launch_wave", stub_launch_wave)
         monkeypatch.setattr(coalesce, "wave_latency_ewma",
-                            coalesce._LatencyEWMA())   # no sample
+                            coalesce._LatencyEWMA())
+        if latency_s is not None:
+            coalesce.wave_latency_ewma.update(latency_s)
 
         class KinStub:
             class _Arr:
@@ -342,7 +353,7 @@ class TestAdaptiveCoalescer:
             cap_cpu = _Arr()
 
         c = coalesce.LaunchCoalescer(3, window_min_s=0.001,
-                                     window_max_s=0.001)
+                                     window_max_s=window_max_s)
         out = {}
 
         def member(i):
@@ -353,12 +364,13 @@ class TestAdaptiveCoalescer:
         for t in threads:
             t.start()
         time.sleep(0.2)
-        assert fired == [], "deadline fired without a latency sample"
+        assert fired == [], "a deadline cut the wave"
         c.done()                       # the third member finishes: the
         for t in threads:              # rendezvous completes the wave
             t.join(timeout=10)
         assert fired == [2]
         assert len(out) == 2
+        assert c.deadline_launches == 0
         for _ in range(2):
             c.done()
 
