@@ -30,6 +30,13 @@ push it past 1.0 (overlapped device + host work is the point of the
 pipeline); far below 1.0 means un-instrumented time — the report
 prints it either way rather than pretending.
 
+``cpu_coverage`` asks the instrumentation question without the wall:
+of the CPU the interpreter's threads burned over the burst, the share
+burned inside a named span (thread CPU over thread CPU). Device time
+that overlaps host work cannot raise it and a contended host cannot
+lower it, which both happen to the wall shares; it is the figure the
+CI gate holds to 0.9.
+
 Usage:
     python bench/trace_report.py [out.json]
     (or from bench.py's trace phase / tests via run_traced_burst)
@@ -123,6 +130,45 @@ def _interval_union_s(intervals) -> float:
     if cur_end is not None:
         total += cur_end - cur_start
     return total
+
+
+def python_threads_cpu_s() -> Dict[int, float]:
+    """CPU seconds of every live interpreter thread, by ident, on the
+    clock a span samples (``time.thread_time`` of that thread)."""
+    out = {}
+    for t in threading.enumerate():
+        if t.ident is None or not t.is_alive():
+            continue
+        try:
+            out[t.ident] = time.clock_gettime(
+                time.pthread_getcpuclockid(t.ident))
+        except OSError:                  # gone since enumerate()
+            continue
+    return out
+
+
+def cpu_coverage(stage_totals: Dict, before: Dict[int, float],
+                 after: Dict[int, float]) -> Dict:
+    """The share of the interpreter threads' CPU between two
+    ``python_threads_cpu_s`` readings that ran inside a named span:
+    every stage of ``_ATTRIBUTED`` whatever its clock, the background
+    loops, and the waits of ``_OVERLAPPED`` by the CPU their thread
+    burns in them (entering and leaving the rendezvous, queueing the
+    plan: 0.1 to 0.35 ms a wait, 8 to 15% of a burst), never by their
+    wall. Work that overlaps other work counts once on each side of
+    the quotient, so the pipeline's overlap cannot pass for
+    instrumentation (PERF.md finding 30-3)."""
+    named = sum(
+        agg["exclusive_cpu_s"] for name, agg in stage_totals.items()
+        if name in _ATTRIBUTED or name in _OVERLAPPED
+        or name.startswith("bg."))
+    spent = sum(cpu - before.get(ident, 0.0)
+                for ident, cpu in after.items())
+    return {
+        "named_cpu_s": round(named, 6),
+        "python_cpu_s": round(spent, 6),
+        "cpu_coverage": round(named / spent, 4) if spent > 0 else 0.0,
+    }
 
 
 def decompose(stage_totals: Dict, wall_s: float, n_evals: int,
@@ -432,6 +478,7 @@ def run_traced_burst(n_nodes: int = 1000, n_jobs: int = 100,
             # on the other would break the count-equality gate
             _settle_committed(server, 0)
             telemetry.reset()
+            threads_cpu0 = python_threads_cpu_s()
             # serving-plane counters window with the burst like every
             # other stats source (broker stats are per-server, so the
             # global telemetry.reset cannot reach them)
@@ -452,9 +499,15 @@ def run_traced_burst(n_nodes: int = 1000, n_jobs: int = 100,
             spans = tracer.spans()
             if len(spans) >= tracer.capacity:
                 spans = None
-            decomp = decompose(tracer.stage_totals(), wall, n_jobs,
+            stage_totals = tracer.stage_totals()
+            threads_cpu1 = python_threads_cpu_s()
+            decomp = decompose(stage_totals, wall, n_jobs,
                                profiler_summary=profiler.summary(),
                                spans=spans)
+            # the instrumentation gate: thread CPU inside named spans
+            # over thread CPU spent, both since the reset
+            decomp.update(cpu_coverage(stage_totals, threads_cpu0,
+                                       threads_cpu1))
             # steal-invariant companion: attributed work over the CPU
             # this process actually got. On a contended host (CI
             # neighbors, a parent test suite's leaked threads) wall
@@ -530,6 +583,7 @@ def run_traced_burst(n_nodes: int = 1000, n_jobs: int = 100,
                  "per_eval_ms": h["per_eval_ms"],
                  "attributed_share": h["attributed_share"],
                  "attributed_share_busy": h["attributed_share_busy"],
+                 "cpu_coverage": h["cpu_coverage"],
                  "compile_s": h["stages"].get("compile", {})
                  .get("total_s", 0.0),
                  "compile_share": h["stages"].get("compile", {})

@@ -277,7 +277,10 @@ class KernelIn(NamedTuple):
     job_tg_count: jnp.ndarray        # i32[N]
     penalty: jnp.ndarray             # bool[N]
     aff_score: jnp.ndarray           # f32[N]
-    node_perm: jnp.ndarray           # i32[N]: seeded tie-break permutation
+    # i32[N]: seeded tie-break permutation. The programs read it once a
+    # launch, for each node's rank in it (_inv); a step breaks ties by
+    # that rank plane (_pick), never by a permuted copy of the scores
+    node_perm: jnp.ndarray
     # per-step planes (placement axis K): rescheduled allocs penalize
     # their previous node(s) (rank.go:630 SetPenaltyNodes is per-Select)
     # and sticky/preferred placements pin a node (stack.go:120-139)
@@ -508,6 +511,33 @@ def _score(kin: KernelIn, st, ask_cpu_total, penalty,
     return score_sum / nplanes
 
 
+def _inv(perm: jnp.ndarray) -> jnp.ndarray:
+    """Rank of each node in a tie-break permutation: ``inv[perm[r]] =
+    r``, for an ``[N]`` permutation or, row by row, a stacked
+    ``[B, N]``. One scatter a launch, outside the scan."""
+    def inv(p):
+        return jnp.zeros_like(p).at[p].set(
+            jnp.arange(p.shape[0], dtype=p.dtype))
+
+    return jax.vmap(inv)(perm) if jnp.ndim(perm) == 2 else inv(perm)
+
+
+def _pick(masked: jnp.ndarray, rank: Optional[jnp.ndarray]) -> jnp.ndarray:
+    """The step's choice over the node axis. Without a rank plane the
+    first best score, ``argmax(masked)``. With ``rank = _inv(perm)``,
+    among the nodes that hold the best score the one the permutation
+    meets first (shuffleNodes util.go:464): the node
+    ``perm[argmax(masked[perm])]`` names, taken by two reductions and
+    no gather (a gather of 16,384 single elements was 116 us of every
+    172 us step on the v5e: PERF.md, PR 30). ``rank`` is a permutation,
+    so its least value among the best is one node, and with every node
+    masked out both forms name ``perm[0]``."""
+    if rank is None:
+        return jnp.argmax(masked)
+    n = masked.shape[0]
+    return jnp.argmin(jnp.where(masked == jnp.max(masked), rank, n))
+
+
 def _spread_node_planes(kin: KernelIn, n_spreads: int) -> tuple:
     """The two bucket tables seen from the node axis, derived once per
     launch: ``cnt_n[s, n] = spread_counts[s, spread_bucket[s, n]]`` and
@@ -623,6 +653,7 @@ def place_taskgroup(
     exhausted = lambda fit: jnp.sum(base_i & ~fit).astype(jnp.int32)  # noqa: E731
 
     iota = jnp.arange(n, dtype=jnp.int32)
+    rank = _inv(kin.node_perm) if f.with_shuffle else None
 
     def step(st, i):
         feasible, ask_cpu_total, _ = _feasible(kin, st, f)
@@ -635,12 +666,9 @@ def place_taskgroup(
         final = _score(kin, st, ask_cpu_total, penalty, f, spread_des_n)
         active = i < kin.n_steps
         masked = jnp.where(feasible & active, final, NEG_INF)
-        if f.with_shuffle:
-            # argmax over the permuted plane: equal-score candidates
-            # resolve in permutation order (shuffleNodes util.go:464)
-            best = kin.node_perm[jnp.argmax(masked[kin.node_perm])]
-        else:
-            best = jnp.argmax(masked)
+        # equal scores resolve in permutation order where the
+        # evaluation shuffles, by the rank plane (_pick)
+        best = _pick(masked, rank)
         # preferred-node pin: take it when feasible (stack.go preferred-
         # source select), else fall back to the global argmax
         if f.with_preferred:
@@ -886,7 +914,7 @@ def place_taskgroup_topk(
     if f.with_distinct:
         init_c["job_any_count"] = kin_c.job_any_count
 
-    iota_c = jnp.arange(k_all, dtype=jnp.int32)
+    rank_c = _inv(cand_perm) if f.with_shuffle else None
 
     def step(carry, i):
         st, ok = carry
@@ -901,10 +929,7 @@ def place_taskgroup_topk(
         final = _score(kin_c, st, ask_cpu_total, penalty, f, None)
         active = i < kin_c.n_steps
         masked = jnp.where(feasible & active, final, NEG_INF)
-        if f.with_shuffle:
-            best = kin_c.node_perm[jnp.argmax(masked[kin_c.node_perm])]
-        else:
-            best = jnp.argmax(masked)
+        best = _pick(masked, rank_c)
         if f.with_preferred:
             pref = kin_c.step_preferred[i]
             # the preferred node's candidate row: k_cand + i by layout
@@ -1180,6 +1205,9 @@ def place_taskgroups_joint(
             in_axes=(in_axes,))(kin)
 
     iota = jnp.arange(n, dtype=jnp.int32)
+    # [N] where the wave shares one permutation, else [B, N]
+    rank = _inv(kin.node_perm) if f.with_shuffle else None
+    rank_per_member = f.with_shuffle and rank.ndim == 2
 
     def member_view(st, m):
         """The member's single-problem (kin, st) as place_taskgroup
@@ -1228,10 +1256,7 @@ def place_taskgroups_joint(
                        spread_des_n[m] if f.n_spreads > 0 else None)
         active = active_step & (j < kin_m.n_steps)
         masked = jnp.where(feasible & active, final, NEG_INF)
-        if f.with_shuffle:
-            best = kin_m.node_perm[jnp.argmax(masked[kin_m.node_perm])]
-        else:
-            best = jnp.argmax(masked)
+        best = _pick(masked, rank[m] if rank_per_member else rank)
         if f.with_preferred:
             pref = kin_m.step_preferred[j]
             pref_ok = (pref >= 0) & feasible[jnp.clip(pref, 0, n - 1)] & active

@@ -464,6 +464,10 @@ class WaveStats:
         self.launches = 0
         self.full_launches = 0
         self.deadline_launches = 0
+        # park waits whose deadline would have armed but for a
+        # participant of the batch that had not arrived yet
+        # (LaunchCoalescer._expected)
+        self.held_for_arrivals = 0
         self.members_sum = 0
         self.slots_sum = 0
         # placement steps handed to the device, real and padded, of
@@ -498,9 +502,10 @@ class WaveStats:
             self.padded_steps_sum += padded_steps
             self.relaunched_members_sum += int(relaunched)
 
-    def observe_park(self, seconds: float) -> None:
+    def observe_park(self, seconds: float, held: bool = False) -> None:
         with self._lock:
             self.requests += 1
+            self.held_for_arrivals += int(held)
             self._park_s.append(seconds)
         # the streaming histogram keeps the FULL distribution (the
         # deque above is a bounded recent window for the gauges)
@@ -512,6 +517,7 @@ class WaveStats:
             self.launches = 0
             self.full_launches = 0
             self.deadline_launches = 0
+            self.held_for_arrivals = 0
             self.members_sum = 0
             self.slots_sum = 0
             self.steps_sum = 0
@@ -531,6 +537,7 @@ class WaveStats:
                 "launches": self.launches,
                 "full_launches": self.full_launches,
                 "deadline_launches": self.deadline_launches,
+                "held_for_arrivals": self.held_for_arrivals,
                 "fill_ratio": (self.members_sum / self.slots_sum
                                if self.slots_sum else 0.0),
                 "park_latency_p50_ms": p50 * 1e3,
@@ -911,7 +918,15 @@ class LaunchCoalescer:
     a fraction of the EWMA wave latency, at least ``window_min_s``,
     and armed only while that fraction fits under ``window_max_s``
     (``_window_s``): parking is only worth cutting short while the
-    device call it amortizes is itself short. The
+    device call it amortizes is itself short. And it is armed only once
+    every participant has ARRIVED: called ``launch`` once, finished
+    (``done``) or stepped aside (``suspend``). Until then the batch's
+    members are still being prepared, one after the other under the
+    interpreter lock, and will come: a deadline cuts the first wave
+    before them whatever the launch costs (PERF.md finding 27-2). A
+    participant is one thread (server/worker.py runs each evaluation
+    of a batch as one pool task), which is how an arrival is told from
+    a member that launches again. The
     observer that completes the rendezvous (a parking launcher, a
     finishing participant, or the deadline owner itself) executes the
     device call — there is no dispatcher thread.
@@ -927,6 +942,9 @@ class LaunchCoalescer:
         self._cv = threading.Condition(
             witness_lock("LaunchCoalescer._lock"))
         self._active = participants
+        # participants not yet arrived, and the threads that have
+        self._expected = participants
+        self._arrived: set = set()
         # the owning server's device mesh (None = module default)
         self.mesh = mesh
         self._pending: List[_Request] = []
@@ -971,12 +989,24 @@ class LaunchCoalescer:
         target *= 1.0 + 3.0 * frag
         return min(max(target, self.window_min_s), self.window_max_s)
 
+    def _arrive(self) -> None:
+        """The calling participant is here (``_cv`` held)."""
+        me = threading.get_ident()
+        if me not in self._arrived:
+            self._arrived.add(me)
+            self._expected -= 1
+
+    def _all_arrived(self) -> bool:
+        with self._cv:
+            return self._expected <= 0
+
     def launch(self, kin: KernelIn, k_steps: int,
                features: KernelFeatures,
                origin: Optional[LaunchOrigin] = None) -> KernelOut:
         req = _Request(kin, k_steps, features, origin)
         wave: Optional[List[_Request]] = None
         with self._cv:
+            self._arrive()
             self.requests += 1
             self._pending.append(req)
             if len(self._pending) >= self._active:
@@ -994,16 +1024,21 @@ class LaunchCoalescer:
             # a deadline owner's own launch work is attributed under
             # wave.launch, never double-reported as parking.
             t0 = time.perf_counter()
+            held = False
             with tracer.span("wave.park"):
                 if self.adaptive:
                     fired = claimed = False
                     while not (fired or claimed):
                         window = self._window_s()
+                        if window is not None and not self._all_arrived():
+                            held = True
+                            window = None
                         if window is None:
-                            # disarmed (no latency sample yet, or a
-                            # compile transient in flight): park, and
-                            # poll at a coarse cadence so the deadline
-                            # re-arms once the transient clears
+                            # disarmed (no latency sample yet, a compile
+                            # transient in flight, or members still on
+                            # their way): park, and poll at a coarse
+                            # cadence so the deadline arms once the
+                            # transient clears and the batch is here
                             fired = req.event.wait(0.05)
                             continue
                         fired = req.event.wait(window)
@@ -1026,7 +1061,7 @@ class LaunchCoalescer:
                         req.event.wait()
                 else:
                     req.event.wait()
-            wave_stats.observe_park(time.perf_counter() - t0)
+            wave_stats.observe_park(time.perf_counter() - t0, held)
             if wave is not None:
                 self._fire(wave, deadline_fired=True)
         if req.error is not None:
@@ -1036,6 +1071,9 @@ class LaunchCoalescer:
     def done(self) -> None:
         wave: Optional[List[_Request]] = None
         with self._cv:
+            self._arrive()
+            # the thread is free to carry another participant
+            self._arrived.discard(threading.get_ident())
             self._active -= 1
             if self._pending and len(self._pending) >= self._active:
                 wave = self._pending
@@ -1049,6 +1087,7 @@ class LaunchCoalescer:
         plan applier). Pending requests stop waiting for it."""
         wave: Optional[List[_Request]] = None
         with self._cv:
+            self._arrive()
             self._active -= 1
             if self._pending and len(self._pending) >= self._active:
                 wave = self._pending

@@ -299,6 +299,7 @@ def _fused_sharded_core(kin: KernelIn, step_member, step_local, *,
         TOPK,
         JointOut,
         _feasible,
+        _inv,
         _score,
         pack_fused_wave,
     )
@@ -333,18 +334,9 @@ def _fused_sharded_core(kin: KernelIn, step_member, step_local, *,
     # inverse is computed in full and sliced to this shard's rows),
     # else the global index itself
     if f.with_shuffle:
-        def _inv(p):
-            return jnp.zeros_like(p).at[p].set(
-                jnp.arange(n_glob, dtype=p.dtype))
-
-        def _slc(p):
-            return jax.lax.dynamic_slice(p, (g0,), (n_loc,))
-
-        if jnp.ndim(kin.node_perm) == 2:
-            rank_rows = jax.vmap(
-                lambda p: _slc(_inv(p)))(kin.node_perm)   # [B, N/D]
-        else:
-            rank_rows = _slc(_inv(kin.node_perm))         # [N/D]
+        # [B, N/D] or, one permutation for the wave, [N/D]
+        rank_rows = jax.lax.dynamic_slice_in_dim(
+            _inv(kin.node_perm), g0, n_loc, axis=-1)
 
     def member_view(st, m):
         kin_m = KernelIn(*[

@@ -138,6 +138,12 @@ def prometheus_text(registry=None, event_broker=None) -> str:
         lines.append(
             'nomad_tpu_wave_launches_total{fired="deadline"} '
             f"{w['deadline_launches']}")
+        # park waits whose deadline stayed unarmed only because the
+        # batch was not all there yet: how often that rule, and not the
+        # launch's length, kept a wave whole
+        lines.append("# TYPE nomad_tpu_wave_held_for_arrivals_total counter")
+        lines.append(
+            f"nomad_tpu_wave_held_for_arrivals_total {w['held_for_arrivals']}")
         # sharded dispatch (ISSUE 14): waves that ran the joint program
         # over a device mesh vs mesh-present single-device fallbacks
         # (a node axis the device count does not divide) — fallbacks

@@ -698,42 +698,16 @@ def _spread_reference(cluster, members, order):
     """Place ``order``'s steps (member, local index) one after the other
     over a shared capacity carry, as the wave program does: float64,
     first-best node, spreads by bucket table. ``members`` is a list of
-    (ev, active, n_steps)."""
-    n = SPREAD_N_NODES
-    cap_c = cluster.cap_cpu[:n].astype(np.float64)
-    cap_m = cluster.cap_mem[:n].astype(np.float64)
-    used_c, used_m = np.zeros(n), np.zeros(n)
-    job_cnt = [np.zeros(n) for _ in members]
-    counts = [[sp.counts.astype(np.float64).copy() for sp in ev.spreads]
-              for ev, _, _ in members]
-    out = []
-    for m, local in order:
-        ev, active, n_steps = members[m]
-        ask = ev.ask
-        fits = (cap_c - used_c >= ask.cpu) & (cap_m - used_m >= ask.mem)
-        if local >= n_steps or not fits.any():
-            out.append((-1, 0.0))
-            continue
-        total = (10.0 ** (1 - (used_c + ask.cpu) / cap_c)
-                 + 10.0 ** (1 - (used_m + ask.mem) / cap_m))
-        planes = [np.clip(20.0 - total, 0.0, 18.0) / 18.0]
-        on = [np.ones(n, bool)]
-        planes.append(-(job_cnt[m] + 1) / max(ev.desired_count, 1))
-        on.append(job_cnt[m] > 0)
-        spread = _spread_by_bucket_table(ev.spreads, active, counts[m])
-        planes.append(spread)
-        on.append(spread != 0.0)
-        score = (sum(np.where(o, p, 0.0) for p, o in zip(planes, on))
-                 / sum(o.astype(np.float64) for o in on))
-        best = int(np.argmax(np.where(fits, score, -np.inf)))
-        out.append((best, float(score[best])))
-        used_c[best] += ask.cpu
-        used_m[best] += ask.mem
-        job_cnt[m][best] += 1
-        for s, sp in enumerate(ev.spreads):
-            if active[s] and sp.bucket_id[best] >= 0:
-                counts[m][s][sp.bucket_id[best]] += 1
-    return out
+    (ev, active, n_steps). It is ``_pick_reference`` under the identity
+    permutation, with no pin and no penalty."""
+    steps = 1 + max(local for _, local in order)
+    rows = _pick_reference(
+        cluster,
+        [dict(ev=ev, active=active, n_steps=n_steps,
+              penalty=np.full((steps, 1), -1), preferred=np.full(steps, -1))
+         for ev, active, n_steps in members],
+        order, [np.arange(cluster.n_pad)] * len(members))
+    return [(node, score) for node, score, *_ in rows]
 
 
 def _spread_problem(name, seed, n_members):
@@ -766,6 +740,14 @@ def _spread_problem(name, seed, n_members):
     return cluster, members, kins
 
 
+def _stack_kins(kins):
+    """A wave's members with a leading member axis on every leaf."""
+    from nomad_tpu.ops.kernel import KernelIn
+
+    return KernelIn(*[np.stack([np.asarray(getattr(k, f)) for k in kins])
+                      for f in KernelIn._fields])
+
+
 def _assert_spread_parity(got_chosen, got_scores, want):
     for step, (node, score) in enumerate(want):
         assert got_chosen[step] == node, (
@@ -793,14 +775,12 @@ class TestSpreadNodeAxis:
         capacity carry is shared, the two spread carries are per member."""
         import jax.numpy as jnp
 
-        from nomad_tpu.ops.kernel import KernelIn, place_taskgroups_joint_jit
+        from nomad_tpu.ops.kernel import place_taskgroups_joint_jit
 
         cluster, members, kins = _spread_problem(name, seed=12, n_members=2)
         order = [(t % 2, t // 2) for t in range(2 * SPREAD_STEPS)]
-        stacked = KernelIn(*[np.stack([np.asarray(getattr(k, f)) for k in kins])
-                             for f in KernelIn._fields])
         out = place_taskgroups_joint_jit(
-            stacked, jnp.asarray([m for m, _ in order], jnp.int32),
+            _stack_kins(kins), jnp.asarray([m for m, _ in order], jnp.int32),
             jnp.asarray([i for _, i in order], jnp.int32), len(order))
         want = _spread_reference(cluster, members, order)
         assert sum(node < 0 for node, _ in want) == (
@@ -887,3 +867,311 @@ class TestNoNodeByBucketValueInAStep:
         scans, bad = _node_and_bucket_values(jaxpr.jaxpr, self.N_PAD)
         assert scans >= 1, "no scan found: the guard has nothing to walk"
         assert not bad, bad[:5]
+
+
+# ---------------------------------------------------------------------------
+# The seeded tie-break (ISSUE 30): the programs take it from a rank plane
+# and two reductions; upstream states it as a walk over shuffled nodes
+# ---------------------------------------------------------------------------
+
+PICK_NEG = -1.0e30          # what a program writes for a masked-out node
+PICK_TOPK = 8               # rows of score metadata a step returns
+PICK_CASES = ["tied", "identity_permutation", "no_feasible_step",
+              "preferred_pin", "step_penalty", "spread_1", "spread_4"]
+PICK_PROGRAMS = ["single_full", "joint_shared_perm", "joint_member_perms"]
+
+
+def _pick_reference(cluster, members, order, perms):
+    """Place ``order``'s steps (member, local index) over a shared
+    capacity carry in float64, imports nothing of the program. The
+    tie-break is stated as upstream has it (shuffleNodes util.go:464, then
+    the first best of the walk): ``perm[argmax(masked[perm])]`` over the
+    padded node axis. ``members``: dicts of ``ev``, ``active``,
+    ``n_steps``, ``penalty`` (i32[K, P] node ids, -1 none) and
+    ``preferred`` (i32[K], -1 none). One row a step: chosen, score,
+    found, top-k nodes, top-k scores."""
+    n, n_pad = SPREAD_N_NODES, cluster.n_pad
+    cap_c = cluster.cap_cpu[:n].astype(np.float64)
+    cap_m = cluster.cap_mem[:n].astype(np.float64)
+    used_c, used_m = np.zeros(n), np.zeros(n)
+    job_cnt = [np.zeros(n) for _ in members]
+    counts = [[sp.counts.astype(np.float64).copy() for sp in mb["ev"].spreads]
+              for mb in members]
+    rows = []
+    for m, local in order:
+        mb = members[m]
+        ev, ask = mb["ev"], mb["ev"].ask
+        masked = np.full(n_pad, PICK_NEG)
+        feasible = np.zeros(n_pad, bool)
+        if local < mb["n_steps"]:
+            feasible[:n] = ((cap_c - used_c >= ask.cpu)
+                            & (cap_m - used_m >= ask.mem))
+            total = (10.0 ** (1 - (used_c + ask.cpu) / cap_c)
+                     + 10.0 ** (1 - (used_m + ask.mem) / cap_m))
+            penalized = np.isin(np.arange(n), mb["penalty"][local])
+            planes = [np.clip(20.0 - total, 0.0, 18.0) / 18.0,
+                      -(job_cnt[m] + 1) / max(ev.desired_count, 1),
+                      np.full(n, -1.0)]
+            on = [np.ones(n, bool), job_cnt[m] > 0, penalized]
+            if ev.spreads:
+                spread = _spread_by_bucket_table(
+                    ev.spreads, mb["active"], counts[m])
+                planes.append(spread)
+                on.append(spread != 0.0)
+            score = (sum(np.where(o, p, 0.0) for p, o in zip(planes, on))
+                     / sum(o.astype(np.float64) for o in on))
+            masked[:n] = np.where(feasible[:n], score, PICK_NEG)
+        perm = perms[m]
+        idx = int(perm[np.argmax(masked[perm])])
+        pref = int(mb["preferred"][local])
+        if pref >= 0 and feasible[pref]:
+            idx = pref
+        found = bool(masked[idx] > PICK_NEG / 2)
+        top = np.argsort(-masked, kind="stable")[:PICK_TOPK]
+        rows.append((idx if found else -1, masked[idx] if found else 0.0,
+                     found, top, masked[top]))
+        if found:
+            used_c[idx] += ask.cpu
+            used_m[idx] += ask.mem
+            job_cnt[m][idx] += 1
+            for s, sp in enumerate(ev.spreads):
+                if mb["active"][s] and sp.bucket_id[idx] >= 0:
+                    counts[m][s][sp.bucket_id[idx]] += 1
+    return rows
+
+
+def _pick_problem(case, program, seed):
+    """Identical empty nodes (the benchmark cell's case: every node ties
+    on a member's first step and the empty ones tie again whenever the
+    node being filled is full), one member or four."""
+    rng = np.random.default_rng(seed)
+    n = SPREAD_N_NODES
+    n_members = 1 if program == "single_full" else 4
+    n_steps = 24 if n_members == 1 else 8
+    # three tasks fit a node; one where the nodes are to run out, 20
+    # places for 24 or 32 steps
+    per_node = 1 if case == "no_feasible_step" else 3
+    cluster = make_cluster([(1000 * per_node + 500,) * 2] * n)
+    k_pad = pad_steps(n_steps)
+    members, kins, perms = [], [], []
+    shared_perm = rng.permutation(cluster.n_pad).astype(np.int32)
+    for m in range(n_members):
+        stanzas, active = [], np.zeros(4, bool)
+        if case.startswith("spread"):
+            stanzas, active = _spread_stanzas(
+                "even" if case == "spread_1" else "inactive_between_active",
+                rng)
+        penalty = np.full((k_pad, 4), -1, np.int32)
+        preferred = np.full(k_pad, -1, np.int32)
+        if case == "step_penalty":
+            penalty[:n_steps:2, 0] = rng.integers(0, n, len(penalty[:n_steps:2]))
+            penalty[1, :] = rng.choice(n, 4, replace=False)
+        if case == "preferred_pin":
+            # the same node four steps running: it holds three
+            preferred[2:6] = rng.integers(0, n)
+            preferred[7] = rng.integers(0, n)
+        if case == "identity_permutation":
+            perm = np.arange(cluster.n_pad, dtype=np.int32)
+        elif program == "joint_member_perms":
+            perm = rng.permutation(cluster.n_pad).astype(np.int32)
+        else:
+            perm = shared_perm
+        ev = make_eval(cluster, ask=simple_ask(cpu=1000, mem=1000),
+                       spreads=stanzas, desired_count=n_steps)
+        kin = build_kernel_in(cluster, ev, n_steps, step_penalty=penalty,
+                              step_preferred=preferred, node_perm=perm)
+        if active is None:
+            active = np.asarray(kin.spread_active)
+        kins.append(kin._replace(spread_active=active))
+        members.append(dict(ev=ev, active=active, n_steps=n_steps,
+                            penalty=penalty, preferred=preferred))
+        perms.append(perm)
+    return cluster, members, kins, perms, n_steps
+
+
+def _run_pick_program(program, kins, order, features):
+    import jax.numpy as jnp
+
+    from nomad_tpu.ops.kernel import place_taskgroups_joint_jit
+
+    if program == "single_full":
+        return place_taskgroup_jit(kins[0], len(order), features)
+    stacked = _stack_kins(kins)
+    if program == "joint_shared_perm":
+        # one permutation for the wave, shipped without a member axis
+        assert all(np.array_equal(k.node_perm, kins[0].node_perm)
+                   for k in kins)
+        stacked = stacked._replace(node_perm=np.asarray(kins[0].node_perm))
+    return place_taskgroups_joint_jit(
+        stacked, jnp.asarray([m for m, _ in order], jnp.int32),
+        jnp.asarray([i for _, i in order], jnp.int32), len(order), features)
+
+
+class TestSeededTieBreak:
+    @pytest.mark.parametrize("case", PICK_CASES)
+    @pytest.mark.parametrize("program", PICK_PROGRAMS)
+    def test_pick_is_the_first_best_of_the_shuffled_walk(self, program, case):
+        from nomad_tpu.ops.kernel import FULL_FEATURES, NEG_INF, TOPK
+
+        assert (NEG_INF, TOPK) == (PICK_NEG, PICK_TOPK)
+        cluster, members, kins, perms, n_steps = _pick_problem(
+            case, program, seed=30)
+        k_pad = pad_steps(n_steps)
+        n_m = len(members)
+        # a wave's steps interleaved; a lone member's in order, the
+        # padded steps past n_steps included
+        order = [(t % n_m, t // n_m) for t in range(n_m * k_pad)]
+        features = FULL_FEATURES._replace(
+            with_shuffle=True,
+            n_spreads={"spread_1": 1, "spread_4": 4}.get(case, 0))
+        out = _run_pick_program(program, kins, order, features)
+        want = _pick_reference(cluster, members, order, perms)
+
+        chosen = np.array([r[0] for r in want])
+        found = np.array([r[2] for r in want])
+        real = np.array([i < n_steps for _, i in order])
+        # the case holds what it is named for
+        assert found[real].sum() >= (20 if case == "no_feasible_step"
+                                     else real.sum())
+        assert (~found[real]).sum() == {
+            ("no_feasible_step", 1): 4, ("no_feasible_step", 4): 12,
+        }.get((case, n_m), 0)
+        if case not in ("identity_permutation", "preferred_pin"):
+            first_best = [int(np.argmax(r[4] == r[4][0])) for r in want]
+            assert any(r[0] >= 0 and r[0] != r[3][f]
+                       for r, f in zip(want, first_best)), (
+                "no step's tie went another way than to the first node")
+        np.testing.assert_array_equal(np.asarray(out.chosen), chosen)
+        np.testing.assert_array_equal(np.asarray(out.found), found)
+        np.testing.assert_array_equal(
+            np.asarray(out.topk_idx), np.stack([r[3] for r in want]))
+        np.testing.assert_allclose(
+            np.asarray(out.scores), [r[1] for r in want], rtol=0, atol=1e-6)
+        top_want = np.stack([r[4] for r in want])
+        top_got = np.asarray(out.topk_scores)
+        masked_out = top_want < PICK_NEG / 2
+        np.testing.assert_array_equal(top_got < PICK_NEG / 2, masked_out)
+        np.testing.assert_allclose(
+            np.where(masked_out, 0.0, top_got),
+            np.where(masked_out, 0.0, top_want), rtol=0, atol=1e-6)
+
+    @pytest.mark.parametrize("program", PICK_PROGRAMS)
+    def test_identity_permutation_is_the_plain_argmax(self, program):
+        """Bit for bit, every output: the rank plane of the identity is
+        the node index, and the first best by rank is ``argmax``."""
+        from nomad_tpu.ops.kernel import FULL_FEATURES
+
+        _, members, kins, _, n_steps = _pick_problem(
+            "identity_permutation", program, seed=31)
+        n_m = len(members)
+        order = [(t % n_m, t // n_m) for t in range(n_m * pad_steps(n_steps))]
+        plain = FULL_FEATURES._replace(n_spreads=0)
+        a = _run_pick_program(program, kins, order, plain)
+        b = _run_pick_program(program, kins, order,
+                              plain._replace(with_shuffle=True))
+        for field in ("chosen", "found", "scores", "topk_idx", "topk_scores"):
+            np.testing.assert_array_equal(
+                np.asarray(getattr(a, field)), np.asarray(getattr(b, field)),
+                err_msg=field)
+
+
+def _gathers_in_loops(text):
+    """(lines read, result shapes): every ``stablehlo.gather`` of a
+    lowered module that a ``stablehlo.while`` runs, in the loop's own
+    regions or in a private function they call, however deep."""
+    import re
+
+    funcs, cur = {}, None
+    for line in text.splitlines():
+        head = re.match(r"\s*func\.func \w+ @([\w.]+)\(", line)
+        if head:
+            cur = funcs.setdefault(head.group(1), [])
+        elif cur is not None:
+            cur.append(line)
+    looped = []
+    for lines in funcs.values():
+        depth = None                    # None: outside any while
+        for line in lines:
+            if depth is None:
+                if "stablehlo.while" in line:
+                    depth = 0
+                continue
+            looped.append(line)
+            depth += line.count("{") - line.count("}")
+            if depth == 0:              # the `do` region closed
+                depth = None
+    seen, at = set(), 0
+    while at < len(looped):
+        for name in re.findall(r"call @([\w.]+)", looped[at]):
+            if name not in seen:
+                seen.add(name)
+                looped.extend(funcs[name])
+        at += 1
+    shapes = []
+    for line in looped:
+        if re.search(r"stablehlo\.(dynamic_)?gather", line):
+            result = line.rsplit("->", 1)[1]
+            dims = re.search(r"tensor<((?:\d+x)*)", result).group(1)
+            shapes.append(tuple(int(d) for d in dims.split("x") if d))
+    return len(looped), shapes
+
+
+class TestNoNodeGatherInAStep:
+    """ISSUE 30: a placement step breaks score ties by the rank plane,
+    with two reductions; no step gathers a node-axis plane (16,384
+    single elements a step were 116 of 172 us on the v5e). Read off the
+    lowered text, which no backend has touched yet."""
+
+    N_PAD = 256
+
+    def _kin(self):
+        from nomad_tpu.parallel.synthetic import synthetic_cluster, synthetic_eval
+
+        cluster = synthetic_cluster(self.N_PAD - 20, seed=5)
+        assert cluster.n_pad == self.N_PAD
+        ev = synthetic_eval(cluster, with_spread=True, used_frac=0.3, seed=5)
+        perm = np.random.default_rng(5).permutation(self.N_PAD)
+        return build_kernel_in(cluster, ev, 8, node_perm=perm.astype(np.int32))
+
+    def test_the_reader_sees_a_gather_in_a_scan(self):
+        import jax
+        import jax.numpy as jnp
+
+        @jax.jit
+        def shuffled(masked, perm):     # outlined: a call from the loop
+            return perm[jnp.argmax(masked[perm])]
+
+        def old_way(masked, perm):
+            def step(carry, _):
+                return carry + shuffled(masked + carry, perm), None
+            return jax.lax.scan(step, 0, jnp.arange(3))[0]
+
+        text = jax.jit(old_way).lower(
+            jnp.zeros(self.N_PAD), jnp.arange(self.N_PAD)).as_text()
+        lines, shapes = _gathers_in_loops(text)
+        assert lines and (self.N_PAD,) in shapes, shapes
+
+    @pytest.mark.parametrize("program", ["place_taskgroup",
+                                         "place_taskgroups_joint"])
+    def test_scan_body(self, program):
+        import jax
+
+        from nomad_tpu.ops import kernel as K
+
+        features = K.FULL_FEATURES._replace(with_shuffle=True)
+        kin = self._kin()
+        if program == "place_taskgroup":
+            text = jax.jit(K.place_taskgroup, static_argnums=(1, 2)).lower(
+                kin, 8, features).as_text()
+        else:
+            stacked = K.KernelIn(*[np.stack([np.asarray(x)] * 2) for x in kin])
+            member = np.repeat(np.arange(2, dtype=np.int32), 8)
+            local = np.tile(np.arange(8, dtype=np.int32), 2)
+            text = jax.jit(K.place_taskgroups_joint,
+                           static_argnums=(3, 4)).lower(
+                stacked, member, local, 16, features).as_text()
+        lines, shapes = _gathers_in_loops(text)
+        assert lines > 100, "no loop found: the guard has nothing to read"
+        assert not [s for s in shapes if self.N_PAD in s], shapes
+        # the rank plane is there, scattered once a launch
+        assert f"tensor<{self.N_PAD}xi32>" in text and "stablehlo.scatter" in text

@@ -112,6 +112,63 @@ class TestShardedWaveParity:
                                        rtol=1e-6, atol=1e-7)
 
 
+    @pytest.mark.parametrize("program", ["fused_sharded", "joint_sharded"])
+    def test_tied_shuffled_wave_identical_to_single_device(self, wave_mesh,
+                                                           program):
+        """Identical empty nodes, every member with its own shuffle: a
+        tie goes to the node the member's permutation meets first. Both
+        mesh programs and the one-chip ``joint`` take the rank plane from
+        the same ``ops/kernel._inv``."""
+        from nomad_tpu.ops.kernel import LEAN_FEATURES, build_kernel_in
+        from nomad_tpu.parallel.synthetic import (
+            synthetic_cluster,
+            synthetic_eval,
+        )
+
+        cluster = synthetic_cluster(200, cpu=2000.0, mem=4096.0,
+                                    disk=50000.0, seed=5)
+        rng = np.random.default_rng(30)
+        feats0 = LEAN_FEATURES._replace(with_topk=True, with_shuffle=True)
+        kins, steps, feats = [], [], []
+        for _ in range(4):
+            ev = synthetic_eval(cluster, desired_count=6)
+            perm = rng.permutation(cluster.n_pad).astype(np.int32)
+            kins.append(build_kernel_in(cluster, ev, 6, node_perm=perm))
+            steps.append(6)
+            feats.append(feats0)
+
+        prior = coalesce.fused_wave_enabled()
+        coalesce.configure_fused_wave(False)      # the composite ``joint``
+        coalesce.configure_wave_mesh(None)
+        try:
+            single = coalesce.launch_wave(kins, steps, feats)
+            coalesce.configure_fused_wave(program == "fused_sharded")
+            fused_before = coalesce.fused_wave_stats.snapshot()["launches"]
+            before = coalesce.sharded_wave_launches
+            coalesce.configure_wave_mesh(wave_mesh)
+            sharded = coalesce.launch_wave(kins, steps, feats)
+        finally:
+            coalesce.configure_wave_mesh(None)
+            coalesce.configure_fused_wave(prior)
+        assert coalesce.sharded_wave_launches == before + 1
+        assert (coalesce.fused_wave_stats.snapshot()["launches"]
+                - fused_before) == (program == "fused_sharded")
+
+        # the wave's first step meets nothing but ties
+        first = kins[0].node_perm[
+            np.argmax(np.asarray(kins[0].base_mask)[kins[0].node_perm])]
+        assert np.asarray(single[0].chosen)[0] == first != 0
+        for s, m in zip(single, sharded):
+            assert np.asarray(s.found).all()
+            np.testing.assert_array_equal(np.asarray(s.chosen),
+                                          np.asarray(m.chosen))
+            np.testing.assert_array_equal(np.asarray(s.topk_idx),
+                                          np.asarray(m.topk_idx))
+            np.testing.assert_allclose(np.asarray(s.scores),
+                                       np.asarray(m.scores),
+                                       rtol=1e-6, atol=1e-7)
+
+
 def _shared_layout_wave(n_nodes=200, members=4, k=3, seed=5):
     """B kins whose three sharing groups are ALL identity-shared (the
     live stack.py build's steady shape): wave-shared planes from one

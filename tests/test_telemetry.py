@@ -622,7 +622,9 @@ class TestTraceDecomposition:
     def test_traced_burst_attributes_90_percent(self, tmp_path):
         """The acceptance criterion: the live e2e bench path with
         tracing on emits TRACE_DECOMP.json attributing >= 90% of
-        per-eval wall time to named spans (CPU backend).
+        the burst to named spans (CPU backend): of its threads' CPU
+        (``cpu_coverage``), and of the median evaluation's wall
+        (``tail.p50_coverage``).
 
         Runs bench/trace_report.py in a SUBPROCESS — the bench's own
         shape. In-suite, ~550 earlier tests leave daemon threads
@@ -643,14 +645,18 @@ class TestTraceDecomposition:
                 wave.get("launches", 1), 1)
             return size >= 0.8 * 32 or size >= 0.85 * wave_avg
 
-        def raw_share(d):
-            # instrumentation COVERAGE is a raw-sum question: the
-            # deduped attributed_share (≤ 1.0 by construction) folds
-            # pipelining overlap out, so a fully-instrumented fast
-            # burst can dedupe slightly below 0.9 while every wall
-            # second is in fact covered
-            return d.get("attributed_raw_s", d["attributed_s"]) \
-                / max(d["wall_s"], 1e-9)
+        def covered(d):
+            # instrumentation COVERAGE is a question of CPU over CPU:
+            # of what the interpreter's threads burned over the burst,
+            # the share inside a named span. The raw wall share this
+            # gate read before (attributed_raw_s / wall_s) counted
+            # device wall that overlaps host work twice: 1.05 to 1.07
+            # on bursts whose waves a deadline cut into pieces, 0.81
+            # on the same instrumentation once a wave stays whole
+            # (PERF.md finding 30-3). Overlap cannot raise this one
+            # and a contended host cannot lower it, so it needs no
+            # fallback.
+            return d["cpu_coverage"]
 
         for _attempt in range(2):
             # 300 jobs x 3 allocs (not 100 x 5): the share gates divide
@@ -684,7 +690,7 @@ class TestTraceDecomposition:
                 tail.get("histogram", {}).get("count")
                 == tail.get("committed_evals")
                 and tail.get("p50_coverage", 0.0) >= 0.90)
-            if raw_share(decomp) >= 0.9 \
+            if covered(decomp) >= 0.9 \
                     and ss["jit_cache_misses"] == 0 \
                     and decomp["allocs_placed"] == decomp["allocs_wanted"] \
                     and sched_ok \
@@ -694,12 +700,10 @@ class TestTraceDecomposition:
                          <= 50_000 * decomp["n_evals"]):
                 break
         assert decomp["allocs_placed"] == decomp["allocs_wanted"]
-        # raw wall coverage on a quiet host; the steal-invariant busy
-        # share (attributed / process CPU actually received) is the
-        # fallback when CI neighbors or the parent suite's leaked
-        # threads stretch wall with time this process never had
-        assert raw_share(decomp) >= 0.9 \
-            or decomp["attributed_share_busy"] >= 0.9, decomp
+        assert covered(decomp) >= 0.9, {
+            k: decomp[k] for k in (
+                "cpu_coverage", "named_cpu_s", "python_cpu_s", "wall_s",
+                "attributed_raw_s", "attributed_share_busy")}
         for stage in ("dequeue", "snapshot", "sched-host",
                       "wave-assembly", "h2d", "execute", "d2h",
                       "plan-apply", "fsm"):

@@ -274,10 +274,11 @@ server {
 
 
 class TestAdaptiveCoalescer:
-    def test_partial_wave_fires_at_deadline(self, monkeypatch):
-        """Two of four participants park; the wave must fire at the
-        window deadline with just those two — no waiting on members
-        that never arrive."""
+    @staticmethod
+    def _stubbed(monkeypatch, latency_s=0.02):
+        """(coalesce, fired, KinStub): ``launch_wave`` stubbed to note the
+        wave sizes, and a wave-latency sample short enough to arm a
+        deadline (a cold process parks for full waves)."""
         from nomad_tpu.parallel import coalesce
 
         fired = []
@@ -287,37 +288,133 @@ class TestAdaptiveCoalescer:
             return [object() for _ in kins]
 
         monkeypatch.setattr(coalesce, "launch_wave", stub_launch_wave)
-        # deadlines only arm once a wave-latency sample exists (a cold
-        # process parks for full waves); seed one for the test
         monkeypatch.setattr(coalesce, "wave_latency_ewma",
                             coalesce._LatencyEWMA())
-        coalesce.wave_latency_ewma.update(0.02)
+        monkeypatch.setattr(coalesce, "wave_deadline_ewma",
+                            coalesce._LatencyEWMA(alpha=0.25))
+        coalesce.wave_latency_ewma.update(latency_s)
 
         class KinStub:
             class _Arr:
                 shape = (8,)
             cap_cpu = _Arr()
 
+        return coalesce, fired, KinStub
+
+    @staticmethod
+    def _until(cond, seconds=5.0):
+        t_end = time.perf_counter() + seconds
+        while not cond() and time.perf_counter() < t_end:
+            time.sleep(0.005)
+        return cond()
+
+    def test_partial_wave_fires_at_deadline(self, monkeypatch):
+        """All four participants have arrived once (their first wave);
+        two place again and park, the other two linger: the wave fires
+        at the window deadline with just those two."""
+        coalesce, fired, KinStub = self._stubbed(monkeypatch)
         c = coalesce.LaunchCoalescer(4, window_min_s=0.01,
                                      window_max_s=0.01)
         results = {}
+        linger = threading.Event()
 
         def member(i):
-            results[i] = c.launch(KinStub(), 1, None)
+            try:
+                c.launch(KinStub(), 1, None)
+                if i < 2:
+                    results[i] = c.launch(KinStub(), 1, None)
+                else:
+                    linger.wait(10)
+            finally:
+                c.done()
 
-        t0 = time.perf_counter()
+        threads = [threading.Thread(target=member, args=(i,))
+                   for i in range(4)]
+        for t in threads:
+            t.start()
+        try:
+            assert self._until(lambda: len(fired) == 2), (
+                f"deadline never fired: {fired}")
+        finally:
+            linger.set()
+            for t in threads:
+                t.join(timeout=10)
+        assert fired == [4, 2]
+        assert results[0] is not None and results[1] is not None
+        assert c.deadline_launches == 1
+
+    def test_first_wave_waits_for_members_still_on_their_way(
+            self, monkeypatch):
+        """A 20 ms wave latency arms deadlines, but one participant of
+        the batch has not arrived yet (its tensors are being built): the
+        two that have park for the full wave, and each such wait is
+        counted (ISSUE 30; a deadline here cut every wave of PERF.md
+        finding 27-2)."""
+        coalesce, fired, KinStub = self._stubbed(monkeypatch)
+        c = coalesce.LaunchCoalescer(3, window_min_s=0.001,
+                                     window_max_s=0.050)
+        assert c._window_s() is not None
+        held0 = coalesce.wave_stats.snapshot()["held_for_arrivals"]
+
+        def member():
+            try:
+                c.launch(KinStub(), 1, None)
+            finally:
+                c.done()
+
+        threads = [threading.Thread(target=member) for _ in range(3)]
+        for t in threads[:2]:
+            t.start()
+        time.sleep(0.25)
+        assert fired == [], "a deadline cut the first wave"
+        threads[2].start()
+        for t in threads:
+            t.join(timeout=10)
+        assert fired == [3]
+        assert c.deadline_launches == 0
+        snap = coalesce.wave_stats.snapshot()
+        assert snap["held_for_arrivals"] - held0 == 2
+
+    @pytest.mark.parametrize("how", ["done", "suspend"])
+    def test_a_member_that_finishes_or_steps_aside_has_arrived(
+            self, monkeypatch, how):
+        """Deadlines work as before once every participant has launched
+        once, finished without launching (``done``) or stepped aside
+        (``suspend``): here the third does one of the latter, the other
+        two ride one wave, one of them places again and its deadline
+        fires."""
+        coalesce, fired, KinStub = self._stubbed(monkeypatch)
+        c = coalesce.LaunchCoalescer(3, window_min_s=0.01,
+                                     window_max_s=0.01)
+        getattr(c, how)()                # this thread is the third member
+        out = {}
+        linger = threading.Event()
+
+        def member(i):
+            try:
+                c.launch(KinStub(), 1, None)
+                if i == 0:
+                    out[i] = c.launch(KinStub(), 1, None)
+                else:
+                    linger.wait(10)
+            finally:
+                c.done()
+
         threads = [threading.Thread(target=member, args=(i,))
                    for i in range(2)]
         for t in threads:
             t.start()
-        for t in threads:
-            t.join(timeout=10)
-        dt = time.perf_counter() - t0
-        assert fired == [2]
-        assert results[0] is not None and results[1] is not None
-        assert dt < 5.0, "deadline never fired"
+        try:
+            assert self._until(lambda: len(fired) == 2), (
+                f"deadline never fired: {fired}")
+        finally:
+            linger.set()
+            for t in threads:
+                t.join(timeout=10)
+        assert fired == [2, 1] and out[0] is not None
         assert c.deadline_launches == 1
-        for _ in range(4):
+        if how == "suspend":
+            c.resume()
             c.done()
 
     @pytest.mark.parametrize("latency_s, window_max_s", [
@@ -479,6 +576,8 @@ class TestAdaptiveCoalescer:
         assert 'nomad_tpu_wave_park_latency_seconds{quantile="0.99"}' \
             in text
         assert 'nomad_tpu_wave_launches_total{fired="deadline"}' in text
+        assert ("nomad_tpu_wave_held_for_arrivals_total "
+                f"{snap['held_for_arrivals']}") in text
 
 
 class TestFeatureCanonicalization:
@@ -619,3 +718,52 @@ class TestDecomposeDedupe:
         }
         out = trace_report.decompose(stage_totals, 1.0, 10)
         assert out["attributed_share"] == pytest.approx(0.5)
+
+    def test_cpu_coverage_is_thread_cpu_over_thread_cpu(self):
+        """Overlap cannot raise it: a device stage's wall is not in it,
+        a wait counts by the CPU burned inside it and never by its
+        wall, a span no table names counts on the spent side only, and
+        a thread started since the first reading counts whole."""
+        import trace_report
+
+        def agg(total_s, cpu_s):
+            return {"count": 1, "total_s": total_s, "exclusive_s": total_s,
+                    "cpu_s": cpu_s, "exclusive_cpu_s": cpu_s}
+
+        stage_totals = {
+            "eval.schedule": agg(3.0, 0.5),
+            "kernel.execute": agg(2.0, 0.01),     # wall stage: its CPU
+            "wave.park": agg(9.0, 0.08),          # a wait: its CPU
+            "bg.drainer": agg(0.1, 0.01),
+            "some.unnamed": agg(0.3, 0.3),
+        }
+        out = trace_report.cpu_coverage(
+            stage_totals, before={1: 10.0, 2: 5.0},
+            after={1: 10.4, 2: 5.3, 3: 0.3})
+        assert out["named_cpu_s"] == pytest.approx(0.6)
+        assert out["python_cpu_s"] == pytest.approx(1.0)
+        assert out["cpu_coverage"] == pytest.approx(0.6)
+
+    def test_python_threads_cpu_reads_every_live_thread(self):
+        import trace_report
+
+        stop = threading.Event()
+
+        def spin():
+            while not stop.is_set():
+                pass
+
+        spinner = threading.Thread(target=spin, daemon=True)
+        spinner.start()
+        try:
+            first = trace_report.python_threads_cpu_s()
+            mine = time.thread_time()
+            while time.thread_time() - mine < 0.02:
+                pass
+            second = trace_report.python_threads_cpu_s()
+        finally:
+            stop.set()
+            spinner.join()
+        me = threading.get_ident()
+        assert second[me] - first[me] >= 0.02
+        assert second[spinner.ident] > first[spinner.ident]
