@@ -389,20 +389,10 @@ def run_tpu(budget_s: float = None) -> dict:
     # lean variant: the baseline's asks are cpu/mem/disk binpack only,
     # so compile without port/device/core/spread/top-k planes (the same
     # static specialization the real stack infers per ask); topk=True
-    # engages the candidate-set kernel (exact, bound-checked). On TPU
-    # the fused pallas candidate scan competes with the XLA scan; a
-    # short calibration burst picks the faster per machine.
+    # engages the candidate-set kernel (exact, bound-checked).
     backend = jax.default_backend()
     candidates = [("xla_topk", make_schedule_apply_loop(
         PLACEMENTS_PER_EVAL, LEAN_FEATURES, topk=True))]
-    if backend not in ("cpu",):
-        try:
-            candidates.append(("pallas_topk", make_schedule_apply_loop(
-                PLACEMENTS_PER_EVAL, LEAN_FEATURES, topk=True,
-                backend="pallas_topk")))
-        except Exception as e:                   # noqa: BLE001
-            print(f"warning: pallas backend unavailable: {e}",
-                  file=sys.stderr)
 
     npad = cluster.n_pad
     batch, n_batches = _bench_batch(backend)
@@ -930,14 +920,6 @@ def run_replay(planes, budget_s: float = None) -> dict:
     backend = jax.default_backend()
     candidates = [("xla_topk", make_schedule_apply_loop(
         PLACEMENTS_PER_EVAL, LEAN_FEATURES, topk=True, reset_every=1))]
-    if backend not in ("cpu",):
-        try:
-            candidates.append(("pallas_topk", make_schedule_apply_loop(
-                PLACEMENTS_PER_EVAL, LEAN_FEATURES, topk=True,
-                backend="pallas_topk", reset_every=1)))
-        except Exception as e:                   # noqa: BLE001
-            print(f"warning: pallas backend unavailable: {e}",
-                  file=sys.stderr)
 
     batch, n_batches = _bench_batch(backend)
     n_steps = jnp.asarray(
@@ -1114,15 +1096,10 @@ def main() -> None:
                     "sharded_wave_launches"),
                 trace_steady_sharded_fallbacks=steady.get(
                     "sharded_wave_fallbacks"),
-                # ISSUE 19 steady keys: every steady wave through the
-                # fused mega-kernel (fallbacks gated 0), exactly ONE
-                # wave-critical device dispatch per wave
+                # wave-critical device interactions per steady wave:
+                # ``joint`` and its eager result fetch
                 trace_steady_dispatches_per_wave=steady.get(
                     "dispatches_per_wave"),
-                trace_steady_fused_launches=steady.get(
-                    "fused_wave_launches"),
-                trace_steady_fused_fallbacks=steady.get(
-                    "fused_wave_fallbacks"),
             )
             # ISSUE 8: the steady burst's e2e latency distribution +
             # tail attribution (TRACE_DECOMP gains the "tail" section;
@@ -1351,57 +1328,6 @@ def main() -> None:
                   file=sys.stderr)
     else:
         print("bench budget: skipping mesh cell "
-              f"({budget.remaining():.0f}s left)", file=sys.stderr)
-
-    # ISSUE 19: the fused cell — the fused wave mega-kernel A/B'd
-    # against the composite joint program + its eager result fetch on
-    # the SAME burst of waves. fused_parity_ok (bit-identity incl. the
-    # top-k planes) + fused_dispatches_per_wave == 1.0 +
-    # fused_fallbacks == 0 are the acceptance lines; fused_speedup is
-    # the per-box trajectory line (the composite arm costs one extra
-    # device interaction per wave — the eager fetch the fused program
-    # folds into its own dispatch). Reproduce with
-    # trace_report.run_fused_burst().
-    if budget.remaining() > 60:
-        try:
-            _phase("fused cell")
-            sys.path.insert(0, os.path.join(REPO, "bench"))
-            import trace_report
-
-            cell = trace_report.run_fused_burst()
-            em.update(
-                fused_nodes=cell["nodes"],
-                fused_waves=cell["waves"],
-                fused_wave_ms_p50=cell["fused_wave_ms_p50"],
-                fused_composite_wave_ms_p50=cell[
-                    "composite_wave_ms_p50"],
-                fused_speedup=cell["speedup"],
-                fused_parity_ok=cell["parity_ok"],
-                fused_dispatches_per_wave=cell["dispatches_per_wave"],
-                fused_composite_dispatches_per_wave=cell[
-                    "composite_dispatches_per_wave"],
-                fused_launches=cell["launches"],
-                fused_fallbacks=cell["fallbacks"],
-                fused_jit_cache_misses=cell["jit_cache_misses"],
-                fused_d2h_bytes_per_wave=cell["d2h_bytes_per_wave"],
-                fused_composite_d2h_bytes_per_wave=cell[
-                    "composite_d2h_bytes_per_wave"],
-            )
-            if not cell["parity_ok"]:
-                print("warning: fused cell parity FAILED (fused wave "
-                      "diverged from the composite program)",
-                      file=sys.stderr)
-            if cell["dispatches_per_wave"] != 1.0 or cell["fallbacks"]:
-                print("warning: fused cell dispatch gate FAILED "
-                      f"(dispatches/wave {cell['dispatches_per_wave']}"
-                      f", fallbacks {cell['fallbacks']})",
-                      file=sys.stderr)
-        except Exception as e:                   # noqa: BLE001
-            import traceback
-            traceback.print_exc()
-            print(f"warning: fused cell failed ({e})", file=sys.stderr)
-    else:
-        print("bench budget: skipping fused cell "
               f"({budget.remaining():.0f}s left)", file=sys.stderr)
 
     # ISSUE 16: the store cell — the MVCC StateStore alone at the mesh

@@ -311,12 +311,12 @@ def _settle_committed(server, done0: int, timeout_s: float = 5.0) -> int:
     return committed
 
 
-# Programs whose dispatches sit ON the wave critical path: the fused
-# mega-kernel (one per wave by construction), the composite joint
+# Programs whose dispatches sit ON the wave critical path: the mesh's
+# fused program (one per wave by construction), the composite joint
 # program, and the composite's eager result fetch. single_topk
 # (uncoalesced evals) and topk_drain (deferred, plan-window) are
 # excluded — they are not wave-critical. (ISSUE 19)
-_WAVE_DISPATCH_PROGRAMS = ("joint", "joint_sharded", "fused_wave",
+_WAVE_DISPATCH_PROGRAMS = ("joint", "joint_sharded",
                            "fused_wave_sharded", "wave_fetch")
 
 
@@ -663,17 +663,19 @@ def run_traced_burst(n_nodes: int = 1000, n_jobs: int = 100,
                 "wave_sharded", {}).get("fallbacks", 0),
             "mesh_devices": decomp.get(
                 "wave_sharded", {}).get("mesh_devices", 0),
-            # ISSUE 19 steady gates: every steady wave must run the
-            # fused mega-kernel (fallbacks 0) and cost exactly ONE
-            # wave-critical device dispatch. The quotient counts the
-            # wave programs + the composite's eager result fetch over
-            # wave launches; the deferred top-k drain is excluded —
-            # it runs in the plan window, off the critical path
-            # (dispatches{program="topk_drain"} still exports it)
+            # ISSUE 19 steady gates, the mesh's: every steady wave of
+            # a mesh server must run the fused sharded program
+            # (fallbacks 0) and cost exactly ONE wave-critical device
+            # dispatch; a one-device wave is ``joint`` and its eager
+            # result fetch, two. The quotient counts the wave programs
+            # + the eager fetch over wave launches; the deferred top-k
+            # drain is excluded — it runs in the plan window, off the
+            # critical path (dispatches{program="topk_drain"} still
+            # exports it)
             "dispatches_per_wave": _dispatches_per_wave(decomp),
-            "fused_wave_launches": decomp.get(
+            "fused_sharded_launches": decomp.get(
                 "wave_fused", {}).get("launches", 0),
-            "fused_wave_fallbacks": decomp.get(
+            "fused_sharded_fallbacks": decomp.get(
                 "wave_fused", {}).get("fallbacks", 0),
         }
         return decomp
@@ -2005,170 +2007,6 @@ def run_mesh_burst(n_nodes: int = 100_000, n_allocs: int = 1_000_000,
         }
     finally:
         default_device_state.configure_mesh(prior_mesh)
-        if not was_enabled:
-            telemetry.disable()
-
-
-# ---------------------------------------------------------------------------
-# The fused cell (ISSUE 19): fused mega-kernel vs composite program on
-# the SAME burst — speedup, bit-parity, and the dispatch quotient.
-# ---------------------------------------------------------------------------
-
-FUSED_CELL_SEED = 19019
-
-
-def run_fused_burst(n_nodes: int = 20_000, n_allocs: int = 100_000,
-                    batch_size: int = 32, steps_per_eval: int = 4,
-                    waves: int = 8, n_devices: int = 0,
-                    use_mesh: bool = False,
-                    seed: int = FUSED_CELL_SEED) -> Dict:
-    """The standing fused A/B (ISSUE 19): one burst of identical waves
-    dispatched twice — through the fused wave mega-kernel (ONE device
-    dispatch per wave) and through the composite joint program + its
-    eager result fetch (two device interactions per wave). Same
-    heterogeneous cluster family as the mesh cell, same wave inputs in
-    both arms, both arms warmed OUTSIDE their timed windows:
-
-    - ``speedup`` = composite p50 wave wall / fused p50 wave wall (a
-      trajectory line per box, like every cell ratio);
-    - ``parity_ok`` = chosen/found/scores AND the top-k planes match
-      the composite bit-for-bit (the property suite's identity,
-      standing in the cell);
-    - ``dispatches_per_wave`` must be exactly 1.0 on the fused arm
-      (and 2.0 on the composite arm: program + eager fetch);
-    - ``fallbacks`` must be 0 — every wave of the burst fits the
-      fused envelope by construction;
-    - d2h per wave is reported for both arms (the fused packed
-      readback is strictly smaller than the composite fetch).
-
-    With ``use_mesh`` the A/B runs the sharded programs on the
-    device mesh instead (``fused_wave_sharded`` vs ``joint_sharded``).
-    """
-    import jax
-    import numpy as np
-
-    from nomad_tpu import telemetry
-    from nomad_tpu.ops.kernel import (
-        LEAN_FEATURES,
-        build_kernel_in,
-        neutral_planes,
-    )
-    from nomad_tpu.parallel import coalesce
-    from nomad_tpu.parallel.sharded import wave_mesh
-    from nomad_tpu.parallel.synthetic import synthetic_eval
-    from nomad_tpu.telemetry.histogram import percentile
-    from nomad_tpu.telemetry.kernel_profile import profiler
-
-    mesh = wave_mesh(n_devices) if use_mesh else None
-    cluster = _mesh_cluster(n_nodes, seed)
-    usage = _MeshUsage(cluster.node_ids)
-    _mesh_pack_allocs(cluster, usage, n_allocs, seed)
-
-    ev = synthetic_eval(cluster, desired_count=steps_per_eval)
-    neutral = neutral_planes(cluster.n_pad)
-    base_mask = cluster.ready.copy()
-    base_mask.setflags(write=False)
-    rng = np.random.default_rng(seed + 2)
-    feats = [LEAN_FEATURES._replace(with_topk=True)] * batch_size
-    steps = [steps_per_eval] * batch_size
-    ask_cpu = rng.choice([250.0, 500.0, 1000.0], size=batch_size)
-    ask_mem = rng.choice([128.0, 256.0, 1024.0], size=batch_size)
-
-    shared = cluster.wave_shared_planes(usage)
-    base = build_kernel_in(cluster, ev, steps_per_eval)
-    base = base._replace(
-        **{f: shared[f] for f in shared},
-        port_conflict=neutral.zeros_bool,
-        dev_free=neutral.zeros_dev,
-        dev_aff_score=neutral.zeros_f32,
-        job_tg_count=neutral.zeros_i32,
-        job_any_count=neutral.zeros_i32,
-        penalty=neutral.zeros_bool,
-        aff_score=neutral.zeros_f32,
-        base_mask=base_mask,
-    )
-    # ONE fixed wave input, re-dispatched every wave: the A/B wants
-    # the steady-state program cost, not usage drift
-    kins = [base._replace(
-        ask_cpu=np.asarray(ask_cpu[i], np.float32),
-        ask_mem=np.asarray(ask_mem[i], np.float32),
-    ) for i in range(batch_size)]
-
-    was_enabled = telemetry.enabled()
-    fused_prior = coalesce.fused_wave_enabled()
-    telemetry.enable()
-
-    def run_arm(fused_on: bool) -> Dict:
-        coalesce.configure_fused_wave(fused_on)
-        # compile pass outside the timed window, then a clean stats
-        # window covering exactly this arm's waves
-        coalesce.launch_wave(kins, steps, feats, mesh=mesh)
-        telemetry.reset()
-        ms = []
-        outs = None
-        for _ in range(waves):
-            tw = time.perf_counter()
-            outs = coalesce.launch_wave(kins, steps, feats, mesh=mesh)
-            ms.append((time.perf_counter() - tw) * 1e3)
-        prof = profiler.summary()
-        fw = coalesce.fused_wave_stats.snapshot()
-        return {
-            "outs": outs,
-            "ms_p50": percentile(ms, 0.5),
-            "dispatches_per_wave": _wave_dispatch_quotient(
-                prof.get("Dispatches", {}), waves),
-            "jit_cache_misses": prof["JitCacheMisses"],
-            "launches": fw["launches"],
-            "fallbacks": fw["fallbacks"],
-            "d2h_per_wave": prof["TransferBytes"]["d2h"]
-            / max(waves, 1),
-        }
-
-    try:
-        fused = run_arm(True)
-        comp = run_arm(False)
-
-        # bit-parity over every member, every plane — including the
-        # lazy top-k (drained here, outside both timed windows)
-        parity_ok = True
-        for a, b in zip(fused["outs"], comp["outs"]):
-            if not (np.array_equal(np.asarray(a.chosen),
-                                   np.asarray(b.chosen))
-                    and np.array_equal(np.asarray(a.found),
-                                       np.asarray(b.found))
-                    and np.array_equal(np.asarray(a.scores),
-                                       np.asarray(b.scores))
-                    and np.array_equal(np.asarray(a.topk_idx),
-                                       np.asarray(b.topk_idx))
-                    and np.array_equal(np.asarray(a.topk_scores),
-                                       np.asarray(b.topk_scores))):
-                parity_ok = False
-
-        speedup = (comp["ms_p50"] / fused["ms_p50"]
-                   if fused["ms_p50"] > 0 else 0.0)
-        return {
-            "backend": jax.default_backend(),
-            "devices": int(mesh.size) if mesh is not None else 1,
-            "nodes": n_nodes,
-            "n_pad": cluster.n_pad,
-            "batch_size": batch_size,
-            "waves": waves,
-            "fused_wave_ms_p50": round(fused["ms_p50"], 3),
-            "composite_wave_ms_p50": round(comp["ms_p50"], 3),
-            "speedup": round(speedup, 4),
-            "parity_ok": parity_ok,
-            "dispatches_per_wave": fused["dispatches_per_wave"],
-            "composite_dispatches_per_wave":
-                comp["dispatches_per_wave"],
-            "launches": fused["launches"],
-            "fallbacks": fused["fallbacks"],
-            "jit_cache_misses": fused["jit_cache_misses"],
-            "d2h_bytes_per_wave": round(fused["d2h_per_wave"]),
-            "composite_d2h_bytes_per_wave":
-                round(comp["d2h_per_wave"]),
-        }
-    finally:
-        coalesce.configure_fused_wave(fused_prior)
         if not was_enabled:
             telemetry.disable()
 

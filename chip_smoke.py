@@ -140,11 +140,11 @@ def run_burst(api, server, jobs, timeout_s: float) -> float:
 
 
 def wave_programs(profiler) -> list:
-    """(kernel, features) of every wave program dispatched since the
-    profiler's last reset."""
+    """(kernel, padded nodes, features) of every wave program
+    dispatched since the profiler's last reset."""
     from nomad_tpu.ops.kernel import KernelFeatures
 
-    return [(kernel,
+    return [(kernel, key[2],
              next(p for p in key if isinstance(p, KernelFeatures)))
             for kernel, key in profiler.keys()
             if not kernel.startswith("single_")]
@@ -164,20 +164,31 @@ def dispatched(profiler) -> str:
     return f"{programs}; {others}" if others else programs
 
 
-def check_burst_programs(profiler, label: str, program: str,
-                         lean: bool) -> None:
-    """The burst's waves ran the program the router is meant to choose
-    for them."""
-    from nomad_tpu.ops.kernel import fused_wave_supported
+def check_burst_programs(profiler, label: str, n_devices: int,
+                         program: str, mixed: bool) -> None:
+    """Every wave of the burst ran the program the launcher's router
+    (coalesce.wave_program) names for its nodes and features. A lean
+    burst's waves all ran ``program``; a mixed burst put its spread
+    and GPU asks into a wave that ran ``program``."""
+    from nomad_tpu.ops.kernel import features_key
+    from nomad_tpu.parallel.coalesce import wave_program
 
     waves = wave_programs(profiler)
-    if not any(k == program and fused_wave_supported(f) == lean
-               for k, f in waves):
-        raise SmokeFailure(
-            f"{label}: no dispatch of wave program {program!r} with a "
-            f"{'lean' if lean else 'mixed'} feature union; dispatched: "
-            f"{dispatched(profiler)}")
-    if lean and any(k != program for k, _f in waves):
+    for kernel, n_nodes, feats in waves:
+        want = wave_program(n_devices, n_nodes, feats)
+        if kernel != want:
+            raise SmokeFailure(
+                f"{label}: a wave over {n_nodes} nodes with "
+                f"{features_key(feats)} ran {kernel!r}, the router "
+                f"names {want!r}; dispatched: {dispatched(profiler)}")
+    if mixed:
+        if not any(k == program and (f.n_spreads or f.with_devices)
+                   for k, _n, f in waves):
+            raise SmokeFailure(
+                f"{label}: no dispatch of wave program {program!r} "
+                f"with a spread or a device ask in its feature union; "
+                f"dispatched: {dispatched(profiler)}")
+    elif not waves or any(k != program for k, _n, _f in waves):
         raise SmokeFailure(
             f"{label}: lean waves left the lean program {program!r}: "
             f"{dispatched(profiler)}")
@@ -376,8 +387,10 @@ def main() -> int:
 
     from nomad_tpu.api.agent import Agent, AgentConfig
     from nomad_tpu.api.client import APIClient
+    from nomad_tpu.ops.kernel import FULL_FEATURES, LEAN_FEATURES
     from nomad_tpu.parallel import coalesce
     from nomad_tpu.telemetry.kernel_profile import profiler
+    from nomad_tpu.tensors.schema import pad_bucket
 
     from importlib import metadata
 
@@ -431,12 +444,11 @@ def main() -> int:
         first_job = draw_jobs(c2m, rng, "first", 1, ("service",))
         lean_jobs = draw_jobs(c2m, rng, "lean", args.jobs, LEAN_KINDS)
         mixed_jobs = draw_jobs(c2m, rng, "mixed", args.jobs)
-        sharded = len(devices) > 1
-        suffix = "_sharded" if sharded else ""
-        mixed_program = "joint" + suffix
-        lean_program = ("fused_wave" + suffix
-                        if coalesce.fused_wave_routes(sharded)
-                        else mixed_program)
+        n_pad = pad_bucket(n_nodes)
+        lean_program = coalesce.wave_program(
+            len(devices), n_pad, LEAN_FEATURES)
+        mixed_program = coalesce.wave_program(
+            len(devices), n_pad, FULL_FEATURES)
 
         # One job alone first, as on any server that has scheduled
         # before its first backlog arrives: it takes the single-eval
@@ -445,10 +457,10 @@ def main() -> int:
         # PERF.md, "Bring-up on v5e".)
         profiler.enable()
         new_job_ids = set()
-        for label, jobs, program, lean in (
-                ("first_job", first_job, None, True),
-                ("lean_burst", lean_jobs, lean_program, True),
-                ("mixed_burst", mixed_jobs, mixed_program, False)):
+        for label, jobs, program, mixed in (
+                ("first_job", first_job, None, False),
+                ("lean_burst", lean_jobs, lean_program, False),
+                ("mixed_burst", mixed_jobs, mixed_program, True)):
             profiler.reset()
             new_job_ids.update(job.id for _, job in jobs)
             wall = run_burst(api, server, jobs, timeout_s=300.0)
@@ -463,7 +475,8 @@ def main() -> int:
                 check_output_devices(profiler, ran, platform, len(devices))
                 served_by = "/".join(sorted(set(ran)))
             else:
-                check_burst_programs(profiler, label, program, lean)
+                check_burst_programs(profiler, label, len(devices),
+                                     program, mixed)
                 check_output_devices(profiler, [program], platform,
                                      len(devices))
                 served_by = program

@@ -243,8 +243,7 @@ def canonical_features(f: KernelFeatures) -> KernelFeatures:
     )
 
 #: the lean cpu/mem/disk binpack envelope — what a plain service/batch
-#: ask compiles to, and the exact feature set the pallas backend
-#: (ops/pallas_kernel.py) implements; bench + parity tests pin it
+#: ask compiles to; bench + parity tests pin it
 LEAN_FEATURES = KernelFeatures(
     n_spreads=0, with_topk=False, with_devices=False, with_ports=False,
     with_cores=False, with_network=False, with_distinct=False,
@@ -1356,19 +1355,14 @@ place_taskgroups_joint_jit = jax.jit(
 
 
 # ---------------------------------------------------------------------------
-# Fused wave dispatch (ISSUE 19): ONE device program per wave.
-#
-# The composite path above costs two wave-critical device interactions
-# per launch: the joint program execution, then an eager per-field
-# fetch of eleven separate output buffers. The fused variant runs the
-# same scan as a single Pallas program (ops/pallas_kernel.fused_wave
-# _place — interpreted off-TPU; it does not lower through Mosaic, so
-# on TPU the launcher never routes here, see that module) and PACKS
-# everything the launcher fetches eagerly into one
-# flat f32 buffer, so steady state is one dispatch and one readback
-# that rides the dispatch's own synchronization. The top-k planes stay
-# separate device outputs — they are lazy (_WaveTopK) and drain in the
-# plan window, off the wave-critical path.
+# The packed wave read-back of the mesh's fused program
+# (parallel/sharded.fused_sharded_entry): everything the launcher
+# fetches eagerly in ONE flat f32 buffer, so a fused sharded wave is
+# one dispatch and one readback that rides the dispatch's own
+# synchronization. The top-k planes stay separate device outputs —
+# they are lazy (coalesce._WaveTopK) and drain in the plan window, off
+# the wave-critical path. One-device waves run ``joint`` above and
+# fetch its fields one by one.
 # ---------------------------------------------------------------------------
 
 #: JointOut metric fields in packed-segment order (8 x [B] after the
@@ -1401,20 +1395,17 @@ class FusedWaveOut(NamedTuple):
 
 def fused_wave_supported(f: KernelFeatures) -> bool:
     """Whether a wave's (canonical) feature union fits the fused
-    mega-kernel's envelope. Ports, preemption penalties, preferred
+    sharded program's envelope. Ports, preemption penalties, preferred
     pins, distinct_hosts, shuffle, and top-k are all in (shuffle is
     ALWAYS on for live evals — scheduler/generic.py seeds it per
     eval, so excluding it would turn every live wave into a counted
     fallback). Spread stanzas and the device/core/bandwidth planes
     are out: rare in steady traffic and each would widen the fused
-    signature lattice ~2x — those waves take the composite path,
-    counted by ``fused_wave_stats``."""
+    signature lattice ~2x — on a mesh those waves run
+    ``joint_sharded``, counted by ``fused_wave_stats``
+    (parallel/coalesce.wave_program is the router)."""
     return (f.n_spreads == 0 and not f.with_devices
             and not f.with_cores and not f.with_network)
-
-
-def fused_pack_len(t_steps: int, b: int) -> int:
-    return 2 * t_steps + 8 * b
 
 
 def pack_fused_wave(out: JointOut, t_steps: int, b: int) -> jnp.ndarray:
@@ -1442,23 +1433,6 @@ def unpack_fused_wave(packed: np.ndarray, t_steps: int, b: int) -> dict:
         host[name] = flat[off:off + b].astype(np.int32)
         off += b
     return host
-
-
-def fused_wave_launch(kin: KernelIn, step_member, step_local,
-                      t_steps: int, features: KernelFeatures,
-                      key: tuple) -> FusedWaveOut:
-    """Single-device fused dispatch: ONE profiled Pallas program per
-    wave, selected per bucket key exactly like the composite (the
-    profiler's miss counter and the AOT warmup manifest both see it
-    as the "fused_wave" kernel)."""
-    from nomad_tpu.ops.pallas_kernel import fused_wave_place_jit
-    from nomad_tpu.telemetry.kernel_profile import profiler
-
-    return profiler.call(
-        "fused_wave", fused_wave_place_jit,
-        (kin, jnp.asarray(step_member), jnp.asarray(step_local)),
-        (t_steps, features), key, jit_fn=fused_wave_place_jit,
-    )
 
 
 def infer_features(ev, any_penalty: bool = True, any_preferred: bool = True,

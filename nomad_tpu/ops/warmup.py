@@ -72,16 +72,13 @@ def manifest_from_profiler(profiler=None) -> List[Dict]:
             if kernel == "joint_sharded" and len(key) == 8:
                 # (joint 7-key, devices-tuple): mesh-agnostic manifest
                 kernel, key = "joint", key[:7]
-            # fused program keys (ISSUE 19) fold into the SAME joint
-            # entries: the fused launcher reuses the wave bucket key
-            # verbatim, and warmup_entries re-derives "also compile
-            # the fused variant" from the entry's feature envelope
-            # (fused_wave_supported) — so one manifest line covers
-            # composite, sharded, fused, and fused-sharded
+            # the mesh's fused program folds into the SAME joint
+            # entries: it reuses the wave bucket key verbatim, and
+            # warmup_entries asks coalesce.wave_program which program
+            # an entry's wave runs — so one manifest line covers
+            # one-device, sharded and fused-sharded
             if kernel == "fused_wave_sharded" and len(key) == 8:
                 kernel, key = "joint", key[:7]
-            if kernel == "fused_wave" and len(key) == 7:
-                kernel = "joint"
             if kernel == "joint" and len(key) in (6, 7):
                 # len 6: pre-job-group keys from persisted manifests
                 # (job_shared defaults True, the common layout)
@@ -334,56 +331,56 @@ def _call_both_placements(fn, arrays: tuple, statics: tuple,
         jax.block_until_ready(out)
 
 
-def _warm_joint(e: Dict) -> bool:
-    import jax.numpy as jnp
-
-    from nomad_tpu.ops.kernel import KernelIn, place_taskgroups_joint_jit
+def _entry_wave(e: Dict):
+    """Build one manifest entry's dummy wave exactly as launch_wave
+    stacks it: the layout predicate is SHARED with the launcher
+    (wave_field_is_shared), because the jit cache keys on shapes.
+    Returns the stacked KernelIn, the step planes, the statics and,
+    field by field, whether the layout ships the leaf shared — which
+    are the leaves the live launcher swaps for device-resident
+    twins."""
+    from nomad_tpu.ops.kernel import KernelIn
     from nomad_tpu.parallel.coalesce import wave_field_is_shared
 
     b_pad = int(e["wave"])
     t_pad = int(e["steps"])
-    n = int(e["nodes"])
-    shared = bool(e.get("shared", True))
-    neutral_shared = bool(e.get("neutral_shared", True))
-    job_shared = bool(e.get("job_shared", True))
+    layout = (bool(e.get("shared", True)),
+              bool(e.get("neutral_shared", True)),
+              bool(e.get("job_shared", True)))
     feats = _features_from_dict(e["features"])
     k_max = max(t_pad // max(b_pad, 1), 1)
-    kin = _dummy_kin(n, k_max)
-
-    def stack_field(f, x):
-        # the layout predicate is SHARED with launch_wave: the jit
-        # cache keys on shapes, so warmup must reproduce the live
-        # stacking exactly
-        if wave_field_is_shared(f, shared, neutral_shared, job_shared):
-            return np.asarray(x)
-        return np.stack([np.asarray(x)] * b_pad)
-
+    kin = _dummy_kin(int(e["nodes"]), k_max)
+    shared = [wave_field_is_shared(f, *layout) for f in KernelIn._fields]
     stacked = KernelIn(*[
-        stack_field(f, getattr(kin, f)) for f in KernelIn._fields
+        np.asarray(x) if sh else np.stack([np.asarray(x)] * b_pad)
+        for x, sh in zip(kin, shared)
     ])
     step_member = np.full(t_pad, -1, np.int32)
     step_local = np.zeros(t_pad, np.int32)
-    pos = 0
-    for i in range(b_pad):
-        step_member[pos:pos + k_max] = i
-        step_local[pos:pos + k_max] = np.arange(k_max)
-        pos += k_max
-    # the resident-state signature: shared leaves committed, the rest
-    # host — exactly the leaves the live launcher swaps for device
-    # twins when the cluster state is resident
-    mixed = [wave_field_is_shared(f, shared, neutral_shared, job_shared)
-             for f in KernelIn._fields]
+    step_member[:b_pad * k_max] = np.repeat(np.arange(b_pad), k_max)
+    step_local[:b_pad * k_max] = np.tile(np.arange(k_max), b_pad)
+    return stacked, step_member, step_local, (t_pad, feats), layout, shared
+
+
+def _warm_joint(e: Dict) -> bool:
+    import jax.numpy as jnp
+
+    from nomad_tpu.ops.kernel import place_taskgroups_joint_jit
+
+    stacked, step_member, step_local, statics, _layout, shared = \
+        _entry_wave(e)
     _call_both_placements(
         place_taskgroups_joint_jit,
         (stacked, jnp.asarray(step_member), jnp.asarray(step_local)),
-        (t_pad, feats), mixed=mixed)
+        statics, mixed=shared)
     return True
 
 
-def _warm_joint_sharded(e: Dict, mesh) -> bool:
-    """Populate the SHARDED joint program's jit cache for a manifest
-    entry (parallel/sharded.make_joint_sharded) — the live signatures
-    a mesh server's waves hit:
+def _warm_sharded(e: Dict, mesh, entry) -> bool:
+    """Populate a mesh program's jit cache for a manifest entry;
+    ``entry`` is the program's own (parallel/sharded.joint_sharded_entry
+    or fused_sharded_entry, as coalesce.wave_program chose). The live
+    signatures a mesh server's waves hit:
 
     1. every leaf host numpy (telemetry off, nothing resident — the
        jit itself uploads per its in_shardings);
@@ -394,177 +391,32 @@ def _warm_joint_sharded(e: Dict, mesh) -> bool:
        resident cluster state + frozen singletons), the rest host.
 
     All three trace onto ONE XLA program; the extra traces are cache
-    hits on the compilation cache. Entries whose node axis the mesh
-    does not divide are skipped — the live launcher falls back to
-    single-device dispatch for those (and counts it)."""
+    hits on the compilation cache."""
     import jax
 
     from nomad_tpu.ops.kernel import KernelIn
-    from nomad_tpu.parallel.coalesce import wave_field_is_shared
-    from nomad_tpu.parallel.sharded import (
-        joint_in_shardings,
-        make_joint_sharded,
-    )
 
-    n = int(e["nodes"])
-    if mesh is None or mesh.size < 2 or n % mesh.size != 0:
-        return False
-    b_pad = int(e["wave"])
-    t_pad = int(e["steps"])
-    shared = bool(e.get("shared", True))
-    neutral_shared = bool(e.get("neutral_shared", True))
-    job_shared = bool(e.get("job_shared", True))
-    feats = _features_from_dict(e["features"])
-    k_max = max(t_pad // max(b_pad, 1), 1)
-    kin = _dummy_kin(n, k_max)
-
-    def stack_field(f, x):
-        if wave_field_is_shared(f, shared, neutral_shared, job_shared):
-            return np.asarray(x)
-        return np.stack([np.asarray(x)] * b_pad)
-
-    stacked = KernelIn(*[
-        stack_field(f, getattr(kin, f)) for f in KernelIn._fields
-    ])
-    step_member = np.full(t_pad, -1, np.int32)
-    step_local = np.zeros(t_pad, np.int32)
-    pos = 0
-    for i in range(b_pad):
-        step_member[pos:pos + k_max] = i
-        step_local[pos:pos + k_max] = np.arange(k_max)
-        pos += k_max
-    fn = make_joint_sharded(mesh, shared, neutral_shared, job_shared)
-    kin_shardings, repl = joint_in_shardings(
-        mesh, shared, neutral_shared, job_shared)
+    stacked, step_member, step_local, statics, layout, shared = \
+        _entry_wave(e)
+    fn, kin_shardings, repl = entry(mesh, *layout)
     arrays = (stacked, step_member, step_local)
-    shardings = (kin_shardings, repl, repl)
     # all-host signature (jit uploads per in_shardings)
-    out = fn(*arrays, t_pad, feats)
-    jax.block_until_ready(out)
+    jax.block_until_ready(fn(*arrays, *statics))
     # all-committed signature (the profiled path)
-    placed = jax.device_put(arrays, shardings)
-    out = fn(*placed, t_pad, feats)
-    jax.block_until_ready(out)
+    placed = jax.device_put(arrays, (kin_shardings, repl, repl))
+    jax.block_until_ready(fn(*placed, *statics))
     # mixed signature: shared leaves resident (mesh-placed), rest host
     # — only meaningful when the layout shares something (all-stacked
     # waves have no resident leaves, and the mixed call would just
     # repeat the all-host trace)
     subs = {
-        f: jax.device_put(getattr(stacked, f),
-                          getattr(kin_shardings, f))
-        for f in KernelIn._fields
-        if wave_field_is_shared(f, shared, neutral_shared, job_shared)
+        f: jax.device_put(getattr(stacked, f), getattr(kin_shardings, f))
+        for f, sh in zip(KernelIn._fields, shared) if sh
     }
     if subs:
-        out = fn(stacked._replace(**subs), step_member, step_local,
-                 t_pad, feats)
-        jax.block_until_ready(out)
-    return True
-
-
-def _entry_wave(e: Dict):
-    """Build one manifest entry's dummy wave exactly as launch_wave
-    stacks it (shared predicate included) — the common prelude of the
-    fused warm passes."""
-    from nomad_tpu.ops.kernel import KernelIn
-    from nomad_tpu.parallel.coalesce import wave_field_is_shared
-
-    b_pad = int(e["wave"])
-    t_pad = int(e["steps"])
-    n = int(e["nodes"])
-    shared = bool(e.get("shared", True))
-    neutral_shared = bool(e.get("neutral_shared", True))
-    job_shared = bool(e.get("job_shared", True))
-    feats = _features_from_dict(e["features"])
-    k_max = max(t_pad // max(b_pad, 1), 1)
-    kin = _dummy_kin(n, k_max)
-
-    def stack_field(f, x):
-        if wave_field_is_shared(f, shared, neutral_shared, job_shared):
-            return np.asarray(x)
-        return np.stack([np.asarray(x)] * b_pad)
-
-    stacked = KernelIn(*[
-        stack_field(f, getattr(kin, f)) for f in KernelIn._fields
-    ])
-    step_member = np.full(t_pad, -1, np.int32)
-    step_local = np.zeros(t_pad, np.int32)
-    pos = 0
-    for i in range(b_pad):
-        step_member[pos:pos + k_max] = i
-        step_local[pos:pos + k_max] = np.arange(k_max)
-        pos += k_max
-    return (stacked, step_member, step_local, t_pad, feats,
-            (shared, neutral_shared, job_shared))
-
-
-def _warm_fused(e: Dict) -> bool:
-    """Compile the single-device FUSED program for a joint manifest
-    entry — the same three commitment signatures _warm_joint covers
-    (host / committed / resident-mixed), against the fused jit."""
-    import jax.numpy as jnp
-
-    from nomad_tpu.ops.kernel import KernelIn, fused_wave_supported
-    from nomad_tpu.ops.pallas_kernel import fused_wave_place_jit
-    from nomad_tpu.parallel.coalesce import wave_field_is_shared
-
-    feats = _features_from_dict(e["features"])
-    if not fused_wave_supported(feats):
-        return False
-    stacked, step_member, step_local, t_pad, feats, layout = \
-        _entry_wave(e)
-    mixed = [wave_field_is_shared(f, *layout)
-             for f in KernelIn._fields]
-    _call_both_placements(
-        fused_wave_place_jit,
-        (stacked, jnp.asarray(step_member), jnp.asarray(step_local)),
-        (t_pad, feats), mixed=mixed)
-    return True
-
-
-def _warm_fused_sharded(e: Dict, mesh) -> bool:
-    """Compile the FUSED sharded program for a joint manifest entry —
-    the same three signatures as _warm_joint_sharded, against the
-    shard_map entry. Skips entries the mesh cannot serve fused: a
-    node axis it does not divide, or shards narrower than the local
-    TOPK merge (the live launcher counts those as fused fallbacks)."""
-    import jax
-
-    from nomad_tpu.ops.kernel import (
-        TOPK,
-        KernelIn,
-        fused_wave_supported,
-    )
-    from nomad_tpu.parallel.coalesce import wave_field_is_shared
-    from nomad_tpu.parallel.sharded import fused_sharded_entry
-
-    feats = _features_from_dict(e["features"])
-    if not fused_wave_supported(feats):
-        return False
-    n = int(e["nodes"])
-    if (mesh is None or mesh.size < 2 or n % mesh.size != 0
-            or n // mesh.size < TOPK):
-        return False
-    stacked, step_member, step_local, t_pad, feats, layout = \
-        _entry_wave(e)
-    fn, kin_shardings, repl = fused_sharded_entry(mesh, *layout)
-    arrays = (stacked, step_member, step_local)
-    shardings = (kin_shardings, repl, repl)
-    out = fn(*arrays, t_pad, feats)
-    jax.block_until_ready(out)
-    placed = jax.device_put(arrays, shardings)
-    out = fn(*placed, t_pad, feats)
-    jax.block_until_ready(out)
-    subs = {
-        f: jax.device_put(getattr(stacked, f),
-                          getattr(kin_shardings, f))
-        for f in KernelIn._fields
-        if wave_field_is_shared(f, *layout)
-    }
-    if subs:
-        out = fn(stacked._replace(**subs), step_member, step_local,
-                 t_pad, feats)
-        jax.block_until_ready(out)
+        jax.block_until_ready(
+            fn(stacked._replace(**subs), step_member, step_local,
+               *statics))
     return True
 
 
@@ -602,10 +454,10 @@ def warmup_entries(entries: List[Dict], mesh=None,
     and counted — the same program would fail the same way under a
     live wave — but the pass goes on to the entries that remain.
 
-    ``mesh``: ALSO warm the sharded joint signatures for this mesh
-    (the default dispatch on a >=2-device server). ``mesh_only`` skips
-    the single-device programs."""
-    from nomad_tpu.parallel.coalesce import fused_wave_routes
+    ``mesh``: ALSO warm the mesh program of each wave entry for this
+    mesh (the default dispatch on a >=2-device server). ``mesh_only``
+    skips the single-device programs."""
+    from nomad_tpu.parallel.coalesce import wave_program
     from nomad_tpu.tensors.device_state import default_device_state
 
     compiled = failed = 0
@@ -614,24 +466,28 @@ def warmup_entries(entries: List[Dict], mesh=None,
         try:
             did = False
             if e.get("kernel") == "joint":
-                # warm the one program the launcher will route this
-                # entry's envelope to: the FUSED program where the
-                # launcher has a fused route and the envelope supports
-                # it, the composite otherwise — warming both would
-                # double compile time on a program that never
-                # dispatches
+                # warm the program the launcher routes this entry's
+                # wave to on each dispatch kind the server has, and no
+                # other: a second one would double the compile time
+                # on a program that never dispatches
                 if not mesh_only:
-                    did = fused_wave_routes(False) and _warm_fused(e)
-                    if not did:
-                        did = _warm_joint(e)
-                if mesh is not None:
-                    d2 = fused_wave_routes(True) \
-                        and _warm_fused_sharded(e, mesh)
-                    if not d2:
-                        # a mesh too narrow for the fused local
-                        # top-k merge launches composite-sharded
-                        d2 = _warm_joint_sharded(e, mesh)
-                    did = d2 or did
+                    did = _warm_joint(e)
+                program = wave_program(
+                    int(mesh.size) if mesh is not None else 0,
+                    int(e["nodes"]), _features_from_dict(e["features"]))
+                if program != "joint":
+                    # else: no mesh, or one the node axis does not
+                    # split over, whose waves run on one device
+                    from nomad_tpu.parallel.sharded import (
+                        fused_sharded_entry,
+                        joint_sharded_entry,
+                    )
+
+                    did = _warm_sharded(
+                        e, mesh,
+                        fused_sharded_entry
+                        if program == "fused_wave_sharded"
+                        else joint_sharded_entry) or did
             elif e.get("kernel") in ("single_topk", "single_full"):
                 if not mesh_only:
                     did = _warm_single(e)

@@ -37,10 +37,10 @@ def _jit_donating(fn, donate_argnums):
     the allocator happens to hand back an aligned block — the device
     buffer then ALIASES memory the caller still owns. Donating such a
     buffer lets the runtime write the loop's carry in place into the
-    caller's numpy array (observed through the pallas interpret path:
-    the 1-in-5 ``test_pallas_kernel`` top-k parity flake — the first
-    loop call silently rewrote the test's ``used`` planes before the
-    second backend ran). Aliasing is undetectable from the array, so
+    caller's numpy array (observed as a 1-in-5 parity flake: the first
+    loop call silently rewrote a test's ``used`` planes before the
+    second loop ran; tests/test_parallel.py keeps the case). Aliasing
+    is undetectable from the array, so
     every donated arg is copied into a buffer this wrapper owns; the
     copy is O(plane) once per loop call, noise against the T-batch scan
     it feeds, and donation still aliases the carry inside the loop.
@@ -119,8 +119,6 @@ def make_schedule_apply_step(k_steps: int, features: KernelFeatures = FULL_FEATU
 def make_schedule_apply_loop(k_steps: int,
                              features: KernelFeatures = FULL_FEATURES,
                              topk: bool = False,
-                             backend: str = "xla",
-                             interpret: bool = False,
                              reset_every: int = 0):
     """Multi-batch fused loop: T batches of B evals in ONE device call.
 
@@ -130,11 +128,8 @@ def make_schedule_apply_loop(k_steps: int,
     overhead would otherwise dominate and measure the launch path
     instead of the scheduler.
 
-    ``backend``: "xla" uses the vmapped XLA kernels (full-width, or
-    candidate-set when ``topk``); "pallas_topk" uses the fused pallas
-    candidate scan (ops/pallas_kernel.pallas_topk_place_batch) — the
-    full-width pass and approx_max_k stay XLA, the K-step deduction
-    scan runs as one pallas program instead of ~30 XLA ops per step.
+    Runs the vmapped kernels: full-width, or candidate-set when
+    ``topk``.
 
     ``reset_every``: restore the INITIAL utilization planes every that
     many batches (0 = never) — the native baseline's periodic reset
@@ -191,49 +186,6 @@ def make_schedule_apply_loop(k_steps: int,
     # device backends warn "Some donated buffers were not usable"
     # (promoted to an error in tests) — donate nothing then.
     donate = () if reset_every else (1, 2)
-
-    if backend == "pallas_topk":
-        from nomad_tpu.ops.pallas_kernel import pallas_topk_place_batch
-
-        def loop(shared: KernelIn, used_cpu, used_mem,
-                 ask_cpu, ask_mem, n_steps):
-            def one_batch(carry, asks):
-                uc, um = carry
-                a_cpu, a_mem = asks
-                chosen, scores, found, valid = pallas_topk_place_batch(
-                    shared.cap_cpu, shared.cap_mem, shared.cap_disk,
-                    uc, um, shared.used_disk,
-                    shared.base_mask, shared.job_tg_count,
-                    shared.penalty, shared.aff_score,
-                    a_cpu, a_mem, shared.ask_disk,
-                    n_steps, shared.desired_count,
-                    shared.algorithm_spread,
-                    k_steps=k_steps, interpret=interpret,
-                )
-                def run_full(ac, am, ns):
-                    kin = shared._replace(
-                        used_cpu=uc, used_mem=um,
-                        ask_cpu=ac, ask_mem=am, n_steps=ns,
-                    )
-                    out = place_taskgroup(kin, k_steps, features)
-                    return (out.chosen, out.scores, out.found)
-
-                chosen, scores, found = _bound_fallback(
-                    valid, (chosen, scores, found),
-                    lambda: jax.vmap(run_full)(a_cpu, a_mem, n_steps))
-                uc2, um2 = commit_placements(
-                    uc, um, chosen, found, a_cpu, a_mem)
-                stats = (
-                    jnp.sum(jnp.where(found, scores, 0.0)),
-                    jnp.sum(found),
-                    jnp.sum(~valid),
-                )
-                return (uc2, um2), stats
-
-            return scan_loop(one_batch, used_cpu, used_mem,
-                             ask_cpu, ask_mem)
-
-        return _jit_donating(loop, donate)
 
     def loop(shared: KernelIn, used_cpu, used_mem, ask_cpu, ask_mem, n_steps):
         def one_batch(carry, asks):
@@ -505,8 +457,8 @@ def make_preemption_apply_loop(k_steps: int, reset_every: int = 0):
 def commit_placements(used_cpu, used_mem, chosen, found, ask_cpu, ask_mem):
     """The plan applier's state update as on-device algebra
     (nomad/plan_apply.go:209): scatter every accepted placement's ask
-    into the cluster utilization planes. Shared by the XLA and pallas
-    step builders. ``chosen`` i32[B,K] node rows, ``found`` bool[B,K]."""
+    into the cluster utilization planes. Shared by the step and loop
+    builders. ``chosen`` i32[B,K] node rows, ``found`` bool[B,K]."""
     rows = chosen.reshape(-1)                           # i32[B*K]
     ok = found.reshape(-1)
     w_cpu = (jnp.broadcast_to(ask_cpu[:, None], chosen.shape)

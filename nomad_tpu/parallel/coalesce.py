@@ -38,7 +38,6 @@ from nomad_tpu.ops.kernel import (
     LaunchOrigin,
     canonical_features,
     features_key,
-    fused_wave_launch,
     fused_wave_supported,
     launch_attrs,
     pad_steps,
@@ -127,58 +126,20 @@ class _ShardedWaveStats:
 #: short-lived to carry their own history, like wave_stats)
 sharded_wave_stats = _ShardedWaveStats()
 
-#: Fused-wave dispatch knob (ISSUE 19). Default ON: waves whose
-#: feature union fits the fused envelope
-#: (ops/kernel.fused_wave_supported) run the one-dispatch fused
-#: program where one exists for the dispatch (``fused_wave_routes``);
-#: the rest take the composite path, counted as fallbacks below.
-_FUSED_WAVE = True
-
-
-def configure_fused_wave(on: bool) -> None:
-    """Enable/disable the fused wave mega-kernel process-wide (the
-    bench's composite arm and the A/B cell flip this)."""
-    global _FUSED_WAVE
-    _FUSED_WAVE = bool(on)
-
-
-def fused_wave_enabled() -> bool:
-    return _FUSED_WAVE
-
-
-def fused_wave_routes(sharded: bool) -> bool:
-    """Whether waves of this dispatch kind route fused-first: the knob,
-    and a fused program that EXISTS for the dispatch. The sharded
-    program is XLA under ``shard_map`` and runs on any backend. The
-    single-device one is a Pallas program whose body does not lower
-    through Mosaic (ops/pallas_kernel.py has the verdict), so it exists
-    only where Pallas interprets it: on TPU every single-device wave
-    takes the composite — decided here, from the platform, never by
-    trying the fused program and catching what it raises. The launcher
-    and the AOT warmup both ask this one function."""
-    if not _FUSED_WAVE:
-        return False
-    if sharded:
-        return True
-    from nomad_tpu.ops.pallas_kernel import pallas_interpret
-
-    return pallas_interpret()
-
-
 class _FusedWaveStats:
-    """Fused-dispatch accounting (exported as the
+    """Fused-dispatch accounting of MESH waves (exported as the
     ``nomad_tpu_wave_fused_*`` Prometheus series; reset with
     telemetry.reset()).
 
-    ``launches`` counts waves that ran the fused mega-kernel;
-    ``fallbacks`` counts waves that had a fused route
-    (``fused_wave_routes``) but ran the composite anyway — an
+    ``launches`` counts sharded waves that ran ``fused_wave_sharded``;
+    ``fallbacks`` counts sharded waves that ran ``joint_sharded`` — an
     unsupported feature union (spreads/devices/cores/network) or a
     node shard too narrow for the local top-k merge. Both are routing
-    decisions made from the wave itself BEFORE dispatch: a program the
-    router chose that then raises is an error, never a fallback.
-    Steady lean traffic fits the envelope, so the steady-burst gate
-    holds fallbacks at ZERO."""
+    decisions made from the wave itself BEFORE dispatch
+    (``wave_program``): a program the router chose that then raises
+    is an error, never a fallback. Steady lean traffic fits the
+    envelope, so the mesh steady-burst gate holds fallbacks at ZERO.
+    A one-device wave has one program and counts under neither."""
 
     def __init__(self) -> None:
         self._lock = witness_lock("FusedWaveStats._lock")
@@ -428,6 +389,25 @@ def union_features(features: List[KernelFeatures]) -> KernelFeatures:
     ))
 
 
+def wave_program(mesh_size: int, n_nodes: int,
+                 feats: KernelFeatures) -> str:
+    """The one device program a wave runs, from what the launcher can
+    observe of it: how many devices its mesh has (0 or 1: none), its
+    padded node axis and its canonical feature union. The launcher,
+    the AOT warmup (ops/warmup.py) and chip_smoke.py all ask this
+    function; nothing else holds the rule.
+
+    One device, or a node axis the mesh does not divide: ``joint``.
+    A mesh: ``fused_wave_sharded`` for a feature union inside its
+    envelope (ops/kernel.fused_wave_supported) on shards wide enough
+    for the local top-k merge, ``joint_sharded`` otherwise."""
+    if mesh_size < 2 or n_nodes % mesh_size:
+        return "joint"
+    if fused_wave_supported(feats) and n_nodes // mesh_size >= TOPK:
+        return "fused_wave_sharded"
+    return "joint_sharded"
+
+
 def _pad_kin_steps(kin: KernelIn, k_max: int) -> KernelIn:
     """Pad the per-step planes to the wave's step count (neutral rows)."""
     from nomad_tpu.ops.kernel import neutral_step_planes
@@ -675,13 +655,15 @@ def _launch_wave(kins: List[KernelIn], k_steps: List[int],
             # inert filler rows: first member with zero active steps
             filler = padded[0]._replace(n_steps=np.asarray(0, np.int32))
             padded = padded + [filler] * (b_pad - len(padded))
-        # sharded dispatch needs the node axis to split evenly over the
-        # mesh; pad_bucket's power-of-two floor (64) covers every
-        # power-of-two slice, so a fallback here means an exotic device
-        # count — counted, and gated to zero on the steady burst
+        # a mesh the node axis does not split evenly over (pad_bucket's
+        # power-of-two floor, 64, covers every power-of-two slice, so
+        # an exotic device count) dispatches on one device: counted,
+        # and gated to zero on the steady burst
         n_nodes = int(np.asarray(padded[0].cap_cpu).shape[-1])
         mesh_size = int(mesh.size) if mesh is not None else 0
-        wave_sharded = mesh_size >= 2 and n_nodes % mesh_size == 0
+        program = wave_program(mesh_size, n_nodes, feats)
+        wave_sharded = program != "joint"
+        fused = program == "fused_wave_sharded"
         # stack on HOST (numpy): the jit call below uploads each stacked
         # leaf once; stacking device arrays would dispatch per leaf per
         # member. The big node planes (cluster capacity + the wave
@@ -759,17 +741,6 @@ def _launch_wave(kins: List[KernelIn], k_steps: List[int],
     # this key must NOT recompile (the profiler counts violations)
     wave_key = (b_pad, t_pad, n_nodes, shareable, neutral_shareable,
                 job_shareable, feats)
-    # fused dispatch (ISSUE 19): one mega-kernel program instead of
-    # program + eager multi-buffer fetch. Sharded fusion additionally
-    # needs each node shard wide enough for the local TOPK merge.
-    fused_route = fused_wave_routes(wave_sharded)
-    fused_ok = (fused_route and fused_wave_supported(feats)
-                and (not wave_sharded
-                     or n_nodes // mesh_size >= TOPK))
-    if wave_sharded:
-        program = "fused_wave_sharded" if fused_ok else "joint_sharded"
-    else:
-        program = "fused_wave" if fused_ok else "joint"
     record.set(program=program, slots=b_pad, padded_steps=t_pad,
                features=features_key(feats))
     t_launch = time.perf_counter()
@@ -794,7 +765,7 @@ def _launch_wave(kins: List[KernelIn], k_steps: List[int],
             # (the profiler's explicit upload would otherwise commit
             # them to one device and the call would pay a reshard);
             # step planes ship replicated, raw numpy on purpose
-            entry = fused_sharded_entry if fused_ok else joint_sharded_entry
+            entry = fused_sharded_entry if fused else joint_sharded_entry
             fn, kin_shardings, repl = entry(
                 mesh, shareable, neutral_shareable, job_shareable)
             out = profiler.call(
@@ -807,25 +778,20 @@ def _launch_wave(kins: List[KernelIn], k_steps: List[int],
         else:
             if mesh is not None:
                 sharded_wave_stats.note_fallback(mesh_size)
-            if fused_ok:
-                out = fused_wave_launch(
-                    stacked, step_member, step_local, t_pad,
-                    feats, wave_key)
-            else:
-                out = profiler.call(
-                    "joint", place_taskgroups_joint_jit,
-                    (stacked, jnp.asarray(step_member),
-                     jnp.asarray(step_local)),
-                    (t_pad, feats),
-                    wave_key, jit_fn=place_taskgroups_joint_jit,
-                )
-        if fused_ok:
+            out = profiler.call(
+                program, place_taskgroups_joint_jit,
+                (stacked, jnp.asarray(step_member),
+                 jnp.asarray(step_local)),
+                (t_pad, feats),
+                wave_key, jit_fn=place_taskgroups_joint_jit,
+            )
+        if fused:
             host, wave_topk = _fused_fetch(out, t_pad, b_pad)
             fused_wave_stats.note_launch()
         else:
-            if fused_route:
-                # had a fused route, ran the composite (unsupported
-                # feature union or narrow shard)
+            if wave_sharded:
+                # a mesh wave outside the fused envelope, or on
+                # shards too narrow for it
                 fused_wave_stats.note_fallback()
             with tracer.span("kernel.d2h") as sp:
                 # fetch ONLY the planes members consume immediately:

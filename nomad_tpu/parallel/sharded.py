@@ -236,14 +236,6 @@ def joint_sharded_entry(mesh: Mesh, shared: bool = False,
     return entry
 
 
-def make_joint_sharded(mesh: Mesh, shared: bool = False,
-                       neutral_shared: bool = False,
-                       job_shared: bool = False):
-    """The compiled wrapper alone (see ``joint_sharded_entry``)."""
-    return joint_sharded_entry(mesh, shared, neutral_shared,
-                               job_shared)[0]
-
-
 def wave_mesh(n_devices: int = 0, devices=None) -> Mesh:
     """A 1D nodes-axis mesh for live waves (the coalescer's multi-chip
     routing; evals parallelism comes from wave batching, so the whole
@@ -266,9 +258,10 @@ def unstack_kernel_outs(out: KernelOut) -> List[KernelOut]:
 
 
 # ---------------------------------------------------------------------------
-# Fused sharded waves (ISSUE 19): the fused mega-kernel composed with
-# the PR 14 mesh. GSPMD cannot partition through the fused program's
-# pallas boundary, so the node-axis split is explicit ``shard_map``:
+# Fused sharded waves (ISSUE 19): the wave scan with the node-axis
+# split made explicit by ``shard_map`` in place of GSPMD's partitioning
+# of the composite, and the launcher's eager read-back packed into one
+# buffer (ops/kernel.pack_fused_wave):
 # each shard runs the SAME per-step math as the composite
 # (ops/kernel._feasible/_score on its local node rows — shared code,
 # not a reimplementation) and the per-step argmax / preferred-pin /

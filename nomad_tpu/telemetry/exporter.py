@@ -94,9 +94,9 @@ def prometheus_text(registry=None, event_broker=None) -> str:
             f'{{{_lbl(direction=direction)}}} {n}')
     # per-wave device-dispatch counts (ISSUE 19): program executions
     # plus the composite's eager result fetch ("wave_fetch") and the
-    # deferred top-k drain ("topk_drain") — a fused steady wave is
-    # exactly ONE dispatch, which TRACE_DECOMP's dispatches_per_wave
-    # key gates
+    # deferred top-k drain ("topk_drain") — a fused sharded wave is
+    # exactly ONE dispatch, a ``joint`` wave two, which TRACE_DECOMP's
+    # dispatches_per_wave key gates
     if prof.get("Dispatches"):
         lines.append("# TYPE nomad_tpu_kernel_dispatches_total counter")
         for program, n in sorted(prof["Dispatches"].items()):
@@ -164,10 +164,10 @@ def prometheus_text(registry=None, event_broker=None) -> str:
             "# TYPE nomad_tpu_wave_sharded_mesh_devices gauge")
         lines.append(
             f"nomad_tpu_wave_sharded_mesh_devices {s['mesh_devices']}")
-        # fused dispatch (ISSUE 19): waves that ran the one-dispatch
-        # mega-kernel vs fusion-wanted composite fallbacks (an
-        # unsupported feature union, a narrow shard, or a fused
-        # error) — fallbacks must sit at 0 on steady traffic
+        # fused dispatch (ISSUE 19), the mesh's: sharded waves that
+        # ran fused_wave_sharded vs those that ran joint_sharded (an
+        # unsupported feature union or a narrow shard) — fallbacks
+        # must sit at 0 on steady lean traffic
         from nomad_tpu.parallel.coalesce import fused_wave_stats
 
         fu = fused_wave_stats.snapshot()

@@ -1,7 +1,7 @@
 """JAX-level instrumentation of placement kernel launches.
 
 Every device launch (``joint``, ``joint_sharded`` / ``fused_wave_sharded``,
-``fused_wave``, ``single_full``, ``single_topk``) goes through
+``single_full``, ``single_topk``) goes through
 ``KernelProfiler.call``. With the *tracer* on, profiler on or off, the
 call records under the caller's ``wave.launch``:
 
@@ -78,9 +78,9 @@ class KernelProfiler:
         #: ``call`` counts one under its kernel name; the wave
         #: launcher adds "wave_fetch" for the composite's eager
         #: per-field result fetch and "topk_drain" for the deferred
-        #: top-k materialization. The fused mega-kernel's single
+        #: top-k materialization. The mesh's fused program's single
         #: packed readback rides its own dispatch's synchronization,
-        #: so a fused steady wave counts exactly ONE.
+        #: so a fused sharded wave counts exactly ONE.
         self.dispatches: Dict[str, int] = {}
         #: cross-check: observed jit cache growth (when introspectable)
         self.cache_growth = 0

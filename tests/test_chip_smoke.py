@@ -41,7 +41,7 @@ def test_rehearsal_serves_both_bursts(tmp_path):
     assert proc.returncode == 0, proc.stderr[-4000:]
     out = proc.stdout
     assert _line(out, "platform") == "cpu"
-    assert "served by fused_wave;" in _line(out, "lean_burst")
+    assert "served by joint;" in _line(out, "lean_burst")
     assert "served by joint;" in _line(out, "mixed_burst")
     assert json.loads(out.splitlines()[-1]) == {
         "ok": True, "rehearsal": True,
@@ -60,10 +60,10 @@ def test_raising_wave_program_fails_the_run(tmp_path):
     cache = str(tmp_path / "cache")
     code = (
         "import runpy, sys\n"
-        "import nomad_tpu.ops.pallas_kernel as pk\n"
+        "from nomad_tpu.parallel import coalesce\n"
         "def boom(*a, **k):\n"
-        "    raise RuntimeError('injected fused wave failure')\n"
-        "pk.fused_wave_place_jit = boom\n"
+        "    raise RuntimeError('injected wave program failure')\n"
+        "coalesce.place_taskgroups_joint_jit = boom\n"
         f"sys.argv = {[SMOKE, *TINY, '--out', str(tmp_path)]!r}\n"
         f"runpy.run_path({SMOKE!r}, run_name='__main__')\n")
     proc = subprocess.run(
@@ -71,7 +71,7 @@ def test_raising_wave_program_fails_the_run(tmp_path):
         env=_env(JAX_COMPILATION_CACHE_DIR=cache),
         capture_output=True, text=True, timeout=300)
     assert proc.returncode != 0
-    assert "injected fused wave failure" in proc.stderr
+    assert "injected wave program failure" in proc.stderr
     assert '"ok"' not in proc.stdout
     # a cache directory named from outside is the one in use: the
     # program set none of its own

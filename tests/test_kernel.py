@@ -667,10 +667,10 @@ SPREAD_CASES = ["even", "desired_implicit_remainder", "bucketless_nodes",
                 "seeded_counts", "inactive_between_active", "places_nothing"]
 
 
-def _spread_by_bucket_table(stanzas, active, counts):
-    """float64 spread plane over the real nodes, every boost computed over
-    the BUCKET table and then looked up by each node's bucket."""
-    total = np.zeros(SPREAD_N_NODES)
+def _spread_by_bucket_table(stanzas, active, counts, n=SPREAD_N_NODES):
+    """float64 spread plane over the ``n`` real nodes, every boost computed
+    over the BUCKET table and then looked up by each node's bucket."""
+    total = np.zeros(n)
     for s, sp in enumerate(stanzas):
         if not active[s]:
             continue
@@ -689,7 +689,7 @@ def _spread_by_bucket_table(stanzas, active, counts):
             safe = np.where(des > 0, des, 1.0)
             table = np.where(
                 des > 0, ((des - (cnt + 1)) / safe) * sp.weight_frac, -1.0)
-        bucket = sp.bucket_id[:SPREAD_N_NODES]
+        bucket = sp.bucket_id[:n]
         total += np.where(bucket >= 0, table[np.clip(bucket, 0, None)], -1.0)
     return total
 
@@ -889,23 +889,54 @@ def _pick_reference(cluster, members, order, perms):
     padded node axis. ``members``: dicts of ``ev``, ``active``,
     ``n_steps``, ``penalty`` (i32[K, P] node ids, -1 none) and
     ``preferred`` (i32[K], -1 none). One row a step: chosen, score,
-    found, top-k nodes, top-k scores."""
-    n, n_pad = SPREAD_N_NODES, cluster.n_pad
+    found, top-k nodes, top-k scores.
+
+    What a member may also state, each over the nodes and its own: where
+    it starts (``used_cpu``, ``used_mem``: what its snapshot holds, under
+    the wave's shared additions), its job's allocations there
+    (``job_tg_count``, ``job_any_count``) with ``distinct_tg`` and
+    ``distinct_job``, and its ports: ``dyn_ports`` asked of a node's free
+    ones (the wave's additions are shared), ``reserved_ports`` with the
+    nodes where they are taken (``port_conflict``; a placement takes them
+    for this member's later steps)."""
+    n, n_pad = cluster.n_real, cluster.n_pad
     cap_c = cluster.cap_cpu[:n].astype(np.float64)
     cap_m = cluster.cap_mem[:n].astype(np.float64)
-    used_c, used_m = np.zeros(n), np.zeros(n)
-    job_cnt = [np.zeros(n) for _ in members]
+    cap_d = cluster.cap_disk[:n].astype(np.float64)
+    free_dyn = cluster.free_dyn[:n].astype(np.int64)
+
+    def own(key, dtype=np.float64):
+        return [np.array(mb.get(key, np.zeros(n)))[:n].astype(dtype)
+                for mb in members]
+
+    base_c, base_m = own("used_cpu"), own("used_mem")
+    job_cnt, any_cnt = own("job_tg_count"), own("job_any_count")
+    conflict = own("port_conflict", bool)
+    # the wave's additions, seen by every member
+    add_c, add_m, add_d = np.zeros(n), np.zeros(n), np.zeros(n)
+    add_dyn = np.zeros(n, np.int64)
     counts = [[sp.counts.astype(np.float64).copy() for sp in mb["ev"].spreads]
               for mb in members]
     rows = []
     for m, local in order:
         mb = members[m]
         ev, ask = mb["ev"], mb["ev"].ask
+        dyn, reserved = mb.get("dyn_ports", 0), mb.get("reserved_ports", False)
         masked = np.full(n_pad, PICK_NEG)
         feasible = np.zeros(n_pad, bool)
         if local < mb["n_steps"]:
-            feasible[:n] = ((cap_c - used_c >= ask.cpu)
-                            & (cap_m - used_m >= ask.mem))
+            used_c, used_m = base_c[m] + add_c, base_m[m] + add_m
+            ok = ((cap_c - used_c >= ask.cpu) & (cap_m - used_m >= ask.mem)
+                  & (cap_d - add_d >= ask.disk))
+            if dyn > 0:
+                ok &= free_dyn - add_dyn >= dyn
+            if reserved:
+                ok &= ~conflict[m]
+            if mb.get("distinct_job", False):
+                ok &= any_cnt[m] == 0
+            if mb.get("distinct_tg", False):
+                ok &= job_cnt[m] == 0
+            feasible[:n] = ok
             total = (10.0 ** (1 - (used_c + ask.cpu) / cap_c)
                      + 10.0 ** (1 - (used_m + ask.mem) / cap_m))
             penalized = np.isin(np.arange(n), mb["penalty"][local])
@@ -915,7 +946,7 @@ def _pick_reference(cluster, members, order, perms):
             on = [np.ones(n, bool), job_cnt[m] > 0, penalized]
             if ev.spreads:
                 spread = _spread_by_bucket_table(
-                    ev.spreads, mb["active"], counts[m])
+                    ev.spreads, mb["active"], counts[m], n)
                 planes.append(spread)
                 on.append(spread != 0.0)
             score = (sum(np.where(o, p, 0.0) for p, o in zip(planes, on))
@@ -931,9 +962,13 @@ def _pick_reference(cluster, members, order, perms):
         rows.append((idx if found else -1, masked[idx] if found else 0.0,
                      found, top, masked[top]))
         if found:
-            used_c[idx] += ask.cpu
-            used_m[idx] += ask.mem
+            add_c[idx] += ask.cpu
+            add_m[idx] += ask.mem
+            add_d[idx] += ask.disk
+            add_dyn[idx] += dyn
             job_cnt[m][idx] += 1
+            any_cnt[m][idx] += 1
+            conflict[m][idx] |= bool(reserved)
             for s, sp in enumerate(ev.spreads):
                 if mb["active"][s] and sp.bucket_id[idx] >= 0:
                     counts[m][s][sp.bucket_id[idx]] += 1

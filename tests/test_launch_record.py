@@ -132,14 +132,9 @@ class TestSpanAttributes:
 
 class TestLaunchRecord:
     def test_two_member_wave_names_its_members_in_order(self, tracer_only):
-        coalesce.configure_fused_wave(False)    # the composite, as on TPU
-        coalesce.fused_wave_routes(False)       # its lazy import, up front
-        try:
-            k_pad = _two_member_wave(300, [
-                LaunchOrigin("eval-a", 41, 300, False),
-                LaunchOrigin("eval-b", 43, 300, True)])
-        finally:
-            coalesce.configure_fused_wave(True)
+        k_pad = _two_member_wave(300, [
+            LaunchOrigin("eval-a", 41, 300, False),
+            LaunchOrigin("eval-b", 43, 300, True)])
         launches = tracer.spans(name="wave.launch")
         assert len(launches) == 1
         rec = launches[0]

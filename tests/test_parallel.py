@@ -116,7 +116,7 @@ class TestDonatedLoopOwnership:
     numpy memory. ``jnp.asarray(numpy)`` is zero-copy on the CPU
     backend when the allocator cooperates; donating such a buffer let
     the runtime write the scan carry in place into the caller's array
-    — the 1-in-5 test_pallas_kernel top-k parity flake. The
+    — a 1-in-5 top-k parity flake between two loops. The
     ``_jit_donating`` wrapper copies donated args into buffers it
     owns; this test re-runs a loop from the same numpy planes and
     must see identical results and untouched inputs every time."""

@@ -1,7 +1,7 @@
 """Live waves over the device mesh (VERDICT r2 missing #2).
 
 The coalescer's joint wave kernel runs with its node axis sharded over
-the mesh (parallel/sharded.make_joint_sharded): the SAME program, so
+the mesh (parallel/sharded.joint_sharded_entry): the SAME program, so
 placements must be identical to single-device dispatch — per-step
 argmax/top-k lower to per-shard reductions + cross-shard collectives
 (SURVEY.md §2.10 node-axis-over-ICI mapping). Tests run on the
@@ -112,13 +112,17 @@ class TestShardedWaveParity:
                                        rtol=1e-6, atol=1e-7)
 
 
-    @pytest.mark.parametrize("program", ["fused_sharded", "joint_sharded"])
+    @pytest.mark.parametrize("program", ["fused_wave_sharded",
+                                         "joint_sharded"])
     def test_tied_shuffled_wave_identical_to_single_device(self, wave_mesh,
                                                            program):
         """Identical empty nodes, every member with its own shuffle: a
         tie goes to the node the member's permutation meets first. Both
         mesh programs and the one-chip ``joint`` take the rank plane from
-        the same ``ops/kernel._inv``."""
+        the same ``ops/kernel._inv``. The launcher picks the mesh program
+        from the wave's features (``coalesce.wave_program``): a spread
+        slot in the union, its stanzas all inactive, is outside the
+        fused envelope and runs ``joint_sharded``."""
         from nomad_tpu.ops.kernel import LEAN_FEATURES, build_kernel_in
         from nomad_tpu.parallel.synthetic import (
             synthetic_cluster,
@@ -128,7 +132,9 @@ class TestShardedWaveParity:
         cluster = synthetic_cluster(200, cpu=2000.0, mem=4096.0,
                                     disk=50000.0, seed=5)
         rng = np.random.default_rng(30)
-        feats0 = LEAN_FEATURES._replace(with_topk=True, with_shuffle=True)
+        feats0 = LEAN_FEATURES._replace(
+            with_topk=True, with_shuffle=True,
+            n_spreads=int(program == "joint_sharded"))
         kins, steps, feats = [], [], []
         for _ in range(4):
             ev = synthetic_eval(cluster, desired_count=6)
@@ -136,23 +142,19 @@ class TestShardedWaveParity:
             kins.append(build_kernel_in(cluster, ev, 6, node_perm=perm))
             steps.append(6)
             feats.append(feats0)
+        assert coalesce.wave_program(
+            wave_mesh.size, cluster.n_pad,
+            coalesce.union_features(feats)) == program
 
-        prior = coalesce.fused_wave_enabled()
-        coalesce.configure_fused_wave(False)      # the composite ``joint``
-        coalesce.configure_wave_mesh(None)
-        try:
-            single = coalesce.launch_wave(kins, steps, feats)
-            coalesce.configure_fused_wave(program == "fused_sharded")
-            fused_before = coalesce.fused_wave_stats.snapshot()["launches"]
-            before = coalesce.sharded_wave_launches
-            coalesce.configure_wave_mesh(wave_mesh)
-            sharded = coalesce.launch_wave(kins, steps, feats)
-        finally:
-            coalesce.configure_wave_mesh(None)
-            coalesce.configure_fused_wave(prior)
+        single = coalesce.launch_wave(kins, steps, feats, mesh=None)
+        fused_before = coalesce.fused_wave_stats.snapshot()
+        before = coalesce.sharded_wave_launches
+        sharded = coalesce.launch_wave(kins, steps, feats, mesh=wave_mesh)
         assert coalesce.sharded_wave_launches == before + 1
-        assert (coalesce.fused_wave_stats.snapshot()["launches"]
-                - fused_before) == (program == "fused_sharded")
+        fused_after = coalesce.fused_wave_stats.snapshot()
+        assert (fused_after["launches"] - fused_before["launches"],
+                fused_after["fallbacks"] - fused_before["fallbacks"]) == (
+            (1, 0) if program == "fused_wave_sharded" else (0, 1))
 
         # the wave's first step meets nothing but ties
         first = kins[0].node_perm[

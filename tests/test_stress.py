@@ -317,61 +317,6 @@ class TestMeshCell:
         assert cell["dispatches_per_wave"] == 1.0, cell
 
 
-class TestFusedCell:
-    def test_fused_cell_under_lock_witness(self):
-        """ISSUE 19: the standing fused A/B — the same burst of waves
-        through the fused mega-kernel and through the composite joint
-        program — under the runtime lock witness (the fused path's
-        stats counter + the launcher's inflight bookkeeping get
-        order-checked like every other cell's locks). Gates: exact
-        bit-parity including the drained top-k planes, exactly ONE
-        wave-critical dispatch per fused wave vs two composite, zero
-        fused fallbacks, compile-free timed windows. One rep at
-        reduced scale: the A/B is deterministic; repetition adds
-        compile time, not coverage."""
-        import os
-        import sys
-
-        sys.path.insert(0, os.path.join(os.path.dirname(
-            os.path.dirname(os.path.abspath(__file__))), "bench"))
-        import trace_report
-
-        cell = trace_report.run_fused_burst(
-            n_nodes=5_000, n_allocs=20_000, batch_size=16, waves=6)
-        assert cell["parity_ok"], cell
-        assert cell["dispatches_per_wave"] == 1.0, cell
-        assert cell["composite_dispatches_per_wave"] == 2.0, cell
-        assert cell["launches"] == cell["waves"], cell
-        assert cell["fallbacks"] == 0, cell
-        assert cell["jit_cache_misses"] == 0, cell
-        # the fused packed readback is strictly smaller than the
-        # composite's eager multi-buffer fetch
-        assert cell["d2h_bytes_per_wave"] < \
-            cell["composite_d2h_bytes_per_wave"], cell
-        assert cell["speedup"] > 0.0
-
-    def test_fused_cell_sharded_arm_under_lock_witness(self):
-        """The same A/B over the 8-device mesh: fused_wave_sharded vs
-        joint_sharded, same gates (speedup is a trajectory line on
-        virtual CPU devices, not a gate)."""
-        import os
-        import sys
-
-        sys.path.insert(0, os.path.join(os.path.dirname(
-            os.path.dirname(os.path.abspath(__file__))), "bench"))
-        import trace_report
-
-        cell = trace_report.run_fused_burst(
-            n_nodes=2_000, n_allocs=8_000, batch_size=8, waves=4,
-            use_mesh=True)
-        assert cell["devices"] == 8
-        assert cell["parity_ok"], cell
-        assert cell["dispatches_per_wave"] == 1.0, cell
-        assert cell["launches"] == cell["waves"], cell
-        assert cell["fallbacks"] == 0, cell
-        assert cell["jit_cache_misses"] == 0, cell
-
-
 class TestWorkerCell:
     def test_worker_cell_under_lock_witness(self):
         """ISSUE 17: the multi-process worker cell's A/B burst under

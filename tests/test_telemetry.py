@@ -680,7 +680,7 @@ class TestTraceDecomposition:
             assert proc.returncode == 0, proc.stderr.decode()[-2000:]
             decomp = json.loads(out.read_text())
             ss = decomp["steady_state"]
-            sched_ok = (ss["sched_host_share"] <= 0.65 or sum(
+            sched_ok = (ss["sched_host_share"] <= 0.60 or sum(
                 decomp["stages"].get(s, {}).get("per_eval_ms", 0.0)
                 for s in ("sched-host", "sched-reconcile",
                           "sched-feasibility", "sched-assembly",
@@ -743,23 +743,20 @@ class TestTraceDecomposition:
         # must be advancing by dirty-row scatter, not full re-uploads
         assert decomp["device_state"]["delta_advances"] >= 1, \
             decomp["device_state"]
-        # ISSUE 19 steady gates: every steady wave must run the fused
-        # mega-kernel — zero fused fallbacks, fused launches == wave
-        # launches — and cost exactly ONE wave-critical device
-        # dispatch (the composite's separate eager result fetch is
-        # gone; the deferred top-k drain is excluded by definition).
-        assert ss["fused_wave_fallbacks"] == 0, (
-            ss, decomp.get("wave_fused"))
-        assert ss["fused_wave_launches"] == \
-            decomp["wave"]["launches"] > 0, (ss, decomp["wave"])
-        assert ss["dispatches_per_wave"] == 1.0, (
-            ss, decomp["kernel"].get("Dispatches"))
-        # the per-program dispatch counter exported in the artifact:
-        # fused waves only, no composite program, no eager wave fetch
+        # One-device steady gates (ISSUE 31): every steady wave is the
+        # program a TPU runs, ``joint``, with its eager result fetch:
+        # two wave-critical device interactions a wave (the deferred
+        # top-k drain is excluded by definition), none of them a
+        # compile (jit_cache_misses == 0 above), and the mesh's fused
+        # counters stand still.
         disp = decomp["kernel"].get("Dispatches", {})
-        assert disp.get("fused_wave", 0) > 0, disp
-        assert disp.get("joint", 0) == 0, disp
-        assert disp.get("wave_fetch", 0) == 0, disp
+        assert disp.get("joint", 0) == decomp["wave"]["launches"] > 0, (
+            disp, decomp["wave"])
+        assert disp.get("wave_fetch", 0) == disp["joint"], disp
+        assert "fused_wave" not in disp, disp
+        assert ss["dispatches_per_wave"] == 2.0, (ss, disp)
+        assert ss["fused_sharded_launches"] == 0, decomp.get("wave_fused")
+        assert ss["fused_sharded_fallbacks"] == 0, decomp.get("wave_fused")
         # ISSUE 5 steady gates. sched_host_share sums the
         # eval.schedule residue + the feasibility/assembly/plan-build
         # sub-slices. Post-compiler, the feasibility slice itself is
@@ -773,18 +770,18 @@ class TestTraceDecomposition:
         # is thread CPU, so host contention stretches the wall
         # denominator and can only shrink it — the steal-invariant
         # fallback bound is the per-eval CPU milliseconds of the same
-        # four slices. ISSUE 19 recalibrated the share bound from
-        # 0.45: the fused wave cut the execute+fetch leg to one
-        # dispatch, shrinking the wall denominator while the Python
-        # numerator stayed put — the same healthy scheduler now reads
-        # ~0.55-0.60 of the smaller wall (a genuine host regression on
-        # fused walls would read 0.7+).
+        # four slices. The share bound is derived from ``joint``
+        # bursts (ISSUE 31; CHANGES.md has the runs): a healthy
+        # scheduler reads 0.44 to 0.47 of the burst's wall on a quiet
+        # box, the parent's interpreted fused program read the same
+        # (0.46 to 0.47), and the bound stands a quarter above the
+        # largest reading.
         sched_ms = sum(
             decomp["stages"].get(s, {}).get("per_eval_ms", 0.0)
             for s in ("sched-host", "sched-reconcile",
                       "sched-feasibility", "sched-assembly",
                       "sched-planbuild"))
-        assert ss["sched_host_share"] <= 0.65 or sched_ms <= 3.0, \
+        assert ss["sched_host_share"] <= 0.60 or sched_ms <= 3.0, \
             (ss["sched_host_share"], sched_ms)
         # ISSUE 10: the reconcile slice is spanned on its own (the
         # fused single-pass classifier's trajectory line)
@@ -909,10 +906,10 @@ class TestTraceDecomposition:
         # the resident state advanced sharded between waves
         assert decomp["device_state"]["delta_advances"] >= 1, \
             decomp["device_state"]
-        # ISSUE 19: sharded waves run FUSED too (fused_wave_sharded),
-        # still at one dispatch per wave
-        assert ss["fused_wave_fallbacks"] == 0, ss
-        assert ss["fused_wave_launches"] == \
+        # ISSUE 19: a mesh's lean waves run FUSED
+        # (fused_wave_sharded), at one dispatch per wave
+        assert ss["fused_sharded_fallbacks"] == 0, ss
+        assert ss["fused_sharded_launches"] == \
             decomp["wave"]["launches"], (ss, decomp["wave"])
         assert ss["dispatches_per_wave"] == 1.0, (
             ss, decomp["kernel"].get("Dispatches"))

@@ -69,12 +69,9 @@ def _clear_kernel_caches():
         place_taskgroups_joint_jit,
     )
 
-    from nomad_tpu.ops.pallas_kernel import fused_wave_place_jit
-
     place_taskgroups_joint_jit.clear_cache()
     place_taskgroup_topk_jit.clear_cache()
     place_taskgroup_jit.clear_cache()
-    fused_wave_place_jit.clear_cache()
 
 
 class TestManifest:

@@ -63,13 +63,11 @@ _PROM_NAME = re.compile(r"\bnomad_tpu_[a-z0-9]+(?:_[a-z0-9]+)+\b")
 #: ISSUE 17 (the multi-process scheduler worker cell's A/B speedup,
 #: lease-reissue, and IPC round-trip lines); raft_* in ISSUE 18 (the
 #: raft cell's pipelined-vs-synchronous commit-window attribution and
-#: lease-read split); fused_* in ISSUE 19 (the fused wave mega-kernel
-#: cell's A/B speedup, bit-parity, and dispatch-quotient lines);
-#: readplane_* in ISSUE 20 (the follower-read smoke's three mode-leg
+#: lease-read split); readplane_* in ISSUE 20 (the follower-read smoke's three mode-leg
 #: verdicts — the fleet cell's read lines ride the fleet_* prefix)
 _BENCH_KEY = re.compile(
     r"^(?:trace|contention|fleet|chaos|restart|mesh|timeline|store"
-    r"|worker|raft|fused|readplane)_[a-z0-9_]+$")
+    r"|worker|raft|readplane)_[a-z0-9_]+$")
 #: bench kwargs that are not emission keys (worker_batch_size is the
 #: ServerConfig in-process dequeue window, not a trend line)
 _BENCH_KEY_EXCLUDE = {"trace_id", "timeline_path", "worker_batch_size"}
