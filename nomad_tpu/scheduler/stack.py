@@ -42,7 +42,7 @@ from nomad_tpu.scheduler.scaffold import MetricsSkeleton, scaffold_for
 from nomad_tpu.structs import consts
 from nomad_tpu.telemetry.trace import tracer
 from nomad_tpu.structs.alloc import AllocMetric
-from nomad_tpu.structs.constraints import matches_affinity, resolve_target
+from nomad_tpu.structs.constraints import matches_affinity
 from nomad_tpu.structs.network import NetworkIndex, NetworkResource, Port
 from nomad_tpu.structs.resources import (
     AllocatedCpuResources,
@@ -569,10 +569,11 @@ class XLAGenericStack:
         return base
 
     def _build_eval_tensors(self, tg, exclude: np.ndarray) -> EvalTensors:
-        with tracer.span("sched.assembly"):
-            return self._build_eval_tensors_inner(tg, exclude)
+        with tracer.span("sched.assembly") as span:
+            return self._build_eval_tensors_inner(tg, exclude, span)
 
-    def _build_eval_tensors_inner(self, tg, exclude: np.ndarray) -> EvalTensors:
+    def _build_eval_tensors_inner(self, tg, exclude: np.ndarray,
+                                  span) -> EvalTensors:
         c = self.cluster
         snapshot = self.ctx.state
         job = self.job
@@ -730,7 +731,8 @@ class XLAGenericStack:
                 aff_score[i] = score
                 cache[cls] = score
 
-        spreads = self._build_spreads(tg, job_allocs)
+        spreads, spread_codes = self._build_spreads(tg, job_allocs)
+        span.set(spread_codes=spread_codes)
 
         return EvalTensors(
             base_mask=base,
@@ -856,14 +858,16 @@ class XLAGenericStack:
                     if 20000 <= p.value <= 32000:
                         free_dyn_delta[row] += 1
 
-    def _build_spreads(self, tg, job_allocs) -> List[SpreadTensor]:
+    def _build_spreads(self, tg, job_allocs) -> Tuple[List[SpreadTensor], str]:
         """SpreadIterator state -> SpreadTensor list (spread.go:82-113,
-        computeSpreadInfo :245)."""
+        computeSpreadInfo :245), and how the stanzas' node codes were
+        come by: ``built`` (a stanza paid the walk over the cluster's
+        nodes), ``hit``, or ``none`` (no spread)."""
         c = self.cluster
         job = self.job
         combined = list(tg.spreads) + list(job.spreads)
         if not combined:
-            return []
+            return [], "none"
         sum_weights = sum(abs(s.weight) for s in combined)
         out = []
         plan_allocs = [
@@ -877,35 +881,34 @@ class XLAGenericStack:
             for a in job_allocs
             if not a.terminal_status() and a.task_group == tg.name
         ] + plan_allocs
-        node_of = {nid: i for i, nid in enumerate(c.node_ids)}
+        how = "hit"
         for spread in combined:
-            # value table: desired targets first, then observed node values
+            # value table: desired targets first, then the cluster's own
+            # values in first-seen row order (ClusterTensors.spread_codes:
+            # the walk over the nodes, once per cluster build)
             values: Dict[str, int] = {}
             for t in spread.spread_target:
                 if t.value != "*":
                     values.setdefault(t.value, len(values))
-            bucket_id = np.full(c.n_pad, -1, np.int32)
-            node_vals: List[Optional[str]] = [None] * c.n_real
-            for i in range(c.n_real):
-                node = self.ctx.state.node_by_id(c.node_ids[i])
-                if node is None:
-                    continue
-                val, ok = resolve_target(spread.attribute, node)
-                if not ok:
-                    continue
-                node_vals[i] = val
+            codes, seen, built = c.spread_codes(spread.attribute)
+            if built:
+                how = "built"
+            # code -> bucket; the last entry maps the code -1 to -1
+            lut = np.full(len(seen) + 1, -1, np.int32)
+            for code, val in enumerate(seen):
                 if val not in values:
                     if len(values) >= SPREAD_BUCKETS:
                         continue  # overflow: value scores as missing
                     values[val] = len(values)
-                bucket_id[i] = values[val]
+                lut[code] = values[val]
+            bucket_id = lut[codes]
             counts = np.zeros(SPREAD_BUCKETS, np.float32)
             for a in live_allocs:
-                row = node_of.get(a.node_id)
-                if row is None or node_vals[row] is None:
+                row = c.index.get(a.node_id)
+                if row is None:
                     continue
-                b = values.get(node_vals[row])
-                if b is not None:
+                b = lut[codes[row]]
+                if b >= 0:
                     counts[b] += 1
             desired = np.full(SPREAD_BUCKETS, -1.0, np.float32)
             even = not spread.spread_target
@@ -939,7 +942,7 @@ class XLAGenericStack:
                     even=even,
                 )
             )
-        return out
+        return out, how
 
     def _merge_kernel_metrics(self, out: KernelOut) -> None:
         """Fold the kernel's mask-population counts into the eval

@@ -110,6 +110,14 @@ def reset() -> None:
     except Exception:                           # noqa: BLE001
         pass
     try:
+        # spread-code lookups (hits, builds) follow the same window;
+        # the codes themselves stay on their cluster builds
+        from nomad_tpu.tensors.schema import spread_code_stats
+
+        spread_code_stats.reset()
+    except Exception:                           # noqa: BLE001
+        pass
+    try:
         # feasibility mask-cache counters follow the same window; the
         # cached programs/masks themselves stay resident
         from nomad_tpu.feasibility import default_mask_cache

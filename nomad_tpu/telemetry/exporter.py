@@ -221,6 +221,22 @@ def prometheus_text(registry=None, event_broker=None) -> str:
             f"{d['resident_generations']}")
     except Exception:                           # noqa: BLE001
         pass                # device state (jax) unavailable: skip
+    # spread codes (tensors/schema.ClusterTensors.spread_codes): the
+    # walk over the cluster's nodes for a spread attribute should be
+    # paid once per cluster build, so builds follow node writes and
+    # hits follow evaluations
+    try:
+        from nomad_tpu.tensors.schema import spread_code_stats
+
+        sc = spread_code_stats.snapshot()
+        lines.append(
+            "# TYPE nomad_tpu_spread_codes_lookups_total counter")
+        for kind, key in (("hit", "hits"), ("build", "builds")):
+            lines.append(
+                f'nomad_tpu_spread_codes_lookups_total'
+                f'{{kind="{kind}"}} {sc[key]}')
+    except Exception:                           # noqa: BLE001
+        pass                # tensors (numpy) unavailable: skip
     # feasibility compiler (nomad_tpu/feasibility/): mask-program cache
     # effectiveness — a steady cluster should sit near hit_ratio 1.0,
     # with misses only on node-structure forks and novel job specs

@@ -43,6 +43,36 @@ import threading as _threading  # noqa: E402
 #: planes): identity sharing is load-bearing for wave upload layout
 _GATHER_LOCK = _threading.Lock()
 
+
+class SpreadCodeStats:
+    """Process-wide lookups of ``ClusterTensors.spread_codes``: ``builds``
+    paid the walk over the cluster's nodes, ``hits`` found it done."""
+
+    def __init__(self) -> None:
+        self._lock = _threading.Lock()
+        self.hits = 0
+        self.builds = 0
+
+    def note(self, built: bool) -> None:
+        with self._lock:
+            if built:
+                self.builds += 1
+            else:
+                self.hits += 1
+
+    def snapshot(self) -> Dict[str, int]:
+        with self._lock:
+            return {"hits": self.hits, "builds": self.builds}
+
+    def reset(self) -> None:
+        with self._lock:
+            self.hits = 0
+            self.builds = 0
+
+
+#: process-wide, beside default_incremental_cluster_cache's own counters
+spread_code_stats = SpreadCodeStats()
+
 _MIN_BUCKET = 64
 
 
@@ -88,6 +118,9 @@ class ClusterTensors:
     _pool_arr: Optional[np.ndarray] = None
     _usage_perm: Optional[Tuple[int, np.ndarray, np.ndarray]] = None
     _class_rows: Optional[Dict[str, List[int]]] = None
+    #: spread attribute -> (codes, values), see spread_codes
+    _spread_codes: Dict[str, Tuple[np.ndarray, Tuple[str, ...]]] = field(
+        default_factory=dict)
 
     _gathered_usage: Optional[Tuple[int, tuple]] = None
     #: guards _gathered_usage recomputes (see gathered_usage); set by
@@ -172,6 +205,52 @@ class ClusterTensors:
                 rows.setdefault(cc, []).append(i)
             object.__setattr__(self, "_class_rows", rows)
         return self._class_rows
+
+    # graft: frozen
+    def spread_codes(
+            self, attribute: str) -> Tuple[np.ndarray, Tuple[str, ...], bool]:
+        """(codes, values, built): the node-static half of a spread
+        stanza over ``attribute``, cached on the cluster build (the
+        O(N) ``resolve_target`` walk was paid once per stanza per EVAL
+        and was most of ``sched.assembly``). ``codes`` is a READ-ONLY
+        i32[n_pad]: per real row the index of the node's resolved
+        value in ``values``, -1 where the node lacks the attribute or
+        is missing from ``nodes_by_id``, and on padded rows. ``values``
+        holds the distinct resolved values in first-seen row order.
+        ``built`` says whether THIS call paid the walk.
+
+        Double-checked under the instance's lock like
+        ``gathered_usage``: the members of a batch's first wave ask at
+        once, and one walk serves them all."""
+        cached = self._spread_codes.get(attribute)
+        built = cached is None
+        if built:
+            with (self._gather_lock or _GATHER_LOCK):
+                cached = self._spread_codes.get(attribute)
+                built = cached is None
+                if built:
+                    cached = self._walk_spread_codes(attribute)
+                    self._spread_codes[attribute] = cached
+        spread_code_stats.note(built)
+        return cached[0], cached[1], built
+
+    def _walk_spread_codes(
+            self, attribute: str) -> Tuple[np.ndarray, Tuple[str, ...]]:
+        from nomad_tpu.structs.constraints import resolve_target
+
+        codes = np.full(self.n_pad, -1, np.int32)
+        code_of: Dict[str, int] = {}
+        nodes_by_id = self.nodes_by_id
+        for i, nid in enumerate(self.node_ids):
+            node = nodes_by_id.get(nid)
+            if node is None:
+                continue
+            val, ok = resolve_target(attribute, node)
+            if not ok:
+                continue
+            codes[i] = code_of.setdefault(val, len(code_of))
+        codes.setflags(write=False)
+        return codes, tuple(code_of)
 
     def usage_perm(self, usage) -> Tuple[np.ndarray, np.ndarray]:
         """Map cluster rows -> usage-plane rows (gather index + validity).
