@@ -86,10 +86,11 @@ def pad_steps(k: int) -> int:
 #: live-path floor for the placement-axis bucket: a follow-up eval
 #: placing 1-2 leftover allocs used to compile its own tiny step
 #: variant per (wave, k) pair — padding every live launch to at least
-#: 8 steps collapses those onto the primary evals' programs. An
-#: inactive step costs what a real one does (every step scores every
-#: node and masks the result afterwards; PERF.md section 5); a cold
-#: compile is tens of seconds
+#: 8 steps collapses those onto the primary evals' programs. A wave's
+#: program runs only its real steps, so the padding costs a wave no
+#: device time; a lone launch's programs run every padded step (each
+#: scores every node and masks the result afterwards); a cold compile
+#: is tens of seconds
 MIN_STEP_BUCKET = 8
 
 
@@ -383,7 +384,8 @@ def launch_attrs(seq: int, k_steps: list, origins: Optional[list],
     """A launch record's attributes that are known before the launch is
     assembled (docs/TELEMETRY.md): the members in the order the device
     program places them. The launcher adds ``program``, ``slots``,
-    ``padded_steps`` and ``features`` once it has routed the launch."""
+    ``padded_steps``, ``executed_steps`` and ``features`` once it has
+    routed the launch."""
     origins = origins or [None] * len(k_steps)
     return {
         "seq": seq,
@@ -1091,7 +1093,8 @@ def _lone_launch(program: str, jit_fn, kin: KernelIn, k_steps: int,
     if tracer.enabled:
         attrs = dict(
             launch_attrs(seq, [steps], [origin]), program=program,
-            slots=1, padded_steps=k_steps, features=features_key(features))
+            slots=1, padded_steps=k_steps, executed_steps=k_steps,
+            features=features_key(features))
     with tracer.span("wave.launch", attrs=attrs):
         out = profiler.call(program, jit_fn, (kin,), (k_steps, features),
                             key, jit_fn=jit_fn)
@@ -1138,10 +1141,12 @@ def place_taskgroups_joint(
 ) -> JointOut:
     """Place a WAVE of task-group asks with a shared capacity carry.
 
-    ``kin`` is a stacked KernelIn (leading member axis B). The scan
-    runs ``t_steps`` placement steps; step t belongs to wave member
+    ``kin`` is a stacked KernelIn (leading member axis B). The outputs
+    have ``t_steps`` placement steps; step t belongs to wave member
     ``step_member[t]`` (-1 = padding) at member-local placement index
-    ``step_local[t]``.
+    ``step_local[t]``. The loop runs the real steps alone (those of a
+    member inside its ``n_steps``), in order; a padded step's row holds
+    what an inert step writes, and costs the device nothing.
 
     This is the on-device form of the leader's serialized plan applier
     (nomad/plan_apply.go:71): every step's feasibility and score see
@@ -1309,9 +1314,33 @@ def place_taskgroups_joint(
         )
         return st2, out
 
-    st_final, (chosen, scores, found, topk_idx, topk_scores) = jax.lax.scan(
-        step, init, jnp.arange(t_steps)
+    # Only the steps that place run: a member's own, inside its n_steps
+    # (``active`` above), in layout order. Every other row keeps what an
+    # inert step writes, so the loop's trip count is the wave's real
+    # steps and not the padded bucket, and the outputs keep its shape.
+    member_steps = jnp.asarray(kin.n_steps)[jnp.clip(step_member, 0, b - 1)]
+    real = (step_member >= 0) & (step_local < member_steps)
+    n_real = jnp.sum(real, dtype=jnp.int32)
+    real_t = jnp.nonzero(real, size=t_steps, fill_value=0)[0]
+    inert = (
+        jnp.full(t_steps, -1, jnp.int32),
+        jnp.zeros(t_steps, jnp.float32),
+        jnp.zeros(t_steps, bool),
+        # lax.top_k's ties go to the lower index: an all-NEG_INF row
+        jnp.broadcast_to(jnp.arange(TOPK, dtype=jnp.int32) if f.with_topk
+                         else jnp.zeros(TOPK, jnp.int32), (t_steps, TOPK)),
+        jnp.full((t_steps, TOPK), NEG_INF, jnp.float32),
     )
+
+    def real_step(i, carry):
+        st, rows = carry
+        t = real_t[i]
+        st2, row = step(st, t)
+        return st2, tuple(jax.lax.dynamic_update_index_in_dim(r, x, t, 0)
+                          for r, x in zip(rows, row))
+
+    st_final, (chosen, scores, found, topk_idx, topk_scores) = \
+        jax.lax.fori_loop(0, n_real, real_step, (init, inert))
 
     # per-member first-step metrics (AllocMetric inputs), from the
     # pre-wave state — identical to the single-problem kernel's

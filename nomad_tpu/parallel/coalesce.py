@@ -451,16 +451,18 @@ class WaveStats:
         self.members_sum = 0
         self.slots_sum = 0
         # placement steps handed to the device, real and padded, of
-        # EVERY launch (a lone one too), and the members the scheduler
-        # was placing again (a retry against a refreshed state)
+        # EVERY launch (a lone one too), the steps its program ran
+        # (``executed_steps``), and the members the scheduler was
+        # placing again (a retry against a refreshed state)
         self.steps_sum = 0
         self.padded_steps_sum = 0
+        self.executed_steps_sum = 0
         self.relaunched_members_sum = 0
         self._park_s: deque = deque(maxlen=4096)
 
     def observe_wave(self, members: int, deadline_fired: bool,
                      steps: int = 0, padded_steps: int = 0,
-                     relaunched: int = 0) -> None:
+                     executed_steps: int = 0, relaunched: int = 0) -> None:
         with self._lock:
             self.launches += 1
             self.members_sum += members
@@ -471,15 +473,18 @@ class WaveStats:
                 self.full_launches += 1
             self.steps_sum += steps
             self.padded_steps_sum += padded_steps
+            self.executed_steps_sum += executed_steps
             self.relaunched_members_sum += relaunched
 
     def observe_lone(self, steps: int, padded_steps: int,
                      relaunched: bool) -> None:
         """A launch outside any wave (ops/kernel.default_kernel_launch):
-        its steps count, it is no wave and fills no slots."""
+        its steps count, it is no wave and fills no slots. Its program
+        runs every padded step."""
         with self._lock:
             self.steps_sum += steps
             self.padded_steps_sum += padded_steps
+            self.executed_steps_sum += padded_steps
             self.relaunched_members_sum += int(relaunched)
 
     def observe_park(self, seconds: float, held: bool = False) -> None:
@@ -502,6 +507,7 @@ class WaveStats:
             self.slots_sum = 0
             self.steps_sum = 0
             self.padded_steps_sum = 0
+            self.executed_steps_sum = 0
             self.relaunched_members_sum = 0
             self._park_s.clear()
 
@@ -518,6 +524,7 @@ class WaveStats:
                 "full_launches": self.full_launches,
                 "deadline_launches": self.deadline_launches,
                 "held_for_arrivals": self.held_for_arrivals,
+                "executed_steps": self.executed_steps_sum,
                 "fill_ratio": (self.members_sum / self.slots_sum
                                if self.slots_sum else 0.0),
                 "park_latency_p50_ms": p50 * 1e3,
@@ -606,6 +613,14 @@ def wave_step_pad(members: int, k_max: int) -> int:
     PADDED wave so the compiled shape depends only on (wave bucket,
     step bucket, features)."""
     return pad_steps(pad_wave(members) * k_max)
+
+
+def executed_steps(program: str, steps: int, t_pad: int) -> int:
+    """Placement steps a wave's program runs on the device: ``joint``
+    and ``joint_sharded`` the real ones alone
+    (ops/kernel.place_taskgroups_joint), ``fused_wave_sharded`` every
+    padded one."""
+    return t_pad if program == "fused_wave_sharded" else steps
 
 
 def launch_wave(kins: List[KernelIn], k_steps: List[int],
@@ -721,10 +736,11 @@ def _launch_wave(kins: List[KernelIn], k_steps: List[int],
         # applier's serialization order = plan arrival order). The step
         # axis is sized from the PADDED wave (b_pad * k_max) so the
         # compiled shape depends only on (wave bucket, step bucket,
-        # features) — retry waves of any real size reuse it. An inert
-        # step costs the device what a real one does (PERF.md section
-        # 5): the padding is paid for in launch time. Built vectorized:
-        # the per-member python loop showed up at bench wave sizes.
+        # features) — retry waves of any real size reuse it. The joint
+        # programs run only the real steps (a member's first n_steps of
+        # its block), so an inert step costs the device nothing. Built
+        # vectorized: the per-member python loop showed up at bench
+        # wave sizes.
         t_pad = wave_step_pad(len(kins), k_max)
         ks = np.asarray(k_steps, np.int64)
         starts = np.concatenate(([0], np.cumsum(ks)[:-1]))
@@ -741,7 +757,10 @@ def _launch_wave(kins: List[KernelIn], k_steps: List[int],
     # this key must NOT recompile (the profiler counts violations)
     wave_key = (b_pad, t_pad, n_nodes, shareable, neutral_shareable,
                 job_shareable, feats)
+    n_real = sum(min(int(np.asarray(kin.n_steps)), k)
+                 for kin, k in zip(kins, k_steps))
     record.set(program=program, slots=b_pad, padded_steps=t_pad,
+               executed_steps=executed_steps(program, n_real, t_pad),
                features=features_key(feats))
     t_launch = time.perf_counter()
     token = object()
@@ -1077,15 +1096,19 @@ class LaunchCoalescer:
         for r in wave:
             groups.setdefault(int(r.kin.cap_cpu.shape[0]), []).append(r)
         wave_deadline_ewma.update(1.0 if deadline_fired else 0.0)
-        for grp in groups.values():
+        for n_nodes, grp in groups.items():
             self.launches += 1
             self.max_wave = max(self.max_wave, len(grp))
             k_steps = [r.k_steps for r in grp]
             origins = [r.origin for r in grp]
+            steps = sum(real_steps(k_steps, origins))
+            t_pad = wave_step_pad(len(grp), max(k_steps))
+            program = "joint" if self.mesh is None else wave_program(
+                int(self.mesh.size), n_nodes,
+                union_features([r.features for r in grp]))
             wave_stats.observe_wave(
-                len(grp), deadline_fired,
-                steps=sum(real_steps(k_steps, origins)),
-                padded_steps=wave_step_pad(len(grp), max(k_steps)),
+                len(grp), deadline_fired, steps=steps, padded_steps=t_pad,
+                executed_steps=executed_steps(program, steps, t_pad),
                 relaunched=sum(1 for o in origins
                                if o is not None and o.relaunch))
             try:

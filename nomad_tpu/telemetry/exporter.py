@@ -144,6 +144,11 @@ def prometheus_text(registry=None, event_broker=None) -> str:
         lines.append("# TYPE nomad_tpu_wave_held_for_arrivals_total counter")
         lines.append(
             f"nomad_tpu_wave_held_for_arrivals_total {w['held_for_arrivals']}")
+        # placement steps the device programs ran (a wave's real steps
+        # alone, a lone launch's padded bucket)
+        lines.append("# TYPE nomad_tpu_wave_executed_steps_total counter")
+        lines.append(
+            f"nomad_tpu_wave_executed_steps_total {w['executed_steps']}")
         # sharded dispatch (ISSUE 14): waves that ran the joint program
         # over a device mesh vs mesh-present single-device fallbacks
         # (a node axis the device count does not divide) — fallbacks

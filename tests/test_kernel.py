@@ -790,9 +790,11 @@ class TestSpreadNodeAxis:
 
 
 def _node_and_bucket_values(jaxpr, n_pad, inside_scan=False):
-    """(scan bodies met, offending values): every value produced or read
-    inside a scan body of ``jaxpr``, however deeply nested, whose shape
-    has both an ``n_pad``-sized and a SPREAD_BUCKETS-sized axis."""
+    """(loop bodies met, offending values): every value produced or read
+    inside a ``scan`` or ``while`` body of ``jaxpr`` (the joint program's
+    step loop is a ``while``: its trip count is the wave's real steps),
+    however deeply nested, whose shape has both an ``n_pad``-sized and a
+    SPREAD_BUCKETS-sized axis."""
     scans, bad = 0, []
     for eqn in jaxpr.eqns:
         if inside_scan:
@@ -800,7 +802,7 @@ def _node_and_bucket_values(jaxpr, n_pad, inside_scan=False):
                 shape = getattr(v.aval, "shape", ())
                 if n_pad in shape and SPREAD_BUCKETS in shape:
                     bad.append((eqn.primitive.name, shape))
-        is_scan = eqn.primitive.name == "scan"
+        is_scan = eqn.primitive.name in ("scan", "while")
         scans += is_scan
         for param in eqn.params.values():
             for sub in (param if isinstance(param, (list, tuple)) else [param]):
@@ -1210,3 +1212,117 @@ class TestNoNodeGatherInAStep:
         assert not [s for s in shapes if self.N_PAD in s], shapes
         # the rank plane is there, scattered once a launch
         assert f"tensor<{self.N_PAD}xi32>" in text and "stablehlo.scatter" in text
+
+
+# ---------------------------------------------------------------------------
+# A wave's real steps alone (ISSUE 37): the joint program's loop runs the
+# steps of a member inside its n_steps and leaves every other row as an
+# inert step writes it; padding the step axis changes no answer
+# ---------------------------------------------------------------------------
+
+#: (members, k): waves of one to four members in their wave bucket, k of
+#: 1, 8 and 300 (the cell's); spread and top-k each on and off among
+#: them; the shuffle, step penalties and preferred pins on in every case
+REAL_STEP_CASES = [
+    (1, 1, True, True), (1, 300, False, False), (2, 8, True, False),
+    (2, 300, False, True), (3, 1, False, True), (3, 300, True, True),
+    (4, 8, False, True), (4, 300, True, False),
+]
+
+
+def _real_step_wave(members, k, spread, seed=37):
+    """``members`` members of ``k`` steps each, as the launcher stacks
+    them: every leaf member-stacked, the wave bucket's spare slots the
+    first member with no steps, each member's step planes ``k_pad``
+    long with a penalty on every real step and a quarter of them pinned."""
+    from nomad_tpu.ops.kernel import MAX_PENALTY_NODES, pad_steps_live
+    from nomad_tpu.parallel.coalesce import pad_wave
+    from nomad_tpu.parallel.synthetic import synthetic_cluster, synthetic_eval
+
+    rng = np.random.default_rng([seed, members, k])
+    cluster = synthetic_cluster(100, cpu=8000.0, mem=16384.0, seed=seed)
+    k_pad = pad_steps_live(k)
+    kins = []
+    for m in range(members):
+        ev = synthetic_eval(
+            cluster, ask_cpu=float(rng.choice([100, 200, 300])),
+            ask_mem=float(rng.choice([64, 128, 256])), with_spread=spread,
+            used_frac=0.5, seed=seed + m)
+        pen = np.full((k_pad, MAX_PENALTY_NODES), -1, np.int32)
+        pen[:k, 0] = rng.integers(0, cluster.n_real, k)
+        pref = np.full(k_pad, -1, np.int32)
+        pinned = rng.choice(k, max(1, k // 4), replace=False)
+        pref[pinned] = rng.integers(0, cluster.n_real, len(pinned))
+        kins.append(build_kernel_in(
+            cluster, ev, k, pen, pref,
+            node_perm=rng.permutation(cluster.n_pad).astype(np.int32)))
+    kins += [kins[0]._replace(n_steps=np.asarray(0, np.int32))] * (
+        pad_wave(members) - members)
+    return _stack_kins(kins), k_pad
+
+
+def _bits(x):
+    x = np.asarray(x)
+    return x.view(np.uint32) if x.dtype == np.float32 else x
+
+
+class TestRealStepsOnly:
+    @pytest.mark.parametrize("members, k, spread, topk", REAL_STEP_CASES)
+    def test_padding_changes_no_answer(self, members, k, spread, topk):
+        """Bit for bit, the launcher's padded layout (each member's steps
+        in a ``k_pad`` block, the step axis ``wave_step_pad`` long) gives
+        the real rows, the final carry and the member metrics of the same
+        wave laid out with no inert step at all; every padded row holds
+        what an inert step writes."""
+        import jax.numpy as jnp
+
+        from nomad_tpu.ops.kernel import (
+            FULL_FEATURES,
+            FUSED_METRIC_FIELDS,
+            NEG_INF,
+            TOPK,
+            place_taskgroups_joint_jit,
+        )
+        from nomad_tpu.parallel.coalesce import wave_step_pad
+
+        stacked, k_pad = _real_step_wave(members, k, spread)
+        features = FULL_FEATURES._replace(
+            n_spreads=1 if spread else 0, with_topk=topk, with_shuffle=True,
+            with_devices=False, with_ports=False, with_cores=False,
+            with_network=False, with_distinct=False)
+        t_pad = wave_step_pad(members, k_pad)
+        total = members * k
+        member = np.full(t_pad, -1, np.int32)
+        local = np.zeros(t_pad, np.int32)
+        member[:members * k_pad] = np.repeat(np.arange(members), k_pad)
+        local[:members * k_pad] = np.tile(np.arange(k_pad), members)
+        real = (member >= 0) & (local < k)
+        assert real.sum() == total
+
+        padded = place_taskgroups_joint_jit(
+            stacked, jnp.asarray(member), jnp.asarray(local), t_pad, features)
+        bare = place_taskgroups_joint_jit(
+            stacked, jnp.asarray(np.repeat(np.arange(members), k)),
+            jnp.asarray(np.tile(np.arange(k), members)), total, features)
+
+        rows = ("chosen", "scores", "found", "topk_idx", "topk_scores")
+        for field in rows:
+            np.testing.assert_array_equal(
+                _bits(getattr(padded, field))[real],
+                _bits(getattr(bare, field)), err_msg=field)
+        for field in FUSED_METRIC_FIELDS + ("a_cpu", "a_mem", "a_disk"):
+            np.testing.assert_array_equal(
+                _bits(getattr(padded, field)), _bits(getattr(bare, field)),
+                err_msg=field)
+        fill = dict(
+            chosen=np.int32(-1), scores=_bits(np.float32(0.0)), found=False,
+            topk_idx=np.arange(TOPK) if topk else np.zeros(TOPK, np.int32),
+            topk_scores=_bits(np.float32(NEG_INF)))
+        for field in rows:
+            got = _bits(getattr(padded, field))[~real]
+            np.testing.assert_array_equal(
+                got, np.broadcast_to(fill[field], got.shape), err_msg=field)
+        # every real step places (the cluster has room for the wave): the
+        # penalties and pins steer, they do not stall, and no real step
+        # was left out of either loop
+        assert np.asarray(bare.found).all()

@@ -235,12 +235,57 @@ class TestStepCounters:
                               origin=LaunchOrigin("lone", 2, 3, True))
         assert wave_stats.steps_sum == 3
         assert wave_stats.padded_steps_sum == k_pad
+        # a lone launch's programs run every padded step
+        assert wave_stats.executed_steps_sum == k_pad
         assert wave_stats.relaunched_members_sum == 1
         # it is no wave: the fill counters do not see it
         assert wave_stats.launches == 0 and wave_stats.slots_sum == 0
         rec = tracer.spans(name="wave.launch")[0]
         assert rec.attrs["evals"] == ["lone"]
         assert rec.attrs["state_index"] == [2]
+        assert rec.attrs["executed_steps"] == k_pad
+
+    def test_wave_of_three_runs_its_900_real_steps(self, tracer_only,
+                                                  monkeypatch):
+        """ISSUE 37: three members of 300 steps in 512-step blocks: the
+        record says 900 real steps of 2,048 compiled and 900 run, and
+        the coalescer's counter grows by the 900 the program runs."""
+        kin, k_pad, feats = _kin(300)
+        origins = [LaunchOrigin(f"e{i}", 7, 300, False) for i in range(3)]
+        coalesce.launch_wave([kin] * 3, [k_pad] * 3, [feats] * 3,
+                             mesh=None, origins=origins)
+        rec = tracer.spans(name="wave.launch")[0]
+        assert sum(rec.attrs["steps"]) == 900
+        assert rec.attrs["padded_steps"] == 2048
+        assert rec.attrs["executed_steps"] == 900
+
+        wave_stats.reset()
+        co = LaunchCoalescer(3)
+        requests = [coalesce._Request(_NodeAxisOnly(), k_pad, None, o)
+                    for o in origins]
+        launched = []
+        monkeypatch.setattr(
+            coalesce, "launch_wave", lambda kins, *a, **kw:
+            launched.append(len(kins)) or [object()] * len(kins))
+        co._fire(requests)
+        assert launched == [3]
+        assert (wave_stats.steps_sum, wave_stats.padded_steps_sum,
+                wave_stats.executed_steps_sum) == (900, 2048, 900)
+        assert wave_stats.snapshot()["executed_steps"] == 900
+        from nomad_tpu.telemetry.exporter import prometheus_text
+
+        assert "nomad_tpu_wave_executed_steps_total 900" in prometheus_text()
+        wave_stats.reset()
+        assert wave_stats.executed_steps_sum == 0
+        assert wave_stats.snapshot()["executed_steps"] == 0
+
+    def test_executed_steps_by_program(self):
+        """The mesh's fused program runs its padded bucket whole; the
+        joint programs the real steps alone."""
+        assert coalesce.executed_steps("joint", 900, 2048) == 900
+        assert coalesce.executed_steps("joint_sharded", 900, 2048) == 900
+        assert coalesce.executed_steps("fused_wave_sharded", 900, 2048) \
+            == 2048
 
     def test_scheduler_says_who_and_against_which_state(self):
         """Through the real stack: the launcher is handed the eval id,
@@ -550,7 +595,10 @@ class TestMetricFiles:
          "ratio", 58.59375),
         (["nomad_tpu.parallel.coalesce:wave_stats.relaunched_members_sum"],
          "delta", 1.0),
-    ], ids=["step_fill", "relaunched_members"])
+        (["nomad_tpu.parallel.coalesce:wave_stats.steps_sum",
+          "nomad_tpu.parallel.coalesce:wave_stats.executed_steps_sum"],
+         "ratio", 100.0),
+    ], ids=["step_fill", "relaunched_members", "executed_step_fill"])
     def test_counter_reader_reads_the_step_counters(
             self, counters, reduce, want):
         """``step_fill`` and ``relaunched_members`` as a later PR can
@@ -562,10 +610,35 @@ class TestMetricFiles:
         wave_stats.reset()
         before = {p: float(resolve(p, {})) for p in counters}
         wave_stats.observe_wave(4, False, steps=1200, padded_steps=2048,
-                                relaunched=1)
+                                executed_steps=1200, relaunched=1)
         ctx = {"counters": {p: (before[p], float(resolve(p, {})))
                             for p in counters}}
         wave_stats.reset()
         metric = {"counters": counters, "reduce": reduce,
                   "scale": 100 if reduce == "ratio" else 1}
         assert counter.read(metric, ctx) == pytest.approx(want)
+
+
+def test_executed_step_fill_file_reads_the_counters():
+    """``executed_step_fill`` as ``BENCHMARK.json`` lists it: the real
+    steps over the steps the programs ran; nothing on a parent that
+    lacks the counter (``benchmark/tracing._read_counters``)."""
+    from benchmark.readers import counter
+
+    with open(os.path.join(ROOT, "benchmark", "metrics",
+                           "executed_step_fill.json")) as f:
+        metric = json.load(f)
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        listed = {m["name"]: m for m in json.load(f)["per_layer"]}
+    for key in ("name", "unit", "better", "source", "layer", "moves",
+                "workloads"):
+        assert metric[key] == listed["executed_step_fill"][key], key
+    steps, executed = metric["counters"]
+    assert counter.read(metric, {"counters": {
+        steps: (100.0, 1000.0), executed: (100.0, 1000.0)}}) == 100.0
+    # a lone launch's padded steps ran: 900 real of 1,024 run
+    assert counter.read(metric, {"counters": {
+        steps: (0.0, 900.0), executed: (0.0, 1024.0)}}) \
+        == pytest.approx(87.890625)
+    assert counter.read(metric, {"counters": {
+        steps: (0.0, 900.0), executed: (None, None)}}) is None
