@@ -13,6 +13,8 @@ group, carrying per-placement penalty/preferred planes.
 
 from __future__ import annotations
 
+import threading
+import time
 from typing import Dict, List, Optional
 
 from nomad_tpu.scheduler.context import EvalContext
@@ -58,6 +60,36 @@ _VALID_TRIGGERS = frozenset({
 })
 BLOCKED_EVAL_MAX_PLAN = "created due to placement conflicts"
 BLOCKED_EVAL_FAILED_PLACEMENTS = "created to place remaining allocations"
+
+
+class StopStats:
+    """Process-wide cost of the reconciler's stops: ``allocs_sum``
+    allocations appended to plans as stopped, ``seconds_sum`` the
+    host seconds those appends took, one observation per evaluation
+    that stops any. A counter, not a span: a child span would come off
+    ``eval.schedule``'s own time by construction."""
+
+    def __init__(self) -> None:
+        self._lock = threading.Lock()
+        self.allocs_sum = 0
+        self.seconds_sum = 0.0
+
+    def observe(self, allocs: int, seconds: float) -> None:
+        with self._lock:
+            self.allocs_sum += allocs
+            self.seconds_sum += seconds
+
+    def snapshot(self) -> Dict[str, float]:
+        with self._lock:
+            return {"allocs": self.allocs_sum, "seconds": self.seconds_sum}
+
+    def reset(self) -> None:
+        with self._lock:
+            self.allocs_sum = 0
+            self.seconds_sum = 0.0
+
+
+stop_stats = StopStats()
 
 
 class GenericScheduler(Scheduler):
@@ -228,11 +260,14 @@ class GenericScheduler(Scheduler):
         if results.deployment is not None:
             self.deployment = results.deployment
 
-        for stop in results.stop:
-            self.plan.append_stopped_alloc(
-                stop.alloc, stop.status_description, stop.client_status,
-                stop.followup_eval_id,
-            )
+        if results.stop:
+            t0 = time.perf_counter()
+            for stop in results.stop:
+                self.plan.append_stopped_alloc(
+                    stop.alloc, stop.status_description, stop.client_status,
+                    stop.followup_eval_id,
+                )
+            stop_stats.observe(len(results.stop), time.perf_counter() - t0)
         for aid, update in results.disconnect_updates.items():
             self.plan.append_alloc(update, None)
         for update in results.inplace_update:
@@ -266,9 +301,7 @@ class GenericScheduler(Scheduler):
         if self.deployment is not None and self.deployment.active():
             deployment_id = self.deployment.id
 
-        import time as _time
-
-        now = _time.time()
+        now = time.time()
 
         # group placement results by task group, preserving order
         ordered = list(results.destructive_update) + list(results.place)

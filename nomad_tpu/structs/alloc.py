@@ -376,6 +376,15 @@ class Allocation:
     def copy(self) -> "Allocation":
         return _copy.deepcopy(self)
 
+    def copy_shallow(self) -> "Allocation":
+        """A new allocation over the same nested objects (structs.go
+        ``*newAlloc = *alloc``). The memos ride along: their keys are
+        the shared objects. The caller replaces what it changes and
+        mutates nothing nested."""
+        new = object.__new__(type(self))
+        new.__dict__.update(self.__dict__)
+        return new
+
     def copy_skip_job(self) -> "Allocation":
         job = self.job
         self.job = None

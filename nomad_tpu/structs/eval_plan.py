@@ -200,8 +200,12 @@ class Plan:
                 fn()
 
     def append_stopped_alloc(self, alloc: Allocation, desired_desc: str, client_status: str = "", follow_up_eval_id: str = "") -> None:
-        """structs.go Plan.AppendStoppedAlloc."""
-        new = alloc.copy_skip_job()
+        """structs.go Plan.AppendStoppedAlloc: a shallow copy with the
+        stop fields set. Nested objects (resources, metrics, task
+        states) are shared with the source: a stored allocation is
+        never mutated in place, every writer replaces what it changes
+        (``state/store.py``)."""
+        new = alloc.copy_shallow()
         new.desired_status = ALLOC_DESIRED_STOP
         new.desired_description = desired_desc
         if client_status:

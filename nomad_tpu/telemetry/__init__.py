@@ -118,6 +118,14 @@ def reset() -> None:
     except Exception:                           # noqa: BLE001
         pass
     try:
+        # the reconciler's stops (allocations, seconds) follow the
+        # same window
+        from nomad_tpu.scheduler.generic import stop_stats
+
+        stop_stats.reset()
+    except Exception:                           # noqa: BLE001
+        pass
+    try:
         # feasibility mask-cache counters follow the same window; the
         # cached programs/masks themselves stay resident
         from nomad_tpu.feasibility import default_mask_cache

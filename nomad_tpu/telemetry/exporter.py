@@ -242,6 +242,19 @@ def prometheus_text(registry=None, event_broker=None) -> str:
                 f'{{kind="{kind}"}} {sc[key]}')
     except Exception:                           # noqa: BLE001
         pass                # tensors (numpy) unavailable: skip
+    # the reconciler's stops (scheduler/generic.stop_stats): seconds
+    # over allocations is the host cost of one stopped allocation
+    try:
+        from nomad_tpu.scheduler.generic import stop_stats
+
+        st = stop_stats.snapshot()
+        lines.append("# TYPE nomad_tpu_sched_stopped_allocs_total counter")
+        lines.append(f"nomad_tpu_sched_stopped_allocs_total {st['allocs']}")
+        lines.append("# TYPE nomad_tpu_sched_stop_seconds_total counter")
+        lines.append(
+            f"nomad_tpu_sched_stop_seconds_total {st['seconds']:.6f}")
+    except Exception:                           # noqa: BLE001
+        pass                # scheduler (jax) unavailable: skip
     # feasibility compiler (nomad_tpu/feasibility/): mask-program cache
     # effectiveness — a steady cluster should sit near hit_ratio 1.0,
     # with misses only on node-structure forks and novel job specs
