@@ -103,7 +103,9 @@ _ATTRIBUTED = {
     # of the wave-critical sched-host sum
     "plan.deferred": ("plan-post", "cpu"),
     "fsm.apply": ("fsm", "cpu"),
-    # the store's write transaction, a child of fsm.apply: same stage
+    # one raft entry's handler, a child of fsm.apply: same stage
+    "fsm.entry": ("fsm", "cpu"),
+    # the store's write transaction, under fsm.apply: same stage
     "store.txn": ("fsm", "cpu"),
 }
 

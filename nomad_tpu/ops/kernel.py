@@ -1081,8 +1081,8 @@ def _lone_launch(program: str, jit_fn, kin: KernelIn, k_steps: int,
     come back as numpy; the top-k planes stay on the device until the
     plan window's score_meta drain. None where the candidate-set
     program says its bound did not hold."""
-    from nomad_tpu.parallel.coalesce import wave_stats
-    from nomad_tpu.telemetry.kernel_profile import launch_seq, profiler
+    from nomad_tpu.parallel.coalesce import timed_call, wave_stats
+    from nomad_tpu.telemetry.kernel_profile import launch_seq
     from nomad_tpu.telemetry.trace import tracer
 
     steps = origin.steps if origin is not None else int(kin.n_steps)
@@ -1096,8 +1096,8 @@ def _lone_launch(program: str, jit_fn, kin: KernelIn, k_steps: int,
             slots=1, padded_steps=k_steps, executed_steps=k_steps,
             features=features_key(features))
     with tracer.span("wave.launch", attrs=attrs):
-        out = profiler.call(program, jit_fn, (kin,), (k_steps, features),
-                            key, jit_fn=jit_fn)
+        out = timed_call(program, jit_fn, (kin,), (k_steps, features),
+                         key, jit_fn=jit_fn)
         with tracer.span("kernel.d2h") as sp:
             if program == "single_topk":
                 out, ok = out

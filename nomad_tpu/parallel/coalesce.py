@@ -458,7 +458,34 @@ class WaveStats:
         self.padded_steps_sum = 0
         self.executed_steps_sum = 0
         self.relaunched_members_sum = 0
+        # the device's wait between launches: at each launch's call,
+        # the seconds since the last launch's outputs were ready (0
+        # where another launch was still in flight), and how many
+        # launches followed another (``launch_dispatched``)
+        self.gap_seconds_sum = 0.0
+        self.gaps = 0
+        self._in_flight = 0
+        self._last_ready: Optional[float] = None
         self._park_s: deque = deque(maxlen=4096)
+
+    def launch_dispatched(self) -> None:
+        """A launch is about to call its program (every launch, a lone
+        one too): one clock read."""
+        now = time.monotonic()
+        with self._lock:
+            if self._last_ready is not None:
+                self.gaps += 1
+                if not self._in_flight:
+                    self.gap_seconds_sum += max(now - self._last_ready, 0.0)
+            self._in_flight += 1
+
+    def launch_ready(self) -> None:
+        """The launch's outputs are ready (``kernel.execute`` ended) or
+        its call raised: one clock read."""
+        now = time.monotonic()
+        with self._lock:
+            self._in_flight -= 1
+            self._last_ready = now
 
     def observe_wave(self, members: int, deadline_fired: bool,
                      steps: int = 0, padded_steps: int = 0,
@@ -509,6 +536,9 @@ class WaveStats:
             self.padded_steps_sum = 0
             self.executed_steps_sum = 0
             self.relaunched_members_sum = 0
+            self.gap_seconds_sum = 0.0
+            self.gaps = 0
+            self._last_ready = None
             self._park_s.clear()
 
     def snapshot(self) -> dict:
@@ -525,6 +555,8 @@ class WaveStats:
                 "deadline_launches": self.deadline_launches,
                 "held_for_arrivals": self.held_for_arrivals,
                 "executed_steps": self.executed_steps_sum,
+                "gap_seconds": self.gap_seconds_sum,
+                "gaps": self.gaps,
                 "fill_ratio": (self.members_sum / self.slots_sum
                                if self.slots_sum else 0.0),
                 "park_latency_p50_ms": p50 * 1e3,
@@ -608,6 +640,16 @@ def _oldest_inflight_age_s() -> float:
     return time.perf_counter() - oldest
 
 
+def timed_call(*args, **kwargs):
+    """``profiler.call`` for every device launch, wave or lone, with
+    the wait between launches counted (``WaveStats.launch_dispatched``)."""
+    wave_stats.launch_dispatched()
+    try:
+        return profiler.call(*args, **kwargs)
+    finally:
+        wave_stats.launch_ready()
+
+
 def wave_step_pad(members: int, k_max: int) -> int:
     """Padded step count of a wave's device program: sized from the
     PADDED wave so the compiled shape depends only on (wave bucket,
@@ -627,7 +669,9 @@ def launch_wave(kins: List[KernelIn], k_steps: List[int],
                 features: List[KernelFeatures],
                 mesh=_USE_GLOBAL,
                 origins: Optional[List[Optional[LaunchOrigin]]] = None,
-                deadline_fired: bool = False) -> List[KernelOut]:
+                deadline_fired: bool = False,
+                release: Optional[str] = None,
+                released_by: str = "") -> List[KernelOut]:
     """Fire B launch requests as ONE joint device call; split results.
     One ``wave.launch`` span, the launch record, covers it: assembly,
     the call, the wait and the copies are its children.
@@ -642,11 +686,19 @@ def launch_wave(kins: List[KernelIn], k_steps: List[int],
     server opted out" — so co-resident servers never fight over the
     module global; only DIRECT calls (no mesh argument) fall back to
     ``configure_wave_mesh``'s global. ``origins`` (who asked, per
-    member) and ``deadline_fired`` only feed the launch record.
+    member), ``deadline_fired`` and, from a coalescer, ``release`` (how
+    the rendezvous completed: ``LaunchCoalescer._fire``) and
+    ``released_by`` (the evaluation that completed it; the calling
+    thread's trace id where empty) only feed the launch record.
     """
     seq = next(launch_seq)
-    attrs = (launch_attrs(seq, k_steps, origins, deadline_fired)
-             if tracer.enabled else None)
+    attrs = None
+    if tracer.enabled:
+        attrs = launch_attrs(seq, k_steps, origins, deadline_fired)
+        if release is not None:
+            ctx = tracer.context()
+            attrs["release"] = release
+            attrs["released_by"] = released_by or (ctx[0] if ctx else "")
     with tracer.span("wave.launch", attrs=attrs) as record:
         return _launch_wave(kins, k_steps, features, mesh, record)
 
@@ -787,7 +839,7 @@ def _launch_wave(kins: List[KernelIn], k_steps: List[int],
             entry = fused_sharded_entry if fused else joint_sharded_entry
             fn, kin_shardings, repl = entry(
                 mesh, shareable, neutral_shareable, job_shareable)
-            out = profiler.call(
+            out = timed_call(
                 program, fn,
                 (stacked, step_member, step_local),
                 (t_pad, feats),
@@ -797,7 +849,7 @@ def _launch_wave(kins: List[KernelIn], k_steps: List[int],
         else:
             if mesh is not None:
                 sharded_wave_stats.note_fallback(mesh_size)
-            out = profiler.call(
+            out = timed_call(
                 program, place_taskgroups_joint_jit,
                 (stacked, jnp.asarray(step_member),
                  jnp.asarray(step_local)),
@@ -998,7 +1050,7 @@ class LaunchCoalescer:
                 wave = self._pending
                 self._pending = []
         if wave is not None:
-            self._fire(wave)
+            self._fire(wave, release="arrive")
         else:
             # parked: another member completes the rendezvous and runs
             # the device call, or this member's deadline expires and it
@@ -1048,12 +1100,15 @@ class LaunchCoalescer:
                     req.event.wait()
             wave_stats.observe_park(time.perf_counter() - t0, held)
             if wave is not None:
-                self._fire(wave, deadline_fired=True)
+                self._fire(wave, deadline_fired=True, release="deadline")
         if req.error is not None:
             raise req.error
         return req.out
 
-    def done(self) -> None:
+    def done(self, trace_id: str = "") -> None:
+        """The calling participant has finished. ``trace_id`` names its
+        evaluation for the launch record, should it complete the
+        rendezvous: its spans have closed by now."""
         wave: Optional[List[_Request]] = None
         with self._cv:
             self._arrive()
@@ -1064,7 +1119,7 @@ class LaunchCoalescer:
                 wave = self._pending
                 self._pending = []
         if wave is not None:
-            self._fire(wave)
+            self._fire(wave, release="done", released_by=trace_id)
 
     def suspend(self) -> None:
         """Temporarily yield this participant's rendezvous slot (it is
@@ -1078,7 +1133,7 @@ class LaunchCoalescer:
                 wave = self._pending
                 self._pending = []
         if wave is not None:
-            self._fire(wave)
+            self._fire(wave, release="suspend")
 
     def resume(self) -> None:
         """Re-take the slot released by ``suspend``."""
@@ -1088,7 +1143,13 @@ class LaunchCoalescer:
     def plan_window(self) -> _PlanWindow:
         return _PlanWindow(self)
 
-    def _fire(self, wave: List[_Request], deadline_fired: bool = False) -> None:
+    def _fire(self, wave: List[_Request], deadline_fired: bool = False,
+              release: str = "arrive", released_by: str = "") -> None:
+        """Launch ``wave`` on the calling thread, the one that completed
+        the rendezvous. ``release`` says how, for the launch record: a
+        member that arrived (``arrive``), a participant that finished
+        (``done``) or stepped aside for the applier (``suspend``), or
+        a parked member's deadline (``deadline``)."""
         # members that retried after a partial-commit snapshot refresh
         # may have crossed a node-axis pad bucket; a joint launch needs
         # one node axis, so split by shape (each group still coalesces)
@@ -1116,7 +1177,8 @@ class LaunchCoalescer:
                     [r.kin for r in grp], k_steps,
                     [r.features for r in grp],
                     mesh=self.mesh, origins=origins,
-                    deadline_fired=deadline_fired,
+                    deadline_fired=deadline_fired, release=release,
+                    released_by=released_by,
                 )
                 for r, out in zip(grp, outs):
                     r.out = out

@@ -62,6 +62,25 @@ AUTOPILOT_CONFIG = "AutopilotRequestType"
 REGION_UPSERT = "RegionUpsertRequestType"
 
 
+def entry_attrs(msg_type: str, req: Dict) -> Dict:
+    """An ``fsm.entry`` span's attributes: the entry's message type and,
+    for plan results, the allocations it places and stops (evictions
+    by preemption among the stopped)."""
+    attrs: Dict = {"type": msg_type}
+    if msg_type == APPLY_PLAN_RESULTS:
+        plans = req.get("plans")
+        if plans is None:
+            plans = [req]
+        attrs["placed"] = sum(
+            len(allocs) for p in plans
+            for allocs in (p.get("node_allocation") or {}).values())
+        attrs["stopped"] = sum(
+            len(allocs) for p in plans
+            for src in ("node_update", "node_preemptions")
+            for allocs in (p.get(src) or {}).values())
+    return attrs
+
+
 class NomadFSM:
     """Applies committed log entries to the state store."""
 
@@ -89,7 +108,9 @@ class NomadFSM:
         if handler is None:
             raise ValueError(f"unknown FSM message type {msg_type}")
         with tracer.span("fsm.apply"):
-            with self._lock:
+            with self._lock, tracer.span("fsm.entry") as entry:
+                if tracer.enabled:
+                    entry.set(**entry_attrs(msg_type, req))
                 index = handler(self, req)
             # stamp at apply-commit time: the event-stream delivery-lag
             # histogram (op="stream_deliver") measures from HERE to the
@@ -125,12 +146,16 @@ class NomadFSM:
                 with self.state.batch_txn():
                     for msg_type, req in entries:
                         try:
-                            fault("fsm.apply.pre")
-                            handler = self._DISPATCH.get(msg_type)
-                            if handler is None:
-                                raise ValueError(
-                                    f"unknown FSM message type {msg_type}")
-                            index = handler(self, req)
+                            with tracer.span("fsm.entry") as entry:
+                                if tracer.enabled:
+                                    entry.set(**entry_attrs(msg_type, req))
+                                fault("fsm.apply.pre")
+                                handler = self._DISPATCH.get(msg_type)
+                                if handler is None:
+                                    raise ValueError(
+                                        "unknown FSM message type "
+                                        f"{msg_type}")
+                                index = handler(self, req)
                         except Exception as exc:  # noqa: BLE001
                             results.append((None, exc))
                         else:

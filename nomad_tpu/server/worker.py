@@ -394,7 +394,9 @@ class Worker:
         with self._live_lock:
             self._live[ev.id] = token
         try:
-            with tracer.span("eval.schedule", trace_id=ev.id):
+            with tracer.span("eval.schedule", trace_id=ev.id, attrs={
+                    "triggered_by": ev.triggered_by, "job": ev.job_id}
+                    if tracer.enabled else None):
                 if snapshot is None:
                     # SnapshotMinIndex: local raft must catch up to the
                     # eval before scheduling (worker.go:537)
@@ -551,7 +553,7 @@ class Worker:
                             plan_window=coalescer.plan_window(),
                         )
                 finally:
-                    coalescer.done()
+                    coalescer.done(ev.id)
 
             tasks = [
                 pool.submit(one, ev, token) for ev, token in chunk

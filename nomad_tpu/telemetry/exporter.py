@@ -149,6 +149,14 @@ def prometheus_text(registry=None, event_broker=None) -> str:
         lines.append("# TYPE nomad_tpu_wave_executed_steps_total counter")
         lines.append(
             f"nomad_tpu_wave_executed_steps_total {w['executed_steps']}")
+        # the device's wait between launches: seconds from one launch's
+        # outputs ready to the next launch's call, and the launches
+        # that followed another (their ratio is the mean gap)
+        lines.append("# TYPE nomad_tpu_wave_launch_gap_seconds_total counter")
+        lines.append(
+            f"nomad_tpu_wave_launch_gap_seconds_total {w['gap_seconds']:.6f}")
+        lines.append("# TYPE nomad_tpu_wave_launch_gaps_total counter")
+        lines.append(f"nomad_tpu_wave_launch_gaps_total {w['gaps']}")
         # sharded dispatch (ISSUE 14): waves that ran the joint program
         # over a device mesh vs mesh-present single-device fallbacks
         # (a node axis the device count does not divide) — fallbacks

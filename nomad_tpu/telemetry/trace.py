@@ -56,7 +56,12 @@ class Span:
     concurrently-scheduled eval threads each see ~the whole phase as
     wall time, but their CPU times sum to the work actually done — the
     stage decomposition sums CPU for host stages and wall for
-    device-blocking stages, so neither is double counted."""
+    device-blocking stages, so neither is double counted.
+
+    ``cpu_s`` and ``child_cpu_s`` are None where the CPU clock was not
+    read: a span of an unsampled tree (``Tracer.cpu_sample_every``) and
+    an after-the-fact ``record``. None is not 0: a span that did no
+    work reads 0."""
 
     __slots__ = ("name", "trace_id", "span_id", "parent_id", "start_s",
                  "dur_s", "child_s", "cpu_s", "child_cpu_s", "thread",
@@ -86,7 +91,9 @@ class Span:
         return max(self.dur_s - self.child_s, 0.0)
 
     @property
-    def exclusive_cpu_s(self) -> float:
+    def exclusive_cpu_s(self) -> Optional[float]:
+        if self.cpu_s is None:
+            return None
         return max(self.cpu_s - self.child_cpu_s, 0.0)
 
     def to_api(self) -> Dict:
@@ -99,8 +106,10 @@ class Span:
             "Start": round(self.start_s, 6),
             "DurationMs": round(self.dur_s * 1e3, 4),
             "ExclusiveMs": round(self.exclusive_s * 1e3, 4),
-            "CpuMs": round(self.cpu_s * 1e3, 4),
-            "ExclusiveCpuMs": round(self.exclusive_cpu_s * 1e3, 4),
+            "CpuMs": (None if self.cpu_s is None
+                      else round(self.cpu_s * 1e3, 4)),
+            "ExclusiveCpuMs": (None if self.cpu_s is None
+                               else round(self.exclusive_cpu_s * 1e3, 4)),
             "Thread": self.thread,
         }
         if self.attrs:
@@ -173,7 +182,7 @@ class _LiveSpan:
         # while their CPU is excluded from this span's cpu (c0 is
         # captured at the END of the enter read, the exit value before
         # its cost) and lands in the PARENT's CPU window instead
-        cpu = (time.thread_time() - self.c0) if self.sampled else 0.0
+        cpu = (time.thread_time() - self.c0) if self.sampled else None
         dur = time.monotonic() - self.t0
         stack = self.tracer._tls_stack()
         # unwind to self: an exception may have skipped children's exits
@@ -252,13 +261,17 @@ class Tracer:
         """Measure the CPU clock's read cost and pick the tree-sampling
         rate. ``time.thread_time`` is ~0.1µs through the vDSO on
         production kernels (every span reads it: exact attribution),
-        but tens of µs as a real syscall under sandboxed/older kernels
-        — at 4 reads per span site an instrumented eval would owe more
-        CPU to the tracer than to scheduling, and the decomposition
-        would gate on the instrument instead of the system. Sampling
-        1-in-K span trees (scaled by K) keeps aggregates unbiased and
-        the overhead bounded; per-span compensation (_LiveSpan.__exit__)
-        removes the residual bias from the sampled trees themselves."""
+        but a real syscall elsewhere: 4.8 to 10.3µs a read on the TPU
+        v5e host (1-in-2 trees; there the clock also moves in 10 ms
+        ticks, so one span's reading is a tick count and only a mean
+        over many spans is a measurement), tens of µs under
+        sandboxed/older kernels — at 4 reads per span site an
+        instrumented eval would owe more CPU to the tracer than to
+        scheduling, and the decomposition would gate on the instrument
+        instead of the system. Sampling 1-in-K span trees (scaled by K) keeps
+        aggregates unbiased and the overhead bounded; per-span
+        compensation (_LiveSpan.__exit__) removes the residual bias
+        from the sampled trees themselves."""
         reads = 64
         t0 = time.perf_counter()
         for _ in range(reads):
@@ -330,35 +343,41 @@ class Tracer:
             stack[-1].child_s += dur_s
             trace_id = trace_id or stack[-1].trace_id
         # after-the-fact records carry no CPU reading (they are mostly
-        # blocking waits); cpu_s=0 keeps them out of CPU attributions
+        # blocking waits): the clock is marked unread, which keeps them
+        # out of CPU attributions
         sp = Span(name, trace_id, next(_ids), parent_id,
-                  time.monotonic() - dur_s, dur_s, 0.0, 0.0, 0.0,
+                  time.monotonic() - dur_s, dur_s, 0.0, None, None,
                   threading.current_thread().name, attrs)
         self._append(sp, 0)
 
-    def _record(self, live: _LiveSpan, dur_s: float, cpu_s: float) -> None:
+    def _record(self, live: _LiveSpan, dur_s: float,
+                cpu_s: Optional[float]) -> None:
         sp = Span(live.name, live.trace_id, live.span_id, live.parent_id,
-                  live.t0, dur_s, live.child_s, cpu_s, live.child_cpu_s,
+                  live.t0, dur_s, live.child_s, cpu_s,
+                  live.child_cpu_s if live.sampled else None,
                   threading.current_thread().name, live.attrs)
-        self._append(sp, self.cpu_sample_every if live.sampled else 0)
+        self._append(sp, self.cpu_sample_every)
 
     def _append(self, sp: Span, cpu_scale: int = 1) -> None:
-        # ring entries keep the raw per-span reading (0 on unsampled
+        # ring entries keep the raw per-span reading (None on unsampled
         # trees); AGGREGATES scale sampled CPU by the sampling rate so
         # stage_totals stays an unbiased estimate of work executed
+        cpu = excl_cpu = 0.0
+        if sp.cpu_s is not None:
+            cpu = sp.cpu_s * cpu_scale
+            excl_cpu = sp.exclusive_cpu_s * cpu_scale
         with self._lock:
             self._ring.append(sp)
             agg = self._agg.get(sp.name)
             if agg is None:
                 self._agg[sp.name] = [1, sp.dur_s, sp.exclusive_s,
-                                      sp.cpu_s * cpu_scale,
-                                      sp.exclusive_cpu_s * cpu_scale]
+                                      cpu, excl_cpu]
             else:
                 agg[0] += 1
                 agg[1] += sp.dur_s
                 agg[2] += sp.exclusive_s
-                agg[3] += sp.cpu_s * cpu_scale
-                agg[4] += sp.exclusive_cpu_s * cpu_scale
+                agg[3] += cpu
+                agg[4] += excl_cpu
 
     # --- propagation ----------------------------------------------------
 

@@ -196,6 +196,118 @@ class TestLaunchRecord:
         assert len(tracer.spans(name="kernel.d2h")) == 1
 
 
+class TestReleasePaths:
+    """``release`` and ``released_by`` on each of the four ways a
+    rendezvous completes. The device call is stubbed under the record
+    (``_launch_wave``): the record is what is tested."""
+
+    @pytest.fixture()
+    def recorded(self, tracer_only, monkeypatch):
+        monkeypatch.setattr(
+            coalesce, "_launch_wave",
+            lambda kins, *_a, **_kw: [object()] * len(kins))
+        monkeypatch.setattr(coalesce, "wave_cohorts", _NoCohorts())
+        # a short launch on record, so that a parked member's deadline
+        # arms (LaunchCoalescer._window_s); fresh so no other test's
+        # launches move it
+        ewma = coalesce._LatencyEWMA()
+        ewma.update(0.01)
+        monkeypatch.setattr(coalesce, "wave_latency_ewma", ewma)
+        monkeypatch.setattr(coalesce, "wave_deadline_ewma",
+                            coalesce._LatencyEWMA(alpha=0.25))
+
+    @staticmethod
+    def _member(co, trace_id, errors):
+        def run():
+            try:
+                with tracer.span("eval.schedule", trace_id=trace_id):
+                    co.launch(_NodeAxisOnly(), 8, None,
+                              origin=LaunchOrigin(trace_id, 1, 3, False))
+            except BaseException as e:          # noqa: BLE001
+                errors.append(e)
+            finally:
+                co.done(trace_id)
+        t = threading.Thread(target=run)
+        t.start()
+        return t
+
+    @staticmethod
+    def _parked(co):
+        deadline = time.monotonic() + 30
+        while not co._pending and time.monotonic() < deadline:
+            time.sleep(0.001)
+        assert co._pending
+
+    @pytest.mark.parametrize("path", ["arrive", "done", "suspend",
+                                      "deadline"])
+    def test_release_and_released_by(self, recorded, path):
+        errors: list = []
+        if path == "arrive":
+            co = LaunchCoalescer(2, adaptive=False)
+            first = self._member(co, "ev-a", errors)
+            self._parked(co)
+            self._member(co, "ev-b", errors).join(30)
+            first.join(30)
+            want = "ev-b"
+        elif path == "done":
+            co = LaunchCoalescer(2, adaptive=False)
+            first = self._member(co, "ev-a", errors)
+            self._parked(co)
+            # the second participant finishes without launching (a
+            # purge evaluation of the batch): its spans have closed
+            co.done("ev-b")
+            first.join(30)
+            want = "ev-b"
+        elif path == "suspend":
+            co = LaunchCoalescer(2, adaptive=False)
+            first = self._member(co, "ev-a", errors)
+            self._parked(co)
+            with tracer.span("eval.schedule", trace_id="ev-b"):
+                co.suspend()            # off to the plan applier
+                co.resume()
+            co.done("ev-b")
+            first.join(30)
+            want = "ev-b"
+        else:
+            co = LaunchCoalescer(2, window_max_s=0.05)
+            # the second participant has arrived (and stepped back in):
+            # the first member's deadline arms once it parks, and fires
+            with tracer.span("eval.schedule", trace_id="ev-b"):
+                co.suspend()
+                co.resume()
+            first = self._member(co, "ev-a", errors)
+            first.join(30)
+            co.done("ev-b")
+            want = "ev-a"
+        assert not errors, errors
+        (rec,) = tracer.spans(name="wave.launch")
+        assert rec.attrs["release"] == path
+        assert rec.attrs["released_by"] == want
+        assert rec.attrs["deadline"] is (path == "deadline")
+
+    def test_direct_and_lone_launches_carry_no_release(self, tracer_only):
+        kin, k_pad, feats = _kin(2)
+        coalesce.launch_wave([kin], [k_pad], [feats])
+        default_kernel_launch(kin, k_pad, feats)
+        assert [("release" in s.attrs, "released_by" in s.attrs)
+                for s in tracer.spans(name="wave.launch")] \
+            == [(False, False)] * 2
+
+    def test_nothing_built_with_the_tracer_off(self, monkeypatch):
+        telemetry.disable()
+        monkeypatch.setattr(coalesce, "launch_attrs", None)
+        monkeypatch.setattr(coalesce.tracer, "context", None)
+        kin, k_pad, feats = _kin(2)
+        co = LaunchCoalescer(1)
+        co.launch(kin, k_pad, feats)
+        co.done("ev")
+
+
+class _NoCohorts:
+    def note_wave(self, _members):
+        return None
+
+
 class TestStepCounters:
     @pytest.fixture()
     def stubbed(self, monkeypatch):
@@ -437,18 +549,34 @@ class TestPlanAndStore:
         assert sum(c.attrs["allocs"] for c in commits.values()) >= 6
 
     def test_store_txn_lies_inside_fsm_apply(self, live_server):
+        """Directly (a batch's one root swap) or under the
+        ``fsm.entry`` of the entry that wrote it."""
         applies = {s.span_id: s for s in tracer.spans(name="fsm.apply")}
+        entries = {s.span_id: s.parent_id
+                   for s in tracer.spans(name="fsm.entry")}
         txns = tracer.spans(name="store.txn")
-        inside = [t for t in txns if t.parent_id in applies]
+        inside = [t for t in txns
+                  if entries.get(t.parent_id, t.parent_id) in applies]
         assert inside, "no store.txn under an fsm.apply"
         for t in inside:
-            parent = applies[t.parent_id]
+            parent = applies[entries.get(t.parent_id, t.parent_id)]
             assert parent.start_s <= t.start_s
             assert t.start_s + t.dur_s <= parent.start_s + parent.dur_s + 1e-6
             assert t.thread == parent.thread
         wrote_allocs = [t for t in inside if "allocs" in t.attrs["tables"]]
         assert wrote_allocs and all(t.attrs["rows"] >= 1
                                     for t in wrote_allocs)
+
+    def test_eval_schedule_says_its_kind(self, live_server):
+        """Every evaluation's run names its trigger and its job: the
+        three registers' placements among them."""
+        runs = tracer.spans(name="eval.schedule")
+        assert runs and all(set(s.attrs) == {"triggered_by", "job"}
+                            for s in runs)
+        placed = {s.attrs["job"] for s in runs
+                  if s.attrs["triggered_by"] == "job-register"}
+        assert len(placed) == 3
+        assert all(s.trace_id for s in runs)
 
     def test_lone_and_wave_launches_sit_under_eval_schedule(
             self, live_server):
