@@ -315,10 +315,11 @@ def _settle_committed(server, done0: int, timeout_s: float = 5.0) -> int:
 
 # Programs whose dispatches sit ON the wave critical path: the mesh's
 # fused program (one per wave by construction), the composite joint
-# program, and the composite's eager result fetch. single_topk
+# program (or its Pallas form on a TPU), and the composite's eager
+# result fetch. single_topk
 # (uncoalesced evals) and topk_drain (deferred, plan-window) are
 # excluded — they are not wave-critical. (ISSUE 19)
-_WAVE_DISPATCH_PROGRAMS = ("joint", "joint_sharded",
+_WAVE_DISPATCH_PROGRAMS = ("joint", "joint_fused", "joint_sharded",
                            "fused_wave_sharded", "wave_fetch")
 
 

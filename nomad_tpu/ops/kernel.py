@@ -1195,11 +1195,7 @@ def place_taskgroups_joint(
         init["a_dev"] = jnp.zeros((n, kin.dev_free.shape[-1]), jnp.float32)
     if f.with_distinct:
         init["job_any_count"] = _bat(kin.job_any_count, 1)   # [B, N]
-    # which leaves carry a member axis (for vmap over the members)
-    in_axes = KernelIn(*[
-        0 if jnp.ndim(x) == r + 1 else None
-        for x, r in zip(kin, KIN_UNBATCHED_RANKS)
-    ])
+    in_axes = wave_in_axes(kin)
     spread_des_n = None
     if f.n_spreads > 0:
         init["spread_counts"] = _bat(kin.spread_counts, 2)   # [B, S, Bk]
@@ -1342,8 +1338,21 @@ def place_taskgroups_joint(
     st_final, (chosen, scores, found, topk_idx, topk_scores) = \
         jax.lax.fori_loop(0, n_real, real_step, (init, inert))
 
-    # per-member first-step metrics (AllocMetric inputs), from the
-    # pre-wave state — identical to the single-problem kernel's
+    return JointOut(
+        chosen=chosen, scores=scores, found=found,
+        topk_idx=topk_idx, topk_scores=topk_scores,
+        **wave_member_metrics(kin, f),
+        a_cpu=st_final["a_cpu"], a_mem=st_final["a_mem"],
+        a_disk=st_final["a_disk"],
+    )
+
+
+def wave_member_metrics(kin: KernelIn, f: KernelFeatures) -> dict:
+    """Each wave member's first-step metrics (AllocMetric inputs), from
+    the pre-wave state, identical to the single-problem kernel's: the
+    ``JointOut`` fields of ``FUSED_METRIC_FIELDS``, each [B]. Once a
+    launch, outside the step loop."""
+
     def member_metrics(kin_m: KernelIn):
         st0 = dict(
             used_cpu=kin_m.used_cpu, used_mem=kin_m.used_mem,
@@ -1363,19 +1372,18 @@ def place_taskgroups_joint(
             ex(dims0["fit_ports"]), ex(dims0["fit_dev"]), ex(dims0["fit_cores"]),
         )
 
-    (m_eval, m_feas, m_cpu, m_mem, m_disk, m_ports, m_dev, m_cores) = jax.vmap(
-        member_metrics, in_axes=(in_axes,))(kin)
+    return dict(zip(FUSED_METRIC_FIELDS, jax.vmap(
+        member_metrics, in_axes=(wave_in_axes(kin),))(kin)))
 
-    return JointOut(
-        chosen=chosen, scores=scores, found=found,
-        topk_idx=topk_idx, topk_scores=topk_scores,
-        nodes_evaluated=m_eval, nodes_feasible=m_feas,
-        exhausted_cpu=m_cpu, exhausted_mem=m_mem, exhausted_disk=m_disk,
-        exhausted_ports=m_ports, exhausted_devices=m_dev,
-        exhausted_cores=m_cores,
-        a_cpu=st_final["a_cpu"], a_mem=st_final["a_mem"],
-        a_disk=st_final["a_disk"],
-    )
+
+def wave_in_axes(kin: KernelIn) -> KernelIn:
+    """Which leaves of a stacked wave carry a member axis: 0 for those,
+    None for a leaf the wave ships once (``jax.vmap``'s ``in_axes``
+    over the members)."""
+    return KernelIn(*[
+        0 if jnp.ndim(x) == r + 1 else None
+        for x, r in zip(kin, KIN_UNBATCHED_RANKS)
+    ])
 
 
 place_taskgroups_joint_jit = jax.jit(
@@ -1390,8 +1398,8 @@ place_taskgroups_joint_jit = jax.jit(
 # one dispatch and one readback that rides the dispatch's own
 # synchronization. The top-k planes stay separate device outputs —
 # they are lazy (coalesce._WaveTopK) and drain in the plan window, off
-# the wave-critical path. One-device waves run ``joint`` above and
-# fetch its fields one by one.
+# the wave-critical path. One-device waves run ``joint`` above (or its
+# Pallas form, ops/joint_fused.py) and fetch its fields one by one.
 # ---------------------------------------------------------------------------
 
 #: JointOut metric fields in packed-segment order (8 x [B] after the

@@ -79,6 +79,10 @@ def manifest_from_profiler(profiler=None) -> List[Dict]:
             # one-device, sharded and fused-sharded
             if kernel == "fused_wave_sharded" and len(key) == 8:
                 kernel, key = "joint", key[:7]
+            # and so does the one-device Pallas program, under the
+            # same 7-key
+            if kernel == "joint_fused":
+                kernel = "joint"
             if kernel == "joint" and len(key) in (6, 7):
                 # len 6: pre-job-group keys from persisted manifests
                 # (job_shared defaults True, the common layout)
@@ -362,15 +366,20 @@ def _entry_wave(e: Dict):
     return stacked, step_member, step_local, (t_pad, feats), layout, shared
 
 
-def _warm_joint(e: Dict) -> bool:
+def _warm_joint(e: Dict, program: str) -> bool:
+    """Populate the jit cache of the one-device program ``program``
+    (``joint`` or ``joint_fused``, as coalesce.wave_program chose) for
+    a manifest entry."""
     import jax.numpy as jnp
 
+    from nomad_tpu.ops.joint_fused import place_taskgroups_joint_fused_jit
     from nomad_tpu.ops.kernel import place_taskgroups_joint_jit
 
     stacked, step_member, step_local, statics, _layout, shared = \
         _entry_wave(e)
     _call_both_placements(
-        place_taskgroups_joint_jit,
+        place_taskgroups_joint_fused_jit if program == "joint_fused"
+        else place_taskgroups_joint_jit,
         (stacked, jnp.asarray(step_member), jnp.asarray(step_local)),
         statics, mixed=shared)
     return True
@@ -470,12 +479,14 @@ def warmup_entries(entries: List[Dict], mesh=None,
                 # wave to on each dispatch kind the server has, and no
                 # other: a second one would double the compile time
                 # on a program that never dispatches
+                feats = _features_from_dict(e["features"])
                 if not mesh_only:
-                    did = _warm_joint(e)
+                    did = _warm_joint(e, wave_program(
+                        0, int(e["nodes"]), feats))
                 program = wave_program(
                     int(mesh.size) if mesh is not None else 0,
-                    int(e["nodes"]), _features_from_dict(e["features"]))
-                if program != "joint":
+                    int(e["nodes"]), feats)
+                if program in ("joint_sharded", "fused_wave_sharded"):
                     # else: no mesh, or one the node axis does not
                     # split over, whose waves run on one device
                     from nomad_tpu.parallel.sharded import (

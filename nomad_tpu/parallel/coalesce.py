@@ -45,6 +45,10 @@ from nomad_tpu.ops.kernel import (
     real_steps,
     unpack_fused_wave,
 )
+from nomad_tpu.ops.joint_fused import (
+    fused_joint_supported,
+    place_taskgroups_joint_fused_jit,
+)
 from nomad_tpu.telemetry.histogram import histograms, percentile
 from nomad_tpu.telemetry.kernel_profile import launch_seq, profiler
 from nomad_tpu.telemetry.trace import tracer
@@ -139,7 +143,8 @@ class _FusedWaveStats:
     (``wave_program``): a program the router chose that then raises
     is an error, never a fallback. Steady lean traffic fits the
     envelope, so the mesh steady-burst gate holds fallbacks at ZERO.
-    A one-device wave has one program and counts under neither."""
+    A one-device wave (``joint`` or ``joint_fused``) counts under
+    neither."""
 
     def __init__(self) -> None:
         self._lock = witness_lock("FusedWaveStats._lock")
@@ -390,18 +395,27 @@ def union_features(features: List[KernelFeatures]) -> KernelFeatures:
 
 
 def wave_program(mesh_size: int, n_nodes: int,
-                 feats: KernelFeatures) -> str:
+                 feats: KernelFeatures,
+                 backend: Optional[str] = None) -> str:
     """The one device program a wave runs, from what the launcher can
     observe of it: how many devices its mesh has (0 or 1: none), its
-    padded node axis and its canonical feature union. The launcher,
-    the AOT warmup (ops/warmup.py) and chip_smoke.py all ask this
-    function; nothing else holds the rule.
+    padded node axis, its canonical feature union and the backend its
+    arrays live on (``jax.default_backend()`` where not given). The
+    launcher, the AOT warmup (ops/warmup.py) and chip_smoke.py all ask
+    this function; nothing else holds the rule.
 
-    One device, or a node axis the mesh does not divide: ``joint``.
-    A mesh: ``fused_wave_sharded`` for a feature union inside its
-    envelope (ops/kernel.fused_wave_supported) on shards wide enough
-    for the local top-k merge, ``joint_sharded`` otherwise."""
+    One device, or a node axis the mesh does not divide: on a TPU,
+    ``joint_fused`` for a node axis and feature union the Pallas
+    kernel takes (ops/joint_fused.fused_joint_supported), else
+    ``joint``. A mesh: ``fused_wave_sharded`` for a feature union
+    inside its envelope (ops/kernel.fused_wave_supported) on shards
+    wide enough for the local top-k merge, ``joint_sharded``
+    otherwise."""
     if mesh_size < 2 or n_nodes % mesh_size:
+        if backend is None:
+            backend = jax.default_backend()
+        if backend == "tpu" and fused_joint_supported(n_nodes, feats):
+            return "joint_fused"
         return "joint"
     if fused_wave_supported(feats) and n_nodes // mesh_size >= TOPK:
         return "fused_wave_sharded"
@@ -442,6 +456,9 @@ class WaveStats:
         self._lock = witness_lock("WaveStats._lock")
         self.requests = 0
         self.launches = 0
+        # one-device waves that ran ``joint_fused`` (``wave_program``;
+        # counted by the launcher, ``launches`` by the coalescer)
+        self.fused_launches = 0
         self.full_launches = 0
         self.deadline_launches = 0
         # park waits whose deadline would have armed but for a
@@ -503,6 +520,11 @@ class WaveStats:
             self.executed_steps_sum += executed_steps
             self.relaunched_members_sum += relaunched
 
+    def note_fused_launch(self) -> None:
+        """A one-device wave is about to run ``joint_fused``."""
+        with self._lock:
+            self.fused_launches += 1
+
     def observe_lone(self, steps: int, padded_steps: int,
                      relaunched: bool) -> None:
         """A launch outside any wave (ops/kernel.default_kernel_launch):
@@ -527,6 +549,7 @@ class WaveStats:
         with self._lock:
             self.requests = 0
             self.launches = 0
+            self.fused_launches = 0
             self.full_launches = 0
             self.deadline_launches = 0
             self.held_for_arrivals = 0
@@ -551,6 +574,7 @@ class WaveStats:
             return {
                 "requests": self.requests,
                 "launches": self.launches,
+                "fused_launches": self.fused_launches,
                 "full_launches": self.full_launches,
                 "deadline_launches": self.deadline_launches,
                 "held_for_arrivals": self.held_for_arrivals,
@@ -658,8 +682,8 @@ def wave_step_pad(members: int, k_max: int) -> int:
 
 
 def executed_steps(program: str, steps: int, t_pad: int) -> int:
-    """Placement steps a wave's program runs on the device: ``joint``
-    and ``joint_sharded`` the real ones alone
+    """Placement steps a wave's program runs on the device: ``joint``,
+    ``joint_fused`` and ``joint_sharded`` the real ones alone
     (ops/kernel.place_taskgroups_joint), ``fused_wave_sharded`` every
     padded one."""
     return t_pad if program == "fused_wave_sharded" else steps
@@ -729,7 +753,7 @@ def _launch_wave(kins: List[KernelIn], k_steps: List[int],
         n_nodes = int(np.asarray(padded[0].cap_cpu).shape[-1])
         mesh_size = int(mesh.size) if mesh is not None else 0
         program = wave_program(mesh_size, n_nodes, feats)
-        wave_sharded = program != "joint"
+        wave_sharded = program in ("joint_sharded", "fused_wave_sharded")
         fused = program == "fused_wave_sharded"
         # stack on HOST (numpy): the jit call below uploads each stacked
         # leaf once; stacking device arrays would dispatch per leaf per
@@ -849,12 +873,16 @@ def _launch_wave(kins: List[KernelIn], k_steps: List[int],
         else:
             if mesh is not None:
                 sharded_wave_stats.note_fallback(mesh_size)
+            jit_fn = place_taskgroups_joint_jit
+            if program == "joint_fused":
+                jit_fn = place_taskgroups_joint_fused_jit
+                wave_stats.note_fused_launch()
             out = timed_call(
-                program, place_taskgroups_joint_jit,
+                program, jit_fn,
                 (stacked, jnp.asarray(step_member),
                  jnp.asarray(step_local)),
                 (t_pad, feats),
-                wave_key, jit_fn=place_taskgroups_joint_jit,
+                wave_key, jit_fn=jit_fn,
             )
         if fused:
             host, wave_topk = _fused_fetch(out, t_pad, b_pad)

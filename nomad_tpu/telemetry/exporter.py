@@ -138,6 +138,13 @@ def prometheus_text(registry=None, event_broker=None) -> str:
         lines.append(
             'nomad_tpu_wave_launches_total{fired="deadline"} '
             f"{w['deadline_launches']}")
+        # one-device waves that ran the Pallas program (joint_fused;
+        # the rest of the coalescer's waves ran joint or a mesh program)
+        lines.append(
+            "# TYPE nomad_tpu_wave_joint_fused_launches_total counter")
+        lines.append(
+            "nomad_tpu_wave_joint_fused_launches_total "
+            f"{w['fused_launches']}")
         # park waits whose deadline stayed unarmed only because the
         # batch was not all there yet: how often that rule, and not the
         # launch's length, kept a wave whole
